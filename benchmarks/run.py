@@ -72,7 +72,9 @@ def _write_bench_json(name: str, out: dict, elapsed_s: float) -> str:
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import enable_compile_cache
     argv = list(argv if argv is not None else sys.argv[1:])
+    enable_compile_cache()
     # --only <module>: exact-name filter (repeatable) for local
     # iteration; bare args remain substring filters
     only, subs = [], []

@@ -31,7 +31,9 @@ replay prints ``Engine.exe.kernel_report()`` — which kernel impl each
 compiled entry (decode step, prefill chunk, draft/verify, page copy)
 ACTUALLY used, e.g. ``interpret+shard_map(model=2)`` when the Pallas
 hot path compiled per shard; ``--kernel-impl`` overrides the dispatch
-(``ref | xla | pallas | interpret``).
+of the tensor-parallel and rank-budget replays (``ref | xla | pallas |
+interpret``; ``pallas`` needs a TPU, ``interpret`` runs the same
+kernels on CPU).
 
 With ``--rank-budget F`` the demo plans a NON-UNIFORM prune
 (DESIGN.md §14): ``plan_rank_budget`` water-fills ``F`` of the model's
@@ -79,6 +81,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import (AdapterRegistry, apply_rank_budget,
                         clover_decompose, clover_prune, plan_rank_budget)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm_params
 from repro.serve import (Engine, EngineConfig, FaultPlan, Request,
                          greedy_reference, rank_pool_bytes)
@@ -100,9 +103,10 @@ def main():
     ap.add_argument("--kernel-impl", default="",
                     choices=("", "ref", "xla", "pallas", "interpret"),
                     help="kernel dispatch override for the sharded "
-                         "replay (default: inherit the arch config; "
-                         "'interpret' compiles the Pallas hot path "
-                         "per shard)")
+                         "and rank-budget replays (default: inherit "
+                         "the arch config; 'pallas' needs a TPU, "
+                         "'interpret' runs the Pallas kernels on CPU "
+                         "and exercises the per-head rank clamp)")
     ap.add_argument("--host-pages", type=int, default=8,
                     help="host-RAM spill-tier capacity (pages) for the "
                          "hierarchical-KV demo (0 = skip it)")
@@ -125,6 +129,7 @@ def main():
                          "higher-priority work is pending; running "
                          "ones time out with a partial stream")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config("musicgen-large").reduced()
     params = init_lm_params(cfg, jax.random.PRNGKey(0))
     dparams, dcfg, extras = clover_decompose(params, cfg, peft=False)
@@ -194,7 +199,7 @@ def main():
         eb = Engine(bparams, bcfg,
                     EngineConfig(slots=4, max_len=96, prefill_chunk=8,
                                  paged=True, page_tokens=8,
-                                 kernel_impl="interpret",
+                                 kernel_impl=args.kernel_impl,
                                  rank_budget=plan))
         reqs_b = [Request(uid=r.uid, prompt=r.prompt,
                           max_new_tokens=r.max_new_tokens)

@@ -698,6 +698,50 @@ def permute_attention_heads(params: Params, cfg: ArchConfig,
     return out
 
 
+def pad_kv_heads(params: Params, cfg: ArchConfig,
+                 multiple: int) -> Tuple[Params, ArchConfig]:
+    """Append all-zero KV heads (each with its G all-zero query heads)
+    until ``n_kv_heads`` is a multiple of ``multiple`` — so a
+    tensor-parallel degree that does not divide the KV heads still
+    splits the weights and the KV pool instead of replicating them.
+
+    Exact: a zero head's K and V are zero, its query heads' Q is zero
+    and their ``wo`` rows are zero, so each contributes exactly 0 to
+    the attention output.  The padded heads sit at the END of both head
+    axes, so head ``h`` still reads KV head ``h // G``.  ``head_dim`` is
+    pinned so the softmax scale and RoPE width do not follow the new
+    head count; per-head kept ranks (``rank_qk``/``rank_vo``) pad with
+    rank 0.  Returns (params', cfg')."""
+    KV, G = cfg.n_kv_heads, cfg.q_per_kv
+    extra = (-KV) % multiple
+    if extra == 0:
+        return params, cfg
+    # leaf name -> (heads to add, head axis counted from the END)
+    grow = {"wq": (extra * G, 2), "wk": (extra, 2), "wv": (extra, 2),
+            "wo": (extra * G, 3), "s_qk": (extra * G, 3),
+            "s_vo": (extra * G, 3), "k_t": (extra, 3),
+            "rank_qk": (extra, 1), "rank_vo": (extra, 1)}
+
+    def pad(leaf, n, axis_from_end):
+        widths = [(0, 0)] * leaf.ndim
+        widths[leaf.ndim - axis_from_end] = (0, n)
+        return jnp.pad(leaf, widths)
+
+    new_blocks = []
+    for j, (mixer, mlp) in enumerate(cfg.pattern):
+        stacked = dict(params["blocks"][j])
+        if mixer == MIXER_ATTN:
+            stacked["attn"] = {
+                name: (pad(leaf, *grow[name]) if name in grow else leaf)
+                for name, leaf in stacked["attn"].items()}
+        new_blocks.append(stacked)
+    out = dict(params)
+    out["blocks"] = tuple(new_blocks)
+    return out, dataclasses.replace(
+        cfg, n_heads=(KV + extra) * G, n_kv_heads=KV + extra,
+        head_dim=cfg.head_dim_)
+
+
 def mask_head_ranks(params: Params, cfg: ArchConfig,
                     qk_ranks, vo_ranks) -> Params:
     """RAGGED per-head ranks, realized as zero-padding: head ``h``

@@ -11,8 +11,10 @@ softmax (m, l, acc) in scratch, the canonical TPU flash schedule:
   legally.  Causal blocks strictly above the diagonal are skipped with
   ``pl.when`` (zero MXU work), which for long sequences halves compute.
 
-MXU alignment: dq/dv are minor dims; CLOVER's pruning planner snaps kept
-ranks to the sublane multiple so these slabs stay tile-aligned
+Layout is HEAD-MAJOR — q (B, H, S, dq), k/v (B, KV, T, d) — so every
+block's last two dims are (block, width): block a multiple of 8 (or the
+whole axis) and width the whole axis, as the TPU lowering requires.
+``kernels.ops`` transposes token-major activations in and out
 (DESIGN.md §4).
 """
 from __future__ import annotations
@@ -24,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = float(-1e30)
 
@@ -50,9 +50,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :]                                  # (bq, dq)
-        k = k_ref[0, :, 0, :]                                  # (bk, dq)
-        v = v_ref[0, :, 0, :]                                  # (bk, dv)
+        q = q_ref[0, 0]                                        # (bq, dq)
+        k = k_ref[0, 0]                                        # (bk, dq)
+        v = v_ref[0, 0]                                        # (bk, dv)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale        # (bq, bk)
@@ -76,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == n_k - 1)
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -85,14 +85,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     block_q: int = 128,
                     block_k: int = 128,
                     interpret: bool = False) -> jnp.ndarray:
-    """q: (B, S, H, dq);  k: (B, T, KV, dq);  v: (B, T, KV, dv).
+    """q: (B, H, S, dq);  k: (B, KV, T, dq);  v: (B, KV, T, dv)
+    -> (B, H, S, dv).
 
     S and T must be multiples of block_q / block_k (ops.py pads).
     When S < T (windowed prefill against a longer cache) queries are
     aligned to the END of the key range, matching attention_ref.
     """
-    B, S, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    B, H, S, dq = q.shape
+    KV, T = k.shape[1], k.shape[2]
     dv = v.shape[-1]
     G = H // KV
     assert S % block_q == 0 and T % block_k == 0, (S, T, block_q, block_k)
@@ -109,22 +110,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dq),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, dq),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, dv),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, dq),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, dq),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, dv),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dv),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

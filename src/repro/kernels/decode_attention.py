@@ -11,6 +11,14 @@ All G query heads of a KV group ride in one tile: the (G, dq) query slab
 is resident in VMEM across the whole stream, turning the GQA group into
 an MXU-friendly (G x block_t) matmul instead of G vector dots.
 
+Layout is HEAD-MAJOR: queries ``(B, KV, G, d)``, caches ``(B, KV, T, r)``.
+Every block's last two dims are then ``(G, d)`` or ``(block_t, rank)`` —
+full extents or tile multiples, which is what the TPU lowering demands
+of a block (the second-minor dim a multiple of 8 or the whole axis, the
+minor dim a multiple of 128 or the whole axis).  A token-major cache
+``(B, T, KV, r)`` would put a size-1 head block second-to-last, which
+the lowering refuses (DESIGN.md §4).
+
 Grid (B, KV, n_t): n_t sequential with (m, l, acc) scratch.  The grid is
 sized by cache CAPACITY (shape-static), but per-batch ``lengths`` arrive
 via scalar prefetch and bound the work by each row's ACTUAL length: the
@@ -31,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = float(-1e30)
 
 
@@ -52,9 +58,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(to < length)
     def _body():
-        q = q_ref[0]                                           # (G, dq)
-        k = k_ref[0, :, 0, :]                                  # (bt, dq)
-        v = v_ref[0, :, 0, :]                                  # (bt, dv)
+        q = q_ref[0, 0]                                        # (G, dq)
+        k = k_ref[0, 0]                                        # (bt, dq)
+        v = v_ref[0, 0]                                        # (bt, dv)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale        # (G, bt)
@@ -74,7 +80,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     @pl.when(it == n_t - 1)
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _decode_ranked_kernel(len_ref, rq_ref, rv_ref, q_ref, k_ref, v_ref,
@@ -119,8 +125,8 @@ def _decode_ranked_kernel(len_ref, rq_ref, rv_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live & (ir < n_rq) & (ir * rb < rq_ref[kv]))
     def _k_phase():                     # logits += q_blk . k_blk^T
-        q = q_ref[0]                                       # (G, rb)
-        k = k_ref[0, :, 0, :]                              # (bt, rb)
+        q = q_ref[0, 0]                                    # (G, rb)
+        k = k_ref[0, 0]                                    # (bt, rb)
         p_scr[...] += jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -141,7 +147,7 @@ def _decode_ranked_kernel(len_ref, rq_ref, rv_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live & (ir >= n_rq) & ((ir - n_rq) * rb < rv_ref[kv]))
     def _v_phase():                     # acc[iv] += p . v_blk
-        v = v_ref[0, :, 0, :]                              # (bt, rb)
+        v = v_ref[0, 0]                                    # (bt, rb)
         p = p_scr[...]                                     # (G, bt)
         iv = ir - n_rq
         upd = jax.lax.dot_general(
@@ -152,9 +158,9 @@ def _decode_ranked_kernel(len_ref, rq_ref, rv_ref, q_ref, k_ref, v_ref,
     @pl.when((it == n_t - 1) & (ir == n_rq + n_rv - 1))
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)             # (G, 1)
-        acc = acc_scr[...]                                 # (n_rv, G, rb)
-        out = acc.transpose(1, 0, 2).reshape(acc.shape[1], n_rv * rb)
-        o_ref[0] = (out / denom).astype(o_ref.dtype)
+        for i in range(n_rv):                              # static
+            o_ref[0, 0, :, i * rb:(i + 1) * rb] = (
+                acc_scr[i] / denom).astype(o_ref.dtype)
 
 
 def flash_decode_ranked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -167,22 +173,22 @@ def flash_decode_ranked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """``flash_decode`` with a scalar-prefetched PER-HEAD rank clamp
     (non-uniform ``RankBudget`` plans, DESIGN.md §14).
 
-    q: (B, H, dq);  k: (B, T, KV, dq);  v: (B, T, KV, dv);  lengths:
-    (B,) int32;  qk_ranks / vo_ranks: (KV,) int32 kept ranks per kv
-    head (values are clamped to the array widths).  dq/dv must be
-    multiples of ``rank_block`` (ops.py pads; zero-padding is exact).
-    -> (B, H, dv)
+    q: (B, KV, G, dq);  k: (B, KV, T, dq);  v: (B, KV, T, dv);
+    lengths: (B,) int32;  qk_ranks / vo_ranks: (KV,) int32 kept ranks
+    per kv head (values are clamped to the array widths).  dq/dv must
+    be multiples of ``rank_block`` (ops.py pads; zero-padding is
+    exact).  -> (B, KV, G, dv)
 
     Rank blocks at or past a head's kept rank cost neither DMA (their
     index maps re-reference the last kept block, which Pallas leaves
-    resident) nor compute (``pl.when``).  On real TPUs keep
-    ``rank_block`` a multiple of the 128 lane width; tests pass small
-    blocks in interpret mode to exercise multi-block clamping.
+    resident) nor compute (``pl.when``).  The TPU lowering requires
+    ``rank_block`` to be a multiple of the 128 lane width or the whole
+    (padded) width; tests pass small blocks in interpret mode to
+    exercise multi-block clamping.
     """
-    B, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    B, KV, G, dq = q.shape
+    T = k.shape[2]
     dv = v.shape[-1]
-    G = H // KV
     rb = rank_block
     assert T % block_t == 0, (T, block_t)
     assert dq % rb == 0 and dv % rb == 0, (dq, dv, rb)
@@ -199,28 +205,29 @@ def flash_decode_ranked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return jnp.maximum((r + rb - 1) // rb, 1)
 
     def _q_block(b, kv, it, ir, lens, rq, rv):
-        return (b, kv, jnp.minimum(ir, _nblk(rq[kv]) - 1))
+        return (b, kv, 0, jnp.minimum(ir, _nblk(rq[kv]) - 1))
 
     def _k_block(b, kv, it, ir, lens, rq, rv):
         n_valid = jnp.maximum((lens[b] + block_t - 1) // block_t, 1)
-        return (b, jnp.minimum(it, n_valid - 1), kv,
+        return (b, kv, jnp.minimum(it, n_valid - 1),
                 jnp.minimum(ir, _nblk(rq[kv]) - 1))
 
     def _v_block(b, kv, it, ir, lens, rq, rv):
         n_valid = jnp.maximum((lens[b] + block_t - 1) // block_t, 1)
-        return (b, jnp.minimum(it, n_valid - 1), kv,
+        return (b, kv, jnp.minimum(it, n_valid - 1),
                 jnp.clip(ir - n_rq, 0, _nblk(rv[kv]) - 1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, KV, n_t, n_rq + n_rv),
         in_specs=[
-            pl.BlockSpec((1, G, rb), _q_block),
-            pl.BlockSpec((1, block_t, 1, rb), _k_block),
-            pl.BlockSpec((1, block_t, 1, rb), _v_block),
+            pl.BlockSpec((1, 1, G, rb), _q_block),
+            pl.BlockSpec((1, 1, block_t, rb), _k_block),
+            pl.BlockSpec((1, 1, block_t, rb), _v_block),
         ],
         out_specs=pl.BlockSpec(
-            (1, G, dv), lambda b, kv, it, ir, lens, rq, rv: (b, kv, 0)),
+            (1, 1, G, dv),
+            lambda b, kv, it, ir, lens, rq, rv: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -232,8 +239,8 @@ def flash_decode_ranked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -247,9 +254,9 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  scale: Optional[float] = None,
                  block_t: int = 256,
                  interpret: bool = False) -> jnp.ndarray:
-    """q: (B, H, dq);  k: (B, T, KV, dq);  v: (B, T, KV, dv);
+    """q: (B, KV, G, dq);  k: (B, KV, T, dq);  v: (B, KV, T, dv);
     lengths: (B,) int32.  T % block_t == 0 (ops.py pads; padded positions
-    are masked by lengths).  -> (B, H, dv)
+    are masked by lengths).  -> (B, KV, G, dv)
 
     ``lengths`` is the only validity signal: positions past it may hold
     anything — zero-init tail, a previous tenant's cache, or K/V of
@@ -257,10 +264,9 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and never influence the output (masked in-block, clamped out of the
     stream across blocks).
     """
-    B, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    B, KV, G, dq = q.shape
+    T = k.shape[2]
     dv = v.shape[-1]
-    G = H // KV
     assert T % block_t == 0, (T, block_t)
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
@@ -274,17 +280,19 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         # the resident block (no DMA) and `pl.when` skips their compute,
         # so streamed bytes are bounded by lengths[b], not capacity.
         n_valid = jnp.maximum((lens[b] + block_t - 1) // block_t, 1)
-        return (b, jnp.minimum(it, n_valid - 1), kv, 0)
+        return (b, kv, jnp.minimum(it, n_valid - 1), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KV, n_t),
         in_specs=[
-            pl.BlockSpec((1, G, dq), lambda b, kv, it, lens: (b, kv, 0)),
-            pl.BlockSpec((1, block_t, 1, dq), _kv_block),
-            pl.BlockSpec((1, block_t, 1, dv), _kv_block),
+            pl.BlockSpec((1, 1, G, dq),
+                         lambda b, kv, it, lens: (b, kv, 0, 0)),
+            pl.BlockSpec((1, 1, block_t, dq), _kv_block),
+            pl.BlockSpec((1, 1, block_t, dv), _kv_block),
         ],
-        out_specs=pl.BlockSpec((1, G, dv), lambda b, kv, it, lens: (b, kv, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, dv),
+                               lambda b, kv, it, lens: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -292,13 +300,11 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ],
     )
 
-    # H is laid out as KV groups of G consecutive query heads, so the
-    # (1, G, dq) block at index kv is exactly group kv's query slab.
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k, v)

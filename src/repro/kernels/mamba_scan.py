@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _mamba_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref,
                   y_ref, hend_ref, h_scr, *, chunk: int, n_c: int):
@@ -49,10 +47,7 @@ def _mamba_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref,
         b = (dt_t * xv[t][:, None]) * Bm[t][None, :]
         h = a * h + b
         y_t = jnp.sum(h * Cm[t][None, :], axis=1)   # (tile,)
-        # jax 0.4.x interpret-mode discharge chokes on bare int indices;
-        # a size-1 Slice is equivalent and portable
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y_t[None, None, :].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y_t[None, :].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_scr[...])
@@ -106,7 +101,7 @@ def mamba_scan(dt: jnp.ndarray, A: jnp.ndarray, Bmat: jnp.ndarray,
             jax.ShapeDtypeStruct((B, dI, dS), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((tile, dS), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, Bmat, C, x, A, h0)

@@ -7,9 +7,16 @@ resolved once per (impl alias, platform, mesh):
 
 * ``impl`` aliases: ``"ref"`` (pure-jnp oracles), ``"xla"`` (model code
   takes its einsum paths; these entry points fall back to the oracles),
-  ``"pallas"`` (native Pallas; resolves to ``"interpret"`` off
-  TPU/GPU, where no native lowering exists), ``"interpret"`` (Pallas
-  kernels in interpret mode — the CPU validation path).
+  ``"pallas"`` (native Pallas; raises on a platform with no native
+  lowering — it never silently becomes the interpreter),
+  ``"interpret"`` (Pallas kernels in interpret mode — the CPU
+  validation path, an explicit choice).
+* Layout: KV caches and page pools are HEAD-MAJOR — dense ``(B, KV,
+  T, r)``, paged ``(N, KV, page_tokens, r)`` — and the decode kernels
+  take queries as ``(B, KV, G, d)``; these entry points accept the
+  model's ``(B, H, d)`` queries and reshape them (free: H is KV groups
+  of G consecutive heads).  Full-sequence attention keeps token-major
+  activations at this API and transposes them for the kernel.
 * ``mesh``: when set, the serving hot-path kernels (flash-decode,
   paged-decode, page-copy, full-sequence attention) run PER SHARD
   under ``shard_map`` with serve-rules operand specs (slot batch over
@@ -66,58 +73,86 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int,
 # every padded/blocked axis — seq, pages, rank — is unsharded)
 # ---------------------------------------------------------------------------
 
+def _group(q, kv_heads: int):
+    """(B, H, d) queries -> the kernels' (B, KV, G, d)."""
+    B, H, d = q.shape
+    return q.reshape(B, kv_heads, H // kv_heads, d)
+
+
+def _ungroup(out):
+    B, KV, G, d = out.shape
+    return out.reshape(B, KV * G, d)
+
+
 def _clover_body(q, k, v, *, causal, scale, block_q, block_k, interpret):
     S, T = q.shape[1], k.shape[1]
     bq = min(block_q, max(8, S))
     bk = min(block_k, max(8, T))
-    qp = _pad_to(q, 1, bq)
-    kp = _pad_to(k, 1, bk)
-    vp = _pad_to(v, 1, bk)
+    # token-major activations -> the kernel's head-major blocks
+    qp = _pad_to(q, 1, bq).transpose(0, 2, 1, 3)
+    kp = _pad_to(k, 1, bk).transpose(0, 2, 1, 3)
+    vp = _pad_to(v, 1, bk).transpose(0, 2, 1, 3)
     # padded K tail is masked only by causality -> require causal when padded
     assert causal or (S % bq == 0 and T % bk == 0), \
         "non-causal pallas path requires block-aligned shapes"
     out = _flash(qp, kp, vp, causal=causal, scale=scale, block_q=bq,
                  block_k=bk, interpret=interpret)
-    return out[:, :S]
+    return out.transpose(0, 2, 1, 3)[:, :S]
 
 
 def _decode_body(q, k, v, lengths, *, scale, block_t, interpret):
-    T = k.shape[1]
+    T = k.shape[2]
     bt = min(block_t, max(8, T))
-    kp = _pad_to(k, 1, bt)
-    vp = _pad_to(v, 1, bt)
-    return _decode(q, kp, vp, lengths, scale=scale, block_t=bt,
-                   interpret=interpret)
+    kp = _pad_to(k, 2, bt)
+    vp = _pad_to(v, 2, bt)
+    return _ungroup(_decode(_group(q, k.shape[1]), kp, vp, lengths,
+                            scale=scale, block_t=bt, interpret=interpret))
+
+
+def _rank_block(rank_block: int, dq: int, dv: int) -> int:
+    """The rank block both ranked kernels walk: ``rank_block`` (a lane
+    multiple) for wide heads, else the whole width — the only block
+    widths the TPU lowering accepts."""
+    return min(rank_block, max(8, max(dq, dv)))
+
+
+def _paged_decode_body(q, k_pool, v_pool, page_table, lengths, *, scale,
+                       interpret):
+    return _ungroup(_paged_decode(_group(q, k_pool.shape[1]), k_pool,
+                                  v_pool, page_table, lengths, scale=scale,
+                                  interpret=interpret))
 
 
 def _decode_ranked_body(q, k, v, lengths, qk_ranks, vo_ranks, *,
                         scale, block_t, rank_block, interpret):
     # Rank-dim zero-padding to block multiples is exact under the
     # mask_head_ranks convention (zeroed dims contribute exactly 0).
-    T, dq, dv = k.shape[1], q.shape[-1], v.shape[-1]
+    T, dq, dv = k.shape[2], q.shape[-1], v.shape[-1]
     bt = min(block_t, max(8, T))
-    rb = min(rank_block, max(8, max(dq, dv)))
+    rb = _rank_block(rank_block, dq, dv)
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
     out = _decode_ranked(
-        _pad_to(q, -1, rb), _pad_to(_pad_to(k, 1, bt), -1, rb),
-        _pad_to(_pad_to(v, 1, bt), -1, rb), lengths, qk_ranks, vo_ranks,
+        _group(_pad_to(q, -1, rb), k.shape[1]),
+        _pad_to(_pad_to(k, 2, bt), -1, rb),
+        _pad_to(_pad_to(v, 2, bt), -1, rb), lengths, qk_ranks, vo_ranks,
         scale=scale, block_t=bt, rank_block=rb, interpret=interpret)
-    return out[..., :dv]
+    return _ungroup(out)[..., :dv]
 
 
 def _paged_decode_ranked_body(q, k_pool, v_pool, page_table, lengths,
                               qk_ranks, vo_ranks, *, scale, rank_block,
                               interpret):
     dq, dv = q.shape[-1], v_pool.shape[-1]
-    rb = min(rank_block, max(8, max(dq, dv)))
+    rb = _rank_block(rank_block, dq, dv)
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
     out = _paged_decode_ranked(
-        _pad_to(q, -1, rb), _pad_to(k_pool, -1, rb),
-        _pad_to(v_pool, -1, rb), page_table, lengths, qk_ranks, vo_ranks,
-        scale=scale, rank_block=rb, interpret=interpret)
-    return out[..., :dv]
+        _group(_pad_to(q, -1, rb), k_pool.shape[1]),
+        _pad_to(k_pool, -1, rb), _pad_to(v_pool, -1, rb), page_table,
+        lengths, qk_ranks, vo_ranks, scale=scale, rank_block=rb,
+        interpret=interpret)
+    return _ungroup(out)[..., :dv]
 
 
 # ---------------------------------------------------------------------------
@@ -131,15 +166,12 @@ class KernelDispatch:
     Built by ``resolve()`` and threaded through ``ArchConfig
     .kernel_impl`` / ``attn_impl`` in place of the old bare strings
     (both forms remain accepted — ``resolve`` is idempotent).  ``impl``
-    is the canonical backend; ``requested`` records the alias resolve()
-    was handed (e.g. "pallas" that canonicalized to "interpret" on
-    CPU).  With ``mesh`` set, the hot-path methods run under
-    ``shard_map`` per shard; hashable, so configs holding one stay
-    hashable.
+    is the backend that runs.  With ``mesh`` set, the hot-path methods
+    run under ``shard_map`` per shard; hashable, so configs holding one
+    stay hashable.
     """
     impl: str
     mesh: Optional[Mesh] = None
-    requested: str = ""
 
     def __post_init__(self):
         if self.impl not in IMPLS:
@@ -203,7 +235,7 @@ class KernelDispatch:
                          rank_block: int = 128) -> jnp.ndarray:
         """Flash-decoding vs a (possibly CLOVER-rank) KV cache.
 
-        q (B,H,dq), k (B,T,KV,dq), v (B,T,KV,dv), lengths (B,)
+        q (B,H,dq), k (B,KV,T,dq), v (B,KV,T,dv), lengths (B,)
         -> (B,H,dv).  With ``qk_ranks``/``vo_ranks`` ((KV,) int32,
         both or neither) the per-head rank-clamped kernel runs instead
         (non-uniform ``RankBudget`` plans, DESIGN.md §14); under a
@@ -214,12 +246,12 @@ class KernelDispatch:
             return _ref.decode_attention_ref(q, k, v, lengths, scale=scale,
                                              qk_ranks=qk_ranks,
                                              vo_ranks=vo_ranks)
-        b, m = self._axes(batch=q.shape[0], kv_heads=k.shape[2])
+        b, m = self._axes(batch=q.shape[0], kv_heads=k.shape[1])
         if ranked:
             dq, dv = q.shape[-1], v.shape[-1]
-            qk_ranks = (jnp.full((k.shape[2],), dq, jnp.int32)
+            qk_ranks = (jnp.full((k.shape[1],), dq, jnp.int32)
                         if qk_ranks is None else qk_ranks.astype(jnp.int32))
-            vo_ranks = (jnp.full((k.shape[2],), dv, jnp.int32)
+            vo_ranks = (jnp.full((k.shape[1],), dv, jnp.int32)
                         if vo_ranks is None else vo_ranks.astype(jnp.int32))
             body = functools.partial(_decode_ranked_body, scale=scale,
                                      block_t=block_t, rank_block=rank_block,
@@ -227,8 +259,8 @@ class KernelDispatch:
             if b is None and m is None:
                 return body(q, k, v, lengths, qk_ranks, vo_ranks)
             fn = self._shard(body,
-                             in_specs=(P(b, m, None), P(b, None, m, None),
-                                       P(b, None, m, None), P(b), P(m),
+                             in_specs=(P(b, m, None), P(b, m, None, None),
+                                       P(b, m, None, None), P(b), P(m),
                                        P(m)),
                              out_specs=P(b, m, None))
             return fn(q, k, v, lengths, qk_ranks, vo_ranks)
@@ -237,8 +269,8 @@ class KernelDispatch:
         if b is None and m is None:
             return body(q, k, v, lengths)
         fn = self._shard(body,
-                         in_specs=(P(b, m, None), P(b, None, m, None),
-                                   P(b, None, m, None), P(b)),
+                         in_specs=(P(b, m, None), P(b, m, None, None),
+                                   P(b, m, None, None), P(b)),
                          out_specs=P(b, m, None))
         return fn(q, k, v, lengths)
 
@@ -250,8 +282,9 @@ class KernelDispatch:
                                rank_block: int = 128) -> jnp.ndarray:
         """Flash-decoding vs a PAGED (possibly CLOVER-rank) KV cache.
 
-        q (B,H,dq), k_pool (N,page_tokens,KV,dq), v_pool (N,page_tokens,
-        KV,dv), page_table (B,n_p) int32, lengths (B,) -> (B,H,dv).
+        q (B,H,dq), k_pool (N,KV,page_tokens,dq), v_pool (N,KV,
+        page_tokens,dv), page_table (B,n_p) int32, lengths (B,) ->
+        (B,H,dv).
 
         No padding is needed: the pool's ``page_tokens`` axis IS the
         block size, and page-table entries past each slot's in-use
@@ -271,10 +304,10 @@ class KernelDispatch:
                                                    scale=scale,
                                                    qk_ranks=qk_ranks,
                                                    vo_ranks=vo_ranks)
-        b, m = self._axes(batch=q.shape[0], kv_heads=k_pool.shape[2])
+        b, m = self._axes(batch=q.shape[0], kv_heads=k_pool.shape[1])
         if ranked:
             dq, dv = q.shape[-1], v_pool.shape[-1]
-            KV = k_pool.shape[2]
+            KV = k_pool.shape[1]
             qk_ranks = (jnp.full((KV,), dq, jnp.int32)
                         if qk_ranks is None else qk_ranks.astype(jnp.int32))
             vo_ranks = (jnp.full((KV,), dv, jnp.int32)
@@ -286,19 +319,19 @@ class KernelDispatch:
                 return body(q, k_pool, v_pool, page_table, lengths,
                             qk_ranks, vo_ranks)
             fn = self._shard(body,
-                             in_specs=(P(b, m, None), P(None, None, m, None),
-                                       P(None, None, m, None), P(b, None),
+                             in_specs=(P(b, m, None), P(None, m, None, None),
+                                       P(None, m, None, None), P(b, None),
                                        P(b), P(m), P(m)),
                              out_specs=P(b, m, None))
             return fn(q, k_pool, v_pool, page_table, lengths, qk_ranks,
                       vo_ranks)
-        body = functools.partial(_paged_decode, scale=scale,
+        body = functools.partial(_paged_decode_body, scale=scale,
                                  interpret=self.interpret)
         if b is None and m is None:
             return body(q, k_pool, v_pool, page_table, lengths)
         fn = self._shard(body,
-                         in_specs=(P(b, m, None), P(None, None, m, None),
-                                   P(None, None, m, None), P(b, None),
+                         in_specs=(P(b, m, None), P(None, m, None, None),
+                                   P(None, m, None, None), P(b, None),
                                    P(b)),
                          out_specs=P(b, m, None))
         return fn(q, k_pool, v_pool, page_table, lengths)
@@ -307,7 +340,7 @@ class KernelDispatch:
         """Batched KV-page clone — the device half of copy-on-write
         prefix caching (serve.engine, DESIGN.md §9).
 
-        pool (n_blocks, N, page_tokens, KV, r), src/dst (m,) int32
+        pool (n_blocks, N, KV, page_tokens, r), src/dst (m,) int32
         pool-row ids -> pool with row ``dst[i]`` a copy of row
         ``src[i]``, all other rows untouched.  Pure DMA, no compute.
         On the non-kernel paths this is the jnp oracle ("xla" included
@@ -319,12 +352,12 @@ class KernelDispatch:
             return _ref.page_copy_ref(pool, src, dst)
         from repro.kernels.page_copy import page_copy as _page_copy
         body = functools.partial(_page_copy, interpret=self.interpret)
-        _, m = self._axes(batch=1, kv_heads=pool.shape[3])
+        _, m = self._axes(batch=1, kv_heads=pool.shape[2])
         if m is None:
             return body(pool, src, dst)
         fn = self._shard(body,
-                         in_specs=(P(None, None, None, m, None), P(), P()),
-                         out_specs=P(None, None, None, m, None))
+                         in_specs=(P(None, None, m, None, None), P(), P()),
+                         out_specs=P(None, None, m, None, None))
         return fn(pool, src, dst)
 
     def page_restore(self, pool, rows, dst) -> jnp.ndarray:
@@ -332,8 +365,8 @@ class KernelDispatch:
         content into pool rows (hierarchical KV, serve.memory
         ``HostTier``; DESIGN.md §12).
 
-        pool (n_blocks, N, page_tokens, KV, r), rows (n_blocks, W,
-        page_tokens, KV, r), dst (W,) int32 pool-row ids -> pool with
+        pool (n_blocks, N, KV, page_tokens, r), rows (n_blocks, W, KV,
+        page_tokens, r), dst (W,) int32 pool-row ids -> pool with
         row ``dst[i]`` holding ``rows[:, i]``, all other rows
         untouched.  Pure DMA, no compute.  On the non-kernel paths
         this is the jnp oracle ("xla" included — there is no einsum
@@ -345,13 +378,13 @@ class KernelDispatch:
             return _ref.page_restore_ref(pool, rows, dst)
         from repro.kernels.page_copy import page_restore as _page_restore
         body = functools.partial(_page_restore, interpret=self.interpret)
-        _, m = self._axes(batch=1, kv_heads=pool.shape[3])
+        _, m = self._axes(batch=1, kv_heads=pool.shape[2])
         if m is None:
             return body(pool, rows, dst)
         fn = self._shard(body,
-                         in_specs=(P(None, None, None, m, None),
-                                   P(None, None, None, m, None), P()),
-                         out_specs=P(None, None, None, m, None))
+                         in_specs=(P(None, None, m, None, None),
+                                   P(None, None, m, None, None), P()),
+                         out_specs=P(None, None, m, None, None))
         return fn(pool, rows, dst)
 
     # -- recurrent kernels (never shard_map'd: cross-step state) -------
@@ -401,19 +434,24 @@ def _resolve(impl: str, mesh: Optional[Mesh]) -> KernelDispatch:
         raise ValueError(f"unknown kernel impl {impl!r}: expected one "
                          f"of {IMPLS} (or an already-resolved "
                          "KernelDispatch)")
-    canon = impl
-    if impl == "pallas" and jax.local_devices()[0].platform not in (
-            "tpu", "gpu"):
-        canon = "interpret"     # no native Pallas lowering here
-    return KernelDispatch(impl=canon, mesh=mesh, requested=impl)
+    if impl == "pallas":
+        platform = jax.local_devices()[0].platform
+        if platform != "tpu":
+            raise ValueError(
+                f"kernel impl 'pallas' needs a TPU; this process runs on "
+                f"{platform!r}.  Pass 'interpret' to run the Pallas "
+                "kernels in interpret mode (tests) or 'xla' for the "
+                "einsum paths.")
+    return KernelDispatch(impl=impl, mesh=mesh)
 
 
 def resolve(impl: Union[str, KernelDispatch],
             mesh: Optional[Mesh] = None) -> KernelDispatch:
     """impl alias (or already-resolved dispatch) -> ``KernelDispatch``.
 
-    Cached per (alias, mesh) and resolved against the local platform
-    once.  Idempotent: a ``KernelDispatch`` passes straight through
+    Cached per (alias, mesh) and checked against the local platform
+    once: ``"pallas"`` off a TPU raises.  Idempotent: a
+    ``KernelDispatch`` passes straight through
     (gaining ``mesh`` only if it had none), so config fields may hold
     either form and every consumer just calls ``resolve`` again.
     Unknown aliases raise ``ValueError`` here — at config time, not at
@@ -455,7 +493,7 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
                      impl: str = "ref") -> jnp.ndarray:
     """Flash-decoding vs a (possibly CLOVER-rank) KV cache.
 
-    q (B,H,dq), k (B,T,KV,dq), v (B,T,KV,dv), lengths (B,) -> (B,H,dv).
+    q (B,H,dq), k (B,KV,T,dq), v (B,KV,T,dv), lengths (B,) -> (B,H,dv).
     qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
     (non-uniform ``RankBudget`` plans, DESIGN.md §14).
     """
@@ -474,7 +512,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
                            impl: str = "ref") -> jnp.ndarray:
     """Flash-decoding vs a PAGED (possibly CLOVER-rank) KV cache.
 
-    q (B,H,dq), k_pool (N,page_tokens,KV,dq), v_pool (N,page_tokens,KV,dv),
+    q (B,H,dq), k_pool (N,KV,page_tokens,dq), v_pool (N,KV,page_tokens,dv),
     page_table (B,n_p) int32, lengths (B,) -> (B,H,dv).
     qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
     (non-uniform ``RankBudget`` plans, DESIGN.md §14).
@@ -491,7 +529,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
 def page_copy(pool, src, dst, *, impl: str = "ref") -> jnp.ndarray:
     """Batched KV-page clone (copy-on-write prefix caching).
 
-    pool (n_blocks, N, page_tokens, KV, r), src/dst (m,) int32 pool-row
+    pool (n_blocks, N, KV, page_tokens, r), src/dst (m,) int32 pool-row
     ids -> pool with row ``dst[i]`` a copy of row ``src[i]``.
     """
     return resolve(impl).page_copy(pool, src, dst)
@@ -501,8 +539,8 @@ def page_copy(pool, src, dst, *, impl: str = "ref") -> jnp.ndarray:
 def page_restore(pool, rows, dst, *, impl: str = "ref") -> jnp.ndarray:
     """Batched host-tier page restore (hierarchical KV spill/restore).
 
-    pool (n_blocks, N, page_tokens, KV, r), rows (n_blocks, W,
-    page_tokens, KV, r), dst (W,) int32 pool-row ids -> pool with row
+    pool (n_blocks, N, KV, page_tokens, r), rows (n_blocks, W, KV,
+    page_tokens, r), dst (W,) int32 pool-row ids -> pool with row
     ``dst[i]`` holding ``rows[:, i]``.
     """
     return resolve(impl).page_restore(pool, rows, dst)

@@ -3,7 +3,7 @@ caching AND of the host-RAM spill tier's restore path (DESIGN.md §9,
 §12; serve.memory / serve.engine).
 
 Two entry points share one shape contract — a grid of row-to-row
-(page_tokens, KV, r) slab moves over the layer-stacked pool, driven by
+(KV, page_tokens, r) slab moves over the layer-stacked pool, driven by
 scalar-prefetched page-id vectors, exactly the indirection idiom of
 ``paged_decode_attention.py`` (the BlockSpec index maps dereference the
 id vectors BEFORE the body runs, so each slab streams straight to its
@@ -27,7 +27,7 @@ in one batch; see ``Engine._copy_pages``).  Padding a short batch is
 legal in both directions: sentinel->sentinel self-copies are no-ops,
 and restore padding scatters all-zero slabs into the garbage row.
 
-Pool rows are (page_tokens, KV, r) slabs; on real TPUs keep
+Pool rows are head-major (KV, page_tokens, r) slabs; on real TPUs keep
 ``page_tokens`` a multiple of the dtype sublane tile (8 for f32, 16
 for bf16) — the same layout rule the paged decode kernel already
 imposes.  Tests run interpret mode where any size is legal.
@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _page_copy_kernel(src_ref, dst_ref, in_ref, out_ref):
     del src_ref, dst_ref          # consumed by the BlockSpec index maps
@@ -49,13 +47,13 @@ def _page_copy_kernel(src_ref, dst_ref, in_ref, out_ref):
 
 def page_copy(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray, *,
               interpret: bool = False) -> jnp.ndarray:
-    """pool: (n_blocks, N, page_tokens, KV, r) — one layer-stacked KV
+    """pool: (n_blocks, N, KV, page_tokens, r) — one layer-stacked KV
     pool leaf;  src, dst: (m,) int32 pool-row ids (pairs disjoint
     except sentinel self-copy padding).  Returns the pool with row
     ``dst[i]`` holding a copy of row ``src[i]`` for every i, all other
     rows untouched.  -> same shape/dtype as ``pool``.
     """
-    n_blocks, N, pt, KV, r = pool.shape
+    n_blocks, N, KV, pt, r = pool.shape
     m = src.shape[0]
 
     def _src_block(i, b, src, dst):
@@ -69,8 +67,8 @@ def page_copy(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray, *,
         # pairs are the OUTER (sequential) axis so pair i+1 reads pair
         # i's writes if the host ever chains them; blocks inner
         grid=(m, n_blocks),
-        in_specs=[pl.BlockSpec((1, 1, pt, KV, r), _src_block)],
-        out_specs=pl.BlockSpec((1, 1, pt, KV, r), _dst_block),
+        in_specs=[pl.BlockSpec((1, 1, KV, pt, r), _src_block)],
+        out_specs=pl.BlockSpec((1, 1, KV, pt, r), _dst_block),
     )
     return pl.pallas_call(
         _page_copy_kernel,
@@ -80,7 +78,7 @@ def page_copy(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray, *,
         # the copy is in-place on TPU (index 2 = pool, after the two
         # scalar-prefetch operands)
         input_output_aliases={2: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(src.astype(jnp.int32), dst.astype(jnp.int32), pool)
@@ -93,15 +91,15 @@ def _page_restore_kernel(dst_ref, rows_ref, pool_ref, out_ref):
 
 def page_restore(pool: jnp.ndarray, rows: jnp.ndarray, dst: jnp.ndarray,
                  *, interpret: bool = False) -> jnp.ndarray:
-    """pool: (n_blocks, N, page_tokens, KV, r) — one layer-stacked KV
-    pool leaf;  rows: (n_blocks, W, page_tokens, KV, r) — externally
+    """pool: (n_blocks, N, KV, page_tokens, r) — one layer-stacked KV
+    pool leaf;  rows: (n_blocks, W, KV, page_tokens, r) — externally
     sourced slab content (host-tier restore);  dst: (W,) int32 pool-row
     ids (freshly-allocated pages; padding entries repeat the sentinel
     row with zero slabs).  Returns the pool with row ``dst[i]`` holding
     ``rows[:, i]`` for every i, all other rows untouched.  -> same
     shape/dtype as ``pool``.
     """
-    n_blocks, N, pt, KV, r = pool.shape
+    n_blocks, N, KV, pt, r = pool.shape
     W = rows.shape[1]
 
     def _rows_block(i, b, dst):
@@ -113,9 +111,9 @@ def page_restore(pool: jnp.ndarray, rows: jnp.ndarray, dst: jnp.ndarray,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(W, n_blocks),
-        in_specs=[pl.BlockSpec((1, 1, pt, KV, r), _rows_block),
-                  pl.BlockSpec((1, 1, pt, KV, r), _dst_block)],
-        out_specs=pl.BlockSpec((1, 1, pt, KV, r), _dst_block),
+        in_specs=[pl.BlockSpec((1, 1, KV, pt, r), _rows_block),
+                  pl.BlockSpec((1, 1, KV, pt, r), _dst_block)],
+        out_specs=pl.BlockSpec((1, 1, KV, pt, r), _dst_block),
     )
     return pl.pallas_call(
         _page_restore_kernel,
@@ -125,7 +123,7 @@ def page_restore(pool: jnp.ndarray, rows: jnp.ndarray, dst: jnp.ndarray,
         # the scatter is in-place on TPU (index 2 = pool, after the
         # scalar-prefetch operand and the rows input)
         input_output_aliases={2: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(dst.astype(jnp.int32), rows.astype(pool.dtype), pool)

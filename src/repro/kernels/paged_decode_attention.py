@@ -2,12 +2,15 @@
 CLOVER-rank KV cache (vLLM-style page pool + per-slot page tables).
 
 The dense `flash_decode` streams a per-slot cache of shape
-``(B, capacity, KV, r)`` — every slot reserves (and, before the
+``(B, KV, capacity, r)`` — every slot reserves (and, before the
 index-map clamp, streamed) full capacity regardless of actual length.
-Here the cache is one global pool ``(n_pages + 1, page_tokens, KV, r)``
+Here the cache is one global pool ``(n_pages + 1, KV, page_tokens, r)``
 shared by all slots; each slot owns an ordered list of page ids (its
 page table row) and positions map through the indirection
-``pool[table[b, p // page_tokens], p % page_tokens]``.  Rank pruning
+``pool[table[b, p // page_tokens], :, p % page_tokens]``.  The pool is
+HEAD-MAJOR within a page so each streamed block ``(page_tokens, r)``
+spans its array's last two dims whole, as the TPU lowering requires
+(DESIGN.md §4, §6).  Rank pruning
 composes with paging: smaller r -> more tokens per HBM byte -> more
 resident sequences per pool (DESIGN.md §6).
 
@@ -28,8 +31,9 @@ Kernel schedule — grid ``(B, KV, n_p)`` with the page axis sequential:
   * Entries past a slot's in-use pages may be a sentinel id (the pool's
     spare garbage row); the clamp means they are never dereferenced.
 
-Per (batch, kv-head) the whole GQA group's (G, dq) query slab stays
-resident in VMEM across the page stream, same as the dense kernel.
+Per (batch, kv-head) the whole GQA group's (G, dq) query slab (queries
+arrive as ``(B, KV, G, dq)``) stays resident in VMEM across the page
+stream, same as the dense kernel.
 
 Page size: ``page_tokens`` is the sublane dim of the streamed slabs, so
 keep it a multiple of the dtype tile (8 for f32, 16 for bf16) on real
@@ -44,8 +48,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = float(-1e30)
 
@@ -67,9 +69,9 @@ def _paged_decode_kernel(len_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(to < length)
     def _body():
-        q = q_ref[0]                                           # (G, dq)
-        k = k_ref[0, :, 0, :]                                  # (pt, dq)
-        v = v_ref[0, :, 0, :]                                  # (pt, dv)
+        q = q_ref[0, 0]                                        # (G, dq)
+        k = k_ref[0, 0]                                        # (pt, dq)
+        v = v_ref[0, 0]                                        # (pt, dv)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale        # (G, pt)
@@ -88,7 +90,7 @@ def _paged_decode_kernel(len_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ip == n_p - 1)
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _paged_decode_ranked_kernel(len_ref, tab_ref, rq_ref, rv_ref, q_ref,
@@ -126,8 +128,8 @@ def _paged_decode_ranked_kernel(len_ref, tab_ref, rq_ref, rv_ref, q_ref,
 
     @pl.when(live & (ir < n_rq) & (ir * rb < rq_ref[kv]))
     def _k_phase():
-        q = q_ref[0]                                       # (G, rb)
-        k = k_ref[0, :, 0, :]                              # (pt, rb)
+        q = q_ref[0, 0]                                    # (G, rb)
+        k = k_ref[0, 0]                                    # (pt, rb)
         p_scr[...] += jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -148,7 +150,7 @@ def _paged_decode_ranked_kernel(len_ref, tab_ref, rq_ref, rv_ref, q_ref,
 
     @pl.when(live & (ir >= n_rq) & ((ir - n_rq) * rb < rv_ref[kv]))
     def _v_phase():
-        v = v_ref[0, :, 0, :]                              # (pt, rb)
+        v = v_ref[0, 0]                                    # (pt, rb)
         p = p_scr[...]                                     # (G, pt)
         iv = ir - n_rq
         upd = jax.lax.dot_general(
@@ -159,9 +161,9 @@ def _paged_decode_ranked_kernel(len_ref, tab_ref, rq_ref, rv_ref, q_ref,
     @pl.when((ip == n_p - 1) & (ir == n_rq + n_rv - 1))
     def _fin():
         denom = jnp.maximum(l_scr[...], 1e-30)             # (G, 1)
-        acc = acc_scr[...]                                 # (n_rv, G, rb)
-        out = acc.transpose(1, 0, 2).reshape(acc.shape[1], n_rv * rb)
-        o_ref[0] = (out / denom).astype(o_ref.dtype)
+        for i in range(n_rv):                              # static
+            o_ref[0, 0, :, i * rb:(i + 1) * rb] = (
+                acc_scr[i] / denom).astype(o_ref.dtype)
 
 
 def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -174,18 +176,19 @@ def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
     """``paged_flash_decode`` with a scalar-prefetched PER-HEAD rank
     clamp for non-uniform ``RankBudget`` plans (DESIGN.md §14).
 
-    qk_ranks / vo_ranks: (KV,) int32 kept ranks per kv head (values
-    clamped to the pool widths).  dq/dv must be multiples of
-    ``rank_block`` (ops.py pads; the ``mask_head_ranks`` zero-pad
-    convention makes padding exact).  Rank blocks at or past a head's
+    q: (B, KV, G, dq);  pools (N, KV, page_tokens, dq|dv);  qk_ranks /
+    vo_ranks: (KV,) int32 kept ranks per kv head (values clamped to the
+    pool widths).  dq/dv must be multiples of ``rank_block`` (ops.py
+    pads; the ``mask_head_ranks`` zero-pad convention makes padding
+    exact), and the TPU lowering wants ``rank_block`` a multiple of 128
+    or the whole padded width.  -> (B, KV, G, dv)  Rank blocks at or past a head's
     kept rank revisit the resident block (no DMA) and ``pl.when``
     skips their compute, so a pruned head's rank tail is free — the
     rank analogue of the post-rollback length clamp below.
     """
-    B, H, dq = q.shape
-    pt, KV = k_pool.shape[1], k_pool.shape[2]
+    B, KV, G, dq = q.shape
+    pt = k_pool.shape[2]
     dv = v_pool.shape[-1]
-    G = H // KV
     rb = rank_block
     n_p = page_table.shape[1]
     assert dq % rb == 0 and dv % rb == 0, (dq, dv, rb)
@@ -201,28 +204,29 @@ def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
         return jnp.maximum((r + rb - 1) // rb, 1)
 
     def _q_block(b, kv, ip, ir, lens, tab, rq, rv):
-        return (b, kv, jnp.minimum(ir, _nblk(rq[kv]) - 1))
+        return (b, kv, 0, jnp.minimum(ir, _nblk(rq[kv]) - 1))
 
     def _k_block(b, kv, ip, ir, lens, tab, rq, rv):
         n_used = jnp.maximum((lens[b] + pt - 1) // pt, 1)
-        return (tab[b, jnp.minimum(ip, n_used - 1)], 0, kv,
+        return (tab[b, jnp.minimum(ip, n_used - 1)], kv, 0,
                 jnp.minimum(ir, _nblk(rq[kv]) - 1))
 
     def _v_block(b, kv, ip, ir, lens, tab, rq, rv):
         n_used = jnp.maximum((lens[b] + pt - 1) // pt, 1)
-        return (tab[b, jnp.minimum(ip, n_used - 1)], 0, kv,
+        return (tab[b, jnp.minimum(ip, n_used - 1)], kv, 0,
                 jnp.clip(ir - n_rq, 0, _nblk(rv[kv]) - 1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, KV, n_p, n_rq + n_rv),
         in_specs=[
-            pl.BlockSpec((1, G, rb), _q_block),
-            pl.BlockSpec((1, pt, 1, rb), _k_block),
-            pl.BlockSpec((1, pt, 1, rb), _v_block),
+            pl.BlockSpec((1, 1, G, rb), _q_block),
+            pl.BlockSpec((1, 1, pt, rb), _k_block),
+            pl.BlockSpec((1, 1, pt, rb), _v_block),
         ],
         out_specs=pl.BlockSpec(
-            (1, G, dv), lambda b, kv, ip, ir, lens, tab, rq, rv: (b, kv, 0)),
+            (1, 1, G, dv),
+            lambda b, kv, ip, ir, lens, tab, rq, rv: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -234,8 +238,8 @@ def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -249,11 +253,11 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
                        lengths: jnp.ndarray, *,
                        scale: Optional[float] = None,
                        interpret: bool = False) -> jnp.ndarray:
-    """q: (B, H, dq);  k_pool: (N, page_tokens, KV, dq);
-    v_pool: (N, page_tokens, KV, dv);  page_table: (B, n_p) int32 page
+    """q: (B, KV, G, dq);  k_pool: (N, KV, page_tokens, dq);
+    v_pool: (N, KV, page_tokens, dv);  page_table: (B, n_p) int32 page
     ids into the pool (entries past ceil(lengths[b]/page_tokens) are
     never dereferenced and may be any in-range id, e.g. a garbage-sink
-    sentinel);  lengths: (B,) int32.  -> (B, H, dv)
+    sentinel);  lengths: (B,) int32.  -> (B, KV, G, dv)
 
     POST-ROLLBACK contract (speculative decoding, serve.engine): after
     a verify round rejects draft tokens, ``lengths`` decrements while
@@ -264,10 +268,9 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
     a row's allocated page count may exceed ``ceil(lengths[b] /
     page_tokens)`` freely.
     """
-    B, H, dq = q.shape
-    pt, KV = k_pool.shape[1], k_pool.shape[2]
+    B, KV, G, dq = q.shape
+    pt = k_pool.shape[2]
     dv = v_pool.shape[-1]
-    G = H // KV
     n_p = page_table.shape[1]
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
@@ -279,18 +282,19 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
         # Clamp to the row's last in-use page: tail iterations revisit
         # the resident block (no DMA), pl.when skips their compute.
         n_used = jnp.maximum((lens[b] + pt - 1) // pt, 1)
-        return (tab[b, jnp.minimum(ip, n_used - 1)], 0, kv, 0)
+        return (tab[b, jnp.minimum(ip, n_used - 1)], kv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, n_p),
         in_specs=[
-            pl.BlockSpec((1, G, dq), lambda b, kv, ip, lens, tab: (b, kv, 0)),
-            pl.BlockSpec((1, pt, 1, dq), _page_block),
-            pl.BlockSpec((1, pt, 1, dv), _page_block),
+            pl.BlockSpec((1, 1, G, dq),
+                         lambda b, kv, ip, lens, tab: (b, kv, 0, 0)),
+            pl.BlockSpec((1, 1, pt, dq), _page_block),
+            pl.BlockSpec((1, 1, pt, dv), _page_block),
         ],
-        out_specs=pl.BlockSpec((1, G, dv),
-                               lambda b, kv, ip, lens, tab: (b, kv, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, dv),
+                               lambda b, kv, ip, lens, tab: (b, kv, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
@@ -298,13 +302,11 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
         ],
     )
 
-    # H is laid out as KV groups of G consecutive query heads, so the
-    # (1, G, dq) block at index kv is exactly group kv's query slab.
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q,

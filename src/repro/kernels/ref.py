@@ -2,7 +2,9 @@
 behind DESIGN.md §4's kernels and the §9/§12 page movers).
 
 These are the semantics the kernels must reproduce bit-approximately;
-tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.
+tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.  KV caches
+and page pools are head-major, as the kernels store them: dense
+``(B, KV, T, r)``, paged ``(N, KV, page_tokens, r)``.
 """
 from __future__ import annotations
 
@@ -38,7 +40,7 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 def _rank_mask(x: jnp.ndarray, ranks: Optional[jnp.ndarray]) -> jnp.ndarray:
-    """Zero dims >= ranks[kv] of a (..., KV, d) array — the exact-
+    """Zero dims >= ranks[kv] of a (B, KV, T, d) cache — the exact-
     truncation oracle semantics of a non-uniform per-head rank plan
     (DESIGN.md §14).  The rank-clamped kernels skip whole
     ``rank_block``-wide blocks instead; both agree whenever every rank
@@ -49,7 +51,7 @@ def _rank_mask(x: jnp.ndarray, ranks: Optional[jnp.ndarray]) -> jnp.ndarray:
         return x
     d = x.shape[-1]
     keep = jnp.arange(d)[None, :] < jnp.minimum(ranks, d)[:, None]  # (KV, d)
-    return jnp.where(keep, x, jnp.zeros((), x.dtype))
+    return jnp.where(keep[:, None, :], x, jnp.zeros((), x.dtype))
 
 
 def decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -60,7 +62,7 @@ def decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          ) -> jnp.ndarray:
     """Single-token flash-decoding oracle.
 
-    q: (B, H, dq);  k: (B, T, KV, dq);  v: (B, T, KV, dv);
+    q: (B, H, dq);  k: (B, KV, T, dq);  v: (B, KV, T, dv);
     lengths: (B,) int32 — positions >= length are masked.
     qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
     (non-uniform ``RankBudget`` plans, DESIGN.md §14): K dims >=
@@ -70,18 +72,18 @@ def decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     -> (B, H, dv)
     """
     B, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    KV, T = k.shape[1], k.shape[2]
     G = H // KV
     if scale is None:
         scale = 1.0 / jnp.sqrt(dq).astype(jnp.float32)
     k = _rank_mask(k, qk_ranks)
     v = _rank_mask(v, vo_ranks)
     qg = q.reshape(B, KV, G, dq)
-    logits = jnp.einsum("bkgq,btkq->bkgt", qg, k).astype(jnp.float32) * scale
+    logits = jnp.einsum("bkgq,bktq->bkgt", qg, k).astype(jnp.float32) * scale
     mask = jnp.arange(T)[None, :] < lengths[:, None]          # (B, T)
     logits = jnp.where(mask[:, None, None, :], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgt,btkv->bkgv", p, v)
+    out = jnp.einsum("bkgt,bktv->bkgv", p, v)
     return out.reshape(B, H, v.shape[-1])
 
 
@@ -92,7 +94,7 @@ def verify_decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray,
     per-slot cache (the multi-token generalization of
     ``decode_attention_ref`` — W == 1 reduces to it exactly).
 
-    q: (B, W, H, dq);  k: (B, T, KV, dq);  v: (B, T, KV, dv);
+    q: (B, W, H, dq);  k: (B, KV, T, dq);  v: (B, KV, T, dv);
     lengths: (B,) int32 — the TOTAL valid cache length per row, window
     included: query j of row b sits at position ``lengths[b] - W + j``
     and attends to cache positions <= its own.  Cache contents past
@@ -101,17 +103,17 @@ def verify_decode_attention_ref(q: jnp.ndarray, k: jnp.ndarray,
     the output.  -> (B, W, H, dv)
     """
     B, W, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    KV, T = k.shape[1], k.shape[2]
     G = H // KV
     if scale is None:
         scale = 1.0 / jnp.sqrt(dq).astype(jnp.float32)
     qg = q.reshape(B, W, KV, G, dq)
-    logits = jnp.einsum("bskgq,btkq->bkgst", qg, k).astype(jnp.float32) * scale
+    logits = jnp.einsum("bskgq,bktq->bkgst", qg, k).astype(jnp.float32) * scale
     qpos = (lengths[:, None] - W) + jnp.arange(W)[None, :]        # (B, W)
     mask = jnp.arange(T)[None, None, :] <= qpos[:, :, None]       # (B, W, T)
     logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgst,btkv->bskgv", p, v)
+    out = jnp.einsum("bkgst,bktv->bskgv", p, v)
     return out.reshape(B, W, H, v.shape[-1])
 
 
@@ -128,17 +130,25 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
     to the dense oracle (lengths mask everything past each slot's valid
     tokens, so sentinel/garbage pages never influence the output).
 
-    q: (B, H, dq);  k_pool: (N, page_tokens, KV, dq);
-    v_pool: (N, page_tokens, KV, dv);  page_table: (B, n_p) int32;
+    q: (B, H, dq);  k_pool: (N, KV, page_tokens, dq);
+    v_pool: (N, KV, page_tokens, dv);  page_table: (B, n_p) int32;
     lengths: (B,) int32;  qk_ranks / vo_ranks: optional (KV,) int32
     per-head kept ranks (see ``decode_attention_ref``).  -> (B, H, dv)
     """
-    B, n_p = page_table.shape
-    pt = k_pool.shape[1]
-    k = k_pool[page_table].reshape(B, n_p * pt, *k_pool.shape[2:])
-    v = v_pool[page_table].reshape(B, n_p * pt, *v_pool.shape[2:])
+    k = gather_pages(k_pool, page_table)
+    v = gather_pages(v_pool, page_table)
     return decode_attention_ref(q, k, v, lengths, scale=scale,
                                 qk_ranks=qk_ranks, vo_ranks=vo_ranks)
+
+
+def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
+    """Dense per-slot view of a paged pool through the page table:
+    pool (N, KV, page_tokens, r), page_table (B, n_p) -> (B, KV,
+    n_p * page_tokens, r)."""
+    B, n_p = page_table.shape
+    _, KV, pt, r = pool.shape
+    return pool[page_table].transpose(0, 2, 1, 3, 4).reshape(
+        B, KV, n_p * pt, r)
 
 
 def page_copy_ref(pool: jnp.ndarray, src: jnp.ndarray,
@@ -147,7 +157,7 @@ def page_copy_ref(pool: jnp.ndarray, src: jnp.ndarray,
     serve.engine).  Rows ``dst[i]`` become copies of rows ``src[i]``;
     every other row is untouched.
 
-    pool: (n_blocks, N, page_tokens, KV, r);  src, dst: (m,) int32.
+    pool: (n_blocks, N, KV, page_tokens, r);  src, dst: (m,) int32.
     Pairs are disjoint except sentinel self-copies (dst may repeat the
     sentinel row as padding), and all reads see the INPUT pool — a page
     can be a src of one pair and the dst of a LATER pair only after the
@@ -166,8 +176,8 @@ def page_restore_ref(pool: jnp.ndarray, rows: jnp.ndarray,
     writes rows whose bytes came from outside it — host RAM spill
     slabs copied back before a prefix-cache restore resumes prefill.
 
-    pool: (n_blocks, N, page_tokens, KV, r);
-    rows: (n_blocks, W, page_tokens, KV, r) — slab ``rows[:, i]``
+    pool: (n_blocks, N, KV, page_tokens, r);
+    rows: (n_blocks, W, KV, page_tokens, r) — slab ``rows[:, i]``
     lands in pool row ``dst[i]``;  dst: (W,) int32.  Real dst entries
     are distinct freshly-allocated pages; padding repeats the sentinel
     row with all-zero slabs (duplicate scatter targets therefore all

@@ -39,6 +39,12 @@ from repro.parallel import sharding as sh
 from repro.train.step import TrainConfig, make_train_step, make_opt_state
 
 
+# The production meshes model TPU v5e pods; their roofline terms use
+# that chip's published peaks (the compile itself runs on placeholder
+# CPU devices, which have none).
+TARGET_CHIP = H.peaks("TPU v5 lite")
+
+
 def production_cfg(name: str) -> ArchConfig:
     """Full assigned config at production numerics: bf16 params/compute,
     vocab padded to 128 so the logits shard on the model axis."""
@@ -132,7 +138,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                          in_shardings=(p_sh, o_sh, batch_sh),
                          out_shardings=(p_sh, o_sh, None),
                          donate_argnums=(0, 1))
-        with mesh:
+        with sh.use_mesh(mesh):
             return jitted.lower(params_s, opt_s, batch_shapes)
 
     if shape.kind == "prefill":
@@ -153,7 +159,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                          in_shardings=tuple(in_sh),
                          out_shardings=(logits_sh, s_sh),
                          donate_argnums=(2,))
-        with mesh:
+        with sh.use_mesh(mesh):
             return jitted.lower(*args)
 
     # decode: one token against a filled cache of seq_len
@@ -171,7 +177,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                      in_shardings=(p_sh, tok1_sh, s_sh),
                      out_shardings=(logits_sh, s_sh),
                      donate_argnums=(2,))
-    with mesh:
+    with sh.use_mesh(mesh):
         return jitted.lower(params_s, ins["token"], state_s)
 
 
@@ -198,7 +204,7 @@ def calibrated_costs(cfg: ArchConfig, shape: ShapeConfig, mesh,
         lowered = lower_cell(small, shape, mesh, rules, remat=remat,
                              microbatches=1)
         compiled = lowered.compile()
-        rl = H.roofline_from_compiled(compiled)
+        rl = H.roofline_from_compiled(compiled, TARGET_CHIP)
         costs.append((rl.flops, rl.hbm_bytes, rl.coll_bytes,
                       dict(rl.coll_detail)))
     n = cfg.n_blocks
@@ -253,9 +259,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     rl = H.Roofline(
         flops=cal["flops"], hbm_bytes=cal["hbm_bytes"],
         coll_bytes=cal["coll_bytes"], coll_detail=cal["coll_detail"],
-        t_compute=cal["flops"] / H.PEAK_FLOPS,
-        t_memory=cal["hbm_bytes"] / H.HBM_BW,
-        t_collective=cal["coll_bytes"] / (H.ICI_BW * 4))
+        t_compute=cal["flops"] / TARGET_CHIP.flops,
+        t_memory=cal["hbm_bytes"] / TARGET_CHIP.hbm_bw,
+        t_collective=cal["coll_bytes"] / (TARGET_CHIP.ici_bw * 4),
+        peak_flops=TARGET_CHIP.flops)
     n_chips = mesh.size
     # MODEL_FLOPS: 6 N D for train, 2 N D for inference (per token);
     # MoE uses active params.  Per-device = global / chips.

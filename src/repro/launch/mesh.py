@@ -16,17 +16,27 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with every axis in Auto mode: GSPMD propagates
+    shardings from the placement and ``with_sharding_constraint`` hints,
+    which is what the logical-axis rules in ``parallel.sharding`` are
+    written for (Explicit axes would type-check every gather)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """Arbitrary mesh (tests, small deployments, pipeline experiments)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: Optional[int] = None, model: int = 1):
@@ -52,7 +62,7 @@ def make_host_mesh(data: Optional[int] = None, model: int = 1):
         raise ValueError(
             f"mesh ({data}, {model}) needs {data * model} devices but "
             f"jax.device_count()={n}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def axis_size(mesh, name: str) -> int:

@@ -21,6 +21,7 @@ import jax
 from repro.configs import get_config
 from repro.core import clover_decompose, clover_prune
 from repro.data import SyntheticConfig, SyntheticLM, make_global_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.optim import AdamWConfig
@@ -80,6 +81,7 @@ def main(argv=None):
     ap.add_argument("--fail-at", type=int, default=-1,
                     help="inject a WorkerFailure at this step (FT demo)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg, mesh, params, opt_state, data, jitted = build(
         args.arch, reduced=args.reduced, batch=args.batch, seq=args.seq,
@@ -99,7 +101,7 @@ def main(argv=None):
             raise WorkerFailure(f"injected failure at step {i}")
         batch_np = st["data"].batch_at(i)
         batch = make_global_batch(batch_np, mesh, spec)
-        with mesh:
+        with sh.use_mesh(mesh):
             p, o, metrics = jitted(st["params"], st["opt"], batch)
         st = {"params": p, "opt": o, "data": st["data"]}
         st["data"].step = i + 1

@@ -132,7 +132,8 @@ def _causal_attention_chunked(q, k, v, scale, *, softcap: float = 0.0,
     """Memory-bounded causal attention: lax.scan over query blocks, each
     block rematerialized (recompute probs in backward — XLA flash).
 
-    q (B,S,H,dq), k (B,T,KV,dq), v (B,T,KV,dv) -> (B,S,H,dv).
+    q (B,S,H,dq), k (B,KV,T,dq), v (B,KV,T,dv) -> (B,S,H,dv) — keys and
+    values head-major, as the KV caches store them.
     Query i sits at global position ``q_offset + i`` (traced OK); key t at
     position t.  T >= S; zero-filled cache tail is masked by causality.
 
@@ -144,7 +145,7 @@ def _causal_attention_chunked(q, k, v, scale, *, softcap: float = 0.0,
     """
     from repro.parallel.sharding import constrain, BATCH, KV_SEQ
     B, S, H, dq = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    KV, T = k.shape[1], k.shape[2]
     G = H // KV
     dv = v.shape[-1]
     bq = _pick_block(S, ATTN_CHUNK)
@@ -159,7 +160,7 @@ def _causal_attention_chunked(q, k, v, scale, *, softcap: float = 0.0,
         qb, i = xs                                  # (B,bq,KV,G,dq), scalar
         if seq_par:
             qb = constrain(qb, (BATCH, KV_SEQ, None, None, None))
-        logits = jnp.einsum("bskgq,btkq->bkgst", qb, k).astype(jnp.float32)
+        logits = jnp.einsum("bskgq,bktq->bkgst", qb, k).astype(jnp.float32)
         logits = logits * scale
         if softcap > 0:
             logits = jnp.tanh(logits / softcap) * softcap
@@ -168,7 +169,7 @@ def _causal_attention_chunked(q, k, v, scale, *, softcap: float = 0.0,
         logits = jnp.where(mask[None, None, None], logits,
                            jnp.finfo(jnp.float32).min)
         p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-        ob = jnp.einsum("bkgst,btkv->bskgv", p, v)  # (B,bq,KV,G,dv)
+        ob = jnp.einsum("bkgst,bktv->bskgv", p, v)  # (B,bq,KV,G,dv)
         if seq_par:
             ob = constrain(ob, (BATCH, KV_SEQ, None, None, None))
         return carry, ob
@@ -222,14 +223,16 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
     """GQA attention.
 
     Full-sequence (train/prefill): ``kv_cache is None`` -> causal mask.
-    Decode: ``kv_cache`` holds {"k": (B, Smax, KV, dq), "v": (B, Smax, KV, dv)}
-    and ``cache_index`` is the write position (scalar int32); x has S==1.
+    Decode: ``kv_cache`` holds {"k": (B, KV, Smax, dq), "v": (B, KV, Smax,
+    dv)} and ``cache_index`` is the write position (scalar int32); x has
+    S==1.  Caches are HEAD-MAJOR so the decode kernels stream
+    (tokens, rank) blocks whole (DESIGN.md §4).
 
     Paged decode: ``page_table`` (B, n_p) int32 is given and ``kv_cache``
-    holds the global pools {"k": (N, page_tokens, KV, dq), "v": (N,
-    page_tokens, KV, dv)} shared by all slots; ``cache_index`` must be
+    holds the global pools {"k": (N, KV, page_tokens, dq), "v": (N, KV,
+    page_tokens, dv)} shared by all slots; ``cache_index`` must be
     the (B,) per-slot vector.  Position p of slot b lives at
-    ``pool[page_table[b, p // page_tokens], p % page_tokens]``.  The
+    ``pool[page_table[b, p // page_tokens], :, p % page_tokens]``.  The
     table must cover positions [0, cache_index + S) per slot — entries
     may be a sentinel id addressing the pool's spare garbage row, where
     padding/idle-slot writes land harmlessly (DESIGN.md §6).  With
@@ -325,7 +328,6 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
                          else jnp.minimum(rank_qk, dq).astype(jnp.int32)),
             "vo_ranks": (None if rank_vo is None
                          else jnp.minimum(rank_vo, dv).astype(jnp.int32)),
-            "rank_block": max(8, cfg.clover.rank_multiple),
         }
 
     new_cache = None
@@ -337,7 +339,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         # garbage inside a slot's own last page sits beyond its causal
         # horizon until the slot itself overwrites it — the same
         # masked-or-overwritten invariant as the dense cache.
-        N, PT = kv_cache["k"].shape[0], kv_cache["k"].shape[1]
+        N, PT = kv_cache["k"].shape[0], kv_cache["k"].shape[2]
         P = page_table.shape[1]
         pos = cache_index[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         # Positions past the table (an idle slot whose index ran beyond
@@ -354,15 +356,13 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             # any sub-floor write to the garbage row N-1.  The engine's
             # COW path means this never fires for valid traffic.
             page = jnp.where(pos >= write_floor[:, None], page, N - 1)
-        dest = (page * PT + pos % PT).reshape(-1)                   # (B*S,)
-        ck = (kv_cache["k"].reshape(N * PT, KV, dq_c)
-              .at[dest].set(_pad_rank(k, dq_c).reshape(B * S, KV, dq_c)
-                            .astype(kv_cache["k"].dtype))
-              .reshape(kv_cache["k"].shape))
-        cv = (kv_cache["v"].reshape(N * PT, KV, dv_c)
-              .at[dest].set(_pad_rank(v, dv_c).reshape(B * S, KV, dv_c)
-                            .astype(kv_cache["v"].dtype))
-              .reshape(kv_cache["v"].shape))
+        off = pos % PT
+        # (page, :, off) scatter: the (B, S) index pairs lead, so the
+        # update is the token-major (B, S, KV, r) projection itself
+        ck = kv_cache["k"].at[page, :, off].set(
+            _pad_rank(k, dq_c).astype(kv_cache["k"].dtype))
+        cv = kv_cache["v"].at[page, :, off].set(
+            _pad_rank(v, dv_c).astype(kv_cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         if use_pallas and S == 1:  # paged flash-decoding: the hot path
             lengths = (cache_index + 1).astype(jnp.int32)
@@ -373,13 +373,12 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
                 **rank_kw)[:, None]                         # (B,1,H,dv)
             return _vo_out(ctx), new_cache
         # Chunked-prefill reads gather each slot's pages into a dense
-        # (B, P*PT, KV, r) view and reuse the masked path below; writes
+        # (B, KV, P*PT, r) view and reuse the masked path below; writes
         # stay pool-resident (noted in DESIGN.md §6 as the cold path).
-        k = (ck[page_table].reshape(B, P * PT, KV, dq_c)[..., :dq]
-             .astype(x.dtype))
-        v = (cv[page_table].reshape(B, P * PT, KV, dv_c)[..., :dv]
-             .astype(x.dtype))
-        T = k.shape[1]
+        from repro.kernels.ref import gather_pages
+        k = gather_pages(ck, page_table)[..., :dq].astype(x.dtype)
+        v = gather_pages(cv, page_table)[..., :dv].astype(x.dtype)
+        T = k.shape[2]
         kv_pos = jnp.arange(T, dtype=jnp.int32)
         qpos = cache_index[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         mask = kv_pos[None, None, :] <= qpos[:, :, None]      # (B, S, T)
@@ -389,19 +388,22 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         # serving engine's continuous batching; S may be > 1 for chunked
         # prefill, writing an S-token window at each slot's own offset).
         per_slot = jnp.ndim(cache_index) == 1
-        kw = _pad_rank(k, dq_c).astype(kv_cache["k"].dtype)
-        vw = _pad_rank(v, dv_c).astype(kv_cache["v"].dtype)
+        # head-major window (B, KV, S, r), as the cache stores it
+        kw = (_pad_rank(k, dq_c).astype(kv_cache["k"].dtype)
+              .transpose(0, 2, 1, 3))
+        vw = (_pad_rank(v, dv_c).astype(kv_cache["v"].dtype)
+              .transpose(0, 2, 1, 3))
         if per_slot:
             upd = jax.vmap(
                 lambda c, kn, i: jax.lax.dynamic_update_slice_in_dim(
-                    c, kn, i, axis=0))
+                    c, kn, i, axis=1))
             ck = upd(kv_cache["k"], kw, cache_index)
             cv = upd(kv_cache["v"], vw, cache_index)
         else:
             ck = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["k"], kw, cache_index, axis=1)
+                kv_cache["k"], kw, cache_index, axis=2)
             cv = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["v"], vw, cache_index, axis=1)
+                kv_cache["v"], vw, cache_index, axis=2)
         new_cache = {"k": ck, "v": cv}
         if use_pallas and S == 1:  # flash-decoding against the cache
             lengths = jnp.broadcast_to(cache_index + 1, (B,)).astype(jnp.int32)
@@ -419,7 +421,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
                 heads_shardable=_heads_shardable(H),
                 unroll=cfg.unroll_layers)
             return _vo_out(ctx), new_cache
-        T = k.shape[1]
+        T = k.shape[2]
         kv_pos = jnp.arange(T, dtype=jnp.int32)
         ci = jnp.broadcast_to(jnp.atleast_1d(cache_index), (B,))
         # query j of slot b sits at global position ci[b] + j; causal
@@ -432,6 +434,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             ctx = dispatch.clover_attention(q, k, v, causal=True,
                                             scale=scale)       # (B,S,H,dv)
             return _vo_out(ctx), None
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         if S > ATTN_CHUNK:
             # XLA flash: scan over q blocks so the (bq, S) logits slab is
             # the peak — full (S, S) logits at 4k-32k would not fit HBM.
@@ -448,7 +451,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         mask = jnp.broadcast_to(mask, (B, S, T))
 
     qg = q.reshape(B, S, KV, G, dq)
-    logits = jnp.einsum("bskgq,btkq->bkgst", qg, k) * scale
+    logits = jnp.einsum("bskgq,bktq->bkgst", qg, k) * scale
     if cfg.attn_logit_softcap > 0:
         c = cfg.attn_logit_softcap
         logits = jnp.tanh(logits / c) * c
@@ -456,7 +459,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
     neg = jnp.finfo(jnp.float32).min
     logits = jnp.where(mask[:, None, None, :, :], logits, neg)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bkgst,btkv->bskgv", probs, v).reshape(B, S, H, dv)
+    ctx = jnp.einsum("bkgst,bktv->bskgv", probs, v).reshape(B, S, H, dv)
     return _vo_out(ctx), new_cache
 
 

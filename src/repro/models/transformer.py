@@ -250,8 +250,10 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_len: int) -> Params:
         st: Params = {}
         if mixer == MIXER_ATTN:
             st["kv"] = {
-                "k": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.qk_dim), kv_dtype),
-                "v": jnp.zeros((batch, max_len, cfg.n_kv_heads, cfg.vo_dim), kv_dtype),
+                "k": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.qk_dim),
+                               kv_dtype),
+                "v": jnp.zeros((batch, cfg.n_kv_heads, max_len, cfg.vo_dim),
+                               kv_dtype),
             }
         elif mixer == MIXER_MAMBA:
             st["mamba"] = M.init_mamba_state(cfg, batch, dtype)
@@ -273,9 +275,10 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_len: int) -> Params:
 def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
                             page_tokens: int) -> Params:
     """Decode state whose KV caches are PAGED: one global pool
-    ``(n_pages + 1, page_tokens, KV, r)`` per attention layer (stacked
-    over ``n_blocks``) instead of a dense per-slot ``(batch, max_len,
-    KV, r)``.  Row ``n_pages`` is the spare garbage row that sentinel
+    ``(n_pages + 1, KV, page_tokens, r)`` per attention layer (stacked
+    over ``n_blocks``) instead of a dense per-slot ``(batch, KV,
+    max_len, r)``.  Both are head-major (DESIGN.md §4).  Row
+    ``n_pages`` is the spare garbage row that sentinel
     page-table entries address (padding / idle-slot writes land there).
     Recurrent (mamba/rwkv) leaves stay per-slot — they are O(1) in
     sequence length, so paging buys them nothing.  ``index`` is the
@@ -291,8 +294,8 @@ def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
         if "kv" not in names:
             return leaf
         r = leaf.shape[-1]          # qk or vo rank
-        KV = leaf.shape[-2]
-        return jnp.zeros((cfg.n_blocks, n_pages + 1, page_tokens, KV, r),
+        KV = leaf.shape[-3]
+        return jnp.zeros((cfg.n_blocks, n_pages + 1, KV, page_tokens, r),
                          kv_dtype)
 
     blocks = jax.tree_util.tree_map_with_path(repage, dense["blocks"])

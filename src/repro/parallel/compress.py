@@ -24,7 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 Params = Dict[str, Any]
 
@@ -62,8 +61,8 @@ def compress_cross_pod(grads: Params, mesh: Mesh,
         spec = P()  # grads replicated w.r.t. pod at this point
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
-            check_rep=False)
+            jax.shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec,
+            check_vma=False)
         def psum_pod(x):
             return _psum_int8(x, "pod") / n_pod
         return psum_pod(g)
@@ -82,8 +81,8 @@ def compress_cross_pod_ef(grads: Params, residual: Params, mesh: Mesh,
         gf = g.astype(jnp.float32) + r
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
-            check_rep=False)
+            jax.shard_map, mesh=mesh, in_specs=(P(),),
+            out_specs=(P(), P()), check_vma=False)
         def step(x):
             q, scale = _quantize(x)
             sent = q.astype(jnp.float32) * scale       # what the wire saw

@@ -4,10 +4,9 @@ The dry-run's compiled artifact is the per-device SPMD program, so
 ``cost_analysis()`` flops/bytes and the summed collective operand bytes
 are already per-chip quantities (EXPERIMENTS.md §Roofline).
 
-Hardware model (TPU v5e, per chip):
-  peak bf16 compute  197 TFLOP/s
-  HBM bandwidth      819 GB/s
-  ICI per link       ~50 GB/s
+Peak rates come from ``PEAKS``, keyed by ``jax.Device.device_kind``.
+A device that is not in the table raises: a roofline against some
+other chip's peaks is not a roofline.
 """
 from __future__ import annotations
 
@@ -15,9 +14,36 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peak rates."""
+    flops: float          # dense bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # inter-chip bytes/s per link
+    source: str
+
+
+PEAKS: Dict[str, Peaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB
+    # HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect per chip
+    # (4 links of ~50 GB/s).
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                         source='Google Cloud, "TPU v5e"'),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Peak rates of ``device_kind`` (as ``jax.Device.device_kind``
+    reports it); raises ``KeyError`` for a device not in ``PEAKS``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}.  Add the chip with its source to "
+            "parallel/hlo.py PEAKS.") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8,
@@ -101,6 +127,7 @@ class Roofline:
     t_compute: float             # seconds
     t_memory: float
     t_collective: float
+    peak_flops: float            # of the chip the terms were taken for
 
     @property
     def dominant(self) -> str:
@@ -115,13 +142,14 @@ class Roofline:
 
     def fraction(self, model_flops_per_device: float) -> float:
         """Achievable roofline fraction = useful-compute time / bound."""
-        t_useful = model_flops_per_device / PEAK_FLOPS
+        t_useful = model_flops_per_device / self.peak_flops
         return t_useful / max(self.bound, 1e-30)
 
 
-def roofline_from_compiled(compiled, *, ici_links: int = 4,
+def roofline_from_compiled(compiled, chip: Peaks, *, ici_links: int = 4,
                            hlo_text: Optional[str] = None) -> Roofline:
-    """Three roofline terms from a compiled (partitioned) executable.
+    """Three roofline terms from a compiled (partitioned) executable,
+    against ``chip``'s peaks.
 
     ici_links: usable ICI links per chip for the dominant collective
     direction (v5e 2D torus: 4 links; conservative default)."""
@@ -135,9 +163,10 @@ def roofline_from_compiled(compiled, *, ici_links: int = 4,
     cb = float(sum(coll.values()))
     return Roofline(
         flops=flops, hbm_bytes=hbm, coll_bytes=cb, coll_detail=coll,
-        t_compute=flops / PEAK_FLOPS,
-        t_memory=hbm / HBM_BW,
-        t_collective=cb / (ICI_BW * ici_links),
+        t_compute=flops / chip.flops,
+        t_memory=hbm / chip.hbm_bw,
+        t_collective=cb / (chip.ici_bw * ici_links),
+        peak_flops=chip.flops,
     )
 
 

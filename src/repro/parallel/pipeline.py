@@ -86,18 +86,10 @@ def pipeline_apply(stage_params: Params, x: jnp.ndarray, mesh: Mesh,
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
     xr = x.reshape((m, mb) + x.shape[1:])
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(spec_params, P()),
-            out_specs=P(),
-            axis_names={axis}, check_vma=False)
-    else:  # jax 0.4.x spelling
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(spec_params, P()),
-            out_specs=P(),
-            check_rep=False)
+    fn = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(spec_params, P()),
+        out_specs=P(),
+        axis_names={axis}, check_vma=False)
     out = fn(stage_params, xr)
     return out.reshape(x.shape[:1] + out.shape[2:])

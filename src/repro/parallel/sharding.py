@@ -18,9 +18,11 @@ Default logical->mesh map (production):
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -196,7 +198,7 @@ def data_specs(mesh: Mesh, rules: Optional[ShardingRules] = None,
 
 def decode_state_specs(state: Params, mesh: Mesh,
                        rules: Optional[ShardingRules] = None) -> Params:
-    """Decode-state tree: KV caches (B, T, KV, d) shard batch over
+    """Decode-state tree: KV caches (B, KV, T, d) shard batch over
     (pod,data) and the cache sequence over "model" (see module doc);
     mamba/rwkv states shard batch and the inner dim over "model"."""
     rules = rules or ShardingRules()
@@ -204,8 +206,8 @@ def decode_state_specs(state: Params, mesh: Mesh,
     def visit(path, leaf):
         names = [getattr(p, "key", None) for p in path]
         shape = leaf.shape
-        if "kv" in names:                       # (nb, B, T, KV, d)
-            axes = (None, BATCH, KV_SEQ, None, None)
+        if "kv" in names:                       # (nb, B, KV, T, d)
+            axes = (None, BATCH, None, KV_SEQ, None)
         elif "mamba" in names and path and getattr(
                 path[-1], "key", "") == "ssm":  # (nb, B, dI, dS)
             axes = (None, BATCH, FF, None)
@@ -257,9 +259,10 @@ def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
                       rules: Optional[ShardingRules] = None) -> Params:
     """PartitionSpec tree for the serving engine's decode state.
 
-    KV leaves shard along the KV-HEAD axis (axis -2 in both layouts:
-    dense ``(nb, B, T, KV, r)`` and paged ``(nb, n_pages+1, PT, KV,
-    r)``); the dense layout additionally shards slots over "data".  The
+    KV leaves shard along the KV-HEAD axis (axis 2 in both head-major
+    layouts: dense ``(nb, B, KV, T, r)`` and paged ``(nb, n_pages+1,
+    KV, PT, r)``); the dense layout additionally shards slots over
+    "data".  The
     paged pool's page-row axis is replicated — page ids are global, the
     host-side allocator/trie address the same rows on every shard.
     Recurrent (mamba/rwkv) leaves shard only their slot axis over
@@ -273,8 +276,8 @@ def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
         if getattr(path[-1], "key", "") == "index":
             return P()
         if "kv" in names:
-            axes = ((None, None, None, KV_HEADS, None) if paged
-                    else (None, BATCH, None, KV_HEADS, None))
+            axes = ((None, None, KV_HEADS, None, None) if paged
+                    else (None, BATCH, KV_HEADS, None, None))
         else:
             axes = (None, BATCH) + (None,) * (leaf.ndim - 2)
         axes = tuple(axes)[:leaf.ndim]
@@ -327,16 +330,10 @@ def kernel_axes(mesh: Optional[Mesh], *, batch: int, kv_heads: int,
 
 
 def shard_map_call(body, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map: ``jax.shard_map`` (jax >= 0.5) or the
-    0.4.x experimental spelling — the same idiom parallel.pipeline
-    uses.  Replication checking is off: the kernel bodies contain
-    pallas_call, which the checker cannot see through."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off: the kernel
+    bodies contain pallas_call, which the checker cannot see through."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def opt_specs(param_spec_tree: Params) -> Params:
@@ -355,21 +352,27 @@ def shardings(spec_tree: Params, mesh: Mesh) -> Params:
 # (CPU smoke tests).
 # ---------------------------------------------------------------------------
 
-def ambient_mesh() -> Optional[Mesh]:
-    """The mesh currently in context (``with mesh:`` / set_mesh), or None."""
+_AMBIENT: contextvars.ContextVar[Optional[Mesh]] = contextvars.ContextVar(
+    "repro_ambient_mesh", default=None)
+
+
+@contextlib.contextmanager
+def use_mesh(mesh: Mesh) -> Iterator[Mesh]:
+    """Make ``mesh`` ambient for code traced inside the block: the
+    ``constrain`` hints and the MoE dispatch read it through
+    ``ambient_mesh``, and ``jax.set_mesh`` makes it JAX's context mesh
+    too (so jit caches key on it)."""
+    token = _AMBIENT.set(mesh)
     try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001 — internal API; degrade gracefully
-        pass
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:  # jax >= 0.5; absent on 0.4.x
-        am = get_am()
-        if am is not None and getattr(am, "shape", None):
-            return am
-    return None
+        with jax.set_mesh(mesh):
+            yield mesh
+    finally:
+        _AMBIENT.reset(token)
+
+
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the innermost ``use_mesh`` block, or None."""
+    return _AMBIENT.get()
 
 
 def batch_mesh_axes(mesh) -> Tuple[str, ...]:
@@ -386,4 +389,4 @@ def constrain(x, logical_axes: Tuple[Optional[str], ...],
     axes = tuple(logical_axes)[:x.ndim]
     axes = axes + (None,) * (x.ndim - len(axes))
     return jax.lax.with_sharding_constraint(
-        x, rules.spec(axes, x.shape, mesh))
+        x, NamedSharding(mesh, rules.spec(axes, x.shape, mesh)))

@@ -83,7 +83,7 @@ def validate_kernel_parallelism(cfg: ArchConfig, tp: int) -> None:
     dispatch = kops.resolve(cfg.kernel_impl)    # raises on bad aliases
     if tp > 1 and dispatch.kernel_path and is_recurrent(cfg):
         raise ValueError(
-            f"kernel_impl={dispatch.requested or dispatch.impl!r} with "
+            f"kernel_impl={dispatch.impl!r} with "
             f"tp={tp} is unsupported on recurrent (mamba/rwkv) "
             "architectures: mamba_scan/wkv6 carry cross-step recurrent "
             "state and are not shard_map-partitioned, so the kernel "
@@ -469,7 +469,7 @@ class LocalExecutor:
     def page_restore(self, state, rows, dst) -> Params:
         """Host->device restore scatter: slab ``rows[leaf][:, i]`` lands
         in pool row ``dst[i]`` of the matching KV leaf.  ``rows`` is a
-        list of (n_blocks, W, page_tokens, KV, r) arrays in the same
+        list of (n_blocks, W, KV, page_tokens, r) arrays in the same
         tree order ``read_page`` produces; short batches arrive
         zero-padded with sentinel dst entries (one fixed W = no new
         compiled shapes per batch size)."""
@@ -552,9 +552,11 @@ class ShardedExecutor(LocalExecutor):
     archs still serve correctly but INTEGRATE that drift step over
     step, so their sharded streams may diverge from tp=1 on a
     near-tie — the same reason they are excluded from speculative
-    rollback.  Heads that do not divide ``tp`` degrade to replication
-    (the sharding rules drop non-divisible dims) — correct, just not
-    parallel.
+    rollback.  A KV-head count that ``tp`` does not divide is padded
+    with all-zero heads (``core.prune.pad_kv_heads``, exact) up to the
+    next multiple of ``tp``, so the weights and the KV pool still split
+    across the shards rather than replicate; ``kv_pad`` says how many
+    were added.
 
     Pallas step kernels run PER SHARD: ``_compile_cfg`` resolves
     ``kernel_impl`` against the executor's mesh, so the flash-decode /
@@ -574,7 +576,8 @@ class ShardedExecutor(LocalExecutor):
     def __init__(self, params: Params, cfg: ArchConfig,
                  ecfg: EngineConfig, *, tp: Optional[int] = None,
                  plan=None, adapter_bank=None):
-        from repro.core.prune import head_rank_loads, rank_balanced_partition
+        from repro.core.prune import (head_rank_loads, pad_kv_heads,
+                                      rank_balanced_partition)
         from repro.launch.mesh import make_host_mesh
         tp = int(tp if tp is not None else ecfg.tp)
         if tp < 1:
@@ -582,7 +585,12 @@ class ShardedExecutor(LocalExecutor):
         validate_kernel_parallelism(cfg, tp)    # before anything compiles
         self.mesh = make_host_mesh(model=tp)    # clear error on misfit
         has_attn = any(m == MIXER_ATTN for m, _ in cfg.pattern)
-        if plan is None and has_attn and cfg.n_kv_heads % tp == 0:
+        self.kv_pad = 0
+        if has_attn and cfg.n_kv_heads % tp:
+            params, padded = pad_kv_heads(params, cfg, tp)
+            self.kv_pad = padded.n_kv_heads - cfg.n_kv_heads
+            cfg = padded
+        if plan is None and has_attn:
             plan = rank_balanced_partition(head_rank_loads(cfg), tp,
                                            group=cfg.q_per_kv)
         self.plan = plan
@@ -607,6 +615,11 @@ class ShardedExecutor(LocalExecutor):
         if bank is None:
             return None
         from repro.parallel import sharding as sh
+        if self.kv_pad:     # identity scales for the zero query heads
+            n = self.kv_pad * self.cfg.q_per_kv
+            bank = jax.tree.map(
+                lambda a: jnp.pad(a, [(0, 0), (0, 0), (0, n), (0, 0)],
+                                  constant_values=1.0), bank)
         if self.plan is not None and not self.plan.identity:
             perm = jnp.asarray(self.plan.q_perm, jnp.int32)
             bank = jax.tree.map(lambda a: jnp.take(a, perm, axis=2), bank)
@@ -655,7 +668,8 @@ class ShardedExecutor(LocalExecutor):
             cfg, kernel_impl=kops.resolve(cfg.kernel_impl, mesh=self.mesh))
 
     def _ctx(self):
-        return self.mesh      # Mesh is a reusable context manager
+        from repro.parallel.sharding import use_mesh
+        return use_mesh(self.mesh)
 
     def plan_salt(self) -> Tuple:
         if self.plan is not None:

@@ -488,7 +488,7 @@ def rank_pool_bytes(plan, *, page_tokens: int, n_pages: int,
 
     The PHYSICAL pools are sized by the plan's global max widths — the
     transformer lax.scans a stacked state pytree, so every layer's pool
-    shares one shape ``(n_pages + 1, page_tokens, KV, max_rank)``.
+    shares one shape ``(n_pages + 1, KV, page_tokens, max_rank)``.
     This helper reports what those bytes BUY per layer: the bytes the
     kept ranks actually use (``kept``), the uniform-max footprint the
     stack allocates (``allocated``), and the layerwise breakdown — the

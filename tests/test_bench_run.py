@@ -90,7 +90,10 @@ def test_chained_modules_keep_tp_keys(tmp_path):
         [sys.executable, "-m", "benchmarks.run",
          "--only", "kernel_bench", "--only", "serve_bench"],
         env=_env(SERVE_BENCH_SCENARIO="adapter",
-                 BENCH_OUTPUT_DIR=str(tmp_path)),
+                 BENCH_OUTPUT_DIR=str(tmp_path),
+                 # benchmarks.run turns the compile cache on; keep it
+                 # off the checkout in a test
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache")),
         cwd=tmp_path, capture_output=True, text=True, timeout=1200)
     assert res.returncode == 0, (res.stdout[-2000:], res.stderr[-2000:])
     data = json.loads((tmp_path / "BENCH_serve.json").read_text())

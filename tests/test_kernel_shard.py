@@ -61,11 +61,13 @@ def test_resolve_aliases_and_idempotence():
     assert kops.resolve(d) is d                     # idempotent
     assert not kops.resolve("xla").kernel_path
     assert not kops.resolve("ref").kernel_path
-    # "pallas" canonicalizes per platform (CPU has no native lowering)
-    p = kops.resolve("pallas")
-    assert p.requested == "pallas"
-    if jax.local_devices()[0].platform not in ("tpu", "gpu"):
-        assert p.impl == "interpret"
+    # "pallas" never silently becomes the interpreter: off a TPU it
+    # raises, naming the platform and the explicit alternatives
+    if jax.local_devices()[0].platform != "tpu":
+        with pytest.raises(ValueError, match="needs a TPU"):
+            kops.resolve("pallas")
+    else:
+        assert kops.resolve("pallas").impl == "pallas"
     with pytest.raises(ValueError, match="unknown kernel impl"):
         kops.resolve("cuda")
 
@@ -121,8 +123,8 @@ def test_decode_attention_shard_parity():
     rng = np.random.default_rng(0)
     B, H, KV, dq, dv, T = 4, 8, 4, 16, 12, 40
     q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    k = _ragged_kv(rng, (B, T, KV, dq), head_axis=2, rank_axis=3)
-    v = _ragged_kv(rng, (B, T, KV, dv), head_axis=2, rank_axis=3)
+    k = _ragged_kv(rng, (B, KV, T, dq), head_axis=1, rank_axis=3)
+    v = _ragged_kv(rng, (B, KV, T, dv), head_axis=1, rank_axis=3)
     lens = jnp.asarray([1, 17, 40, 5], jnp.int32)
     single = kops.resolve("interpret")
     sharded = kops.resolve("interpret", mesh=mesh)
@@ -141,8 +143,8 @@ def test_paged_decode_attention_shard_parity():
     B, H, KV, dq, dv = 4, 8, 4, 16, 12
     N, PT, nP = 11, 8, 5                 # row N-1 = the garbage row
     q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    kp = _ragged_kv(rng, (N, PT, KV, dq), head_axis=2, rank_axis=3)
-    vp = _ragged_kv(rng, (N, PT, KV, dv), head_axis=2, rank_axis=3)
+    kp = _ragged_kv(rng, (N, KV, PT, dq), head_axis=1, rank_axis=3)
+    vp = _ragged_kv(rng, (N, KV, PT, dv), head_axis=1, rank_axis=3)
     # host-global page ids, including sentinel entries past each slot's
     # coverage — identical tables must dereference identically per shard
     table = jnp.asarray(rng.integers(0, N, (B, nP)), jnp.int32)
@@ -161,7 +163,7 @@ def test_page_copy_shard_parity():
     mesh = make_host_mesh(model=2)
     rng = np.random.default_rng(2)
     nb, N, PT, KV, r = 2, 10, 8, 4, 12
-    pool = _ragged_kv(rng, (nb, N, PT, KV, r), head_axis=3, rank_axis=4)
+    pool = _ragged_kv(rng, (nb, N, KV, PT, r), head_axis=2, rank_axis=4)
     src = jnp.asarray([1, 3, 6], jnp.int32)
     dst = jnp.asarray([5, 7, 0], jnp.int32)
     single = kops.resolve("interpret")
@@ -197,8 +199,8 @@ def test_nondivisible_heads_degrade_to_replication():
     rng = np.random.default_rng(4)
     B, H, KV, dq, dv, T = 4, 6, 3, 8, 8, 16        # 3 kv heads, tp=2
     q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    k = _ragged_kv(rng, (B, T, KV, dq), head_axis=2, rank_axis=3)
-    v = _ragged_kv(rng, (B, T, KV, dv), head_axis=2, rank_axis=3)
+    k = _ragged_kv(rng, (B, KV, T, dq), head_axis=1, rank_axis=3)
+    v = _ragged_kv(rng, (B, KV, T, dv), head_axis=1, rank_axis=3)
     lens = jnp.asarray([4, 16, 8, 1], jnp.int32)
     from repro.parallel.sharding import kernel_axes
     b_ax, m_ax = kernel_axes(mesh, batch=B, kv_heads=KV)

@@ -40,8 +40,8 @@ def test_flash_attention_sweep(B, S, H, KV, dq, dv, dtype):
 def test_flash_decode_sweep(B, H, KV, T, dq, dv):
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, H, dq))
-    k = jax.random.normal(ks[1], (B, T, KV, dq))
-    v = jax.random.normal(ks[2], (B, T, KV, dv))
+    k = jax.random.normal(ks[1], (B, KV, T, dq))     # head-major cache
+    v = jax.random.normal(ks[2], (B, KV, T, dv))
     lengths = jax.random.randint(ks[3], (B,), 1, T + 1)
     o_ref = ref.decode_attention_ref(q, k, v, lengths)
     o_pal = ops.decode_attention(q, k, v, lengths, impl="interpret",
@@ -55,12 +55,12 @@ def test_flash_decode_respects_lengths():
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     B, H, KV, T, d = 2, 4, 2, 64, 16
     q = jax.random.normal(ks[0], (B, H, d))
-    k = jax.random.normal(ks[1], (B, T, KV, d))
-    v = jax.random.normal(ks[2], (B, T, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, T, d))
+    v = jax.random.normal(ks[2], (B, KV, T, d))
     lengths = jnp.array([10, 30])
     o1 = ops.decode_attention(q, k, v, lengths, impl="interpret", block_t=16)
-    k2 = k.at[:, 35:].set(999.0)
-    v2 = v.at[:, 35:].set(-999.0)
+    k2 = k.at[:, :, 35:].set(999.0)
+    v2 = v.at[:, :, 35:].set(-999.0)
     o2 = ops.decode_attention(q, k2, v2, lengths, impl="interpret",
                               block_t=16)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
@@ -75,17 +75,17 @@ def test_flash_decode_chunk_advanced_slots():
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     B, H, KV, T, d, chunk = 3, 4, 2, 96, 16, 32
     q = jax.random.normal(ks[0], (B, H, d))
-    k = jax.random.normal(ks[1], (B, T, KV, d))
-    v = jax.random.normal(ks[2], (B, T, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, T, d))
+    v = jax.random.normal(ks[2], (B, KV, T, d))
     lengths = jnp.array([chunk, 2 * chunk, chunk + 5], jnp.int32)
     poison_k, poison_v = k, v
     clean_k, clean_v = k, v
     for b in range(B):
         L = int(lengths[b])
-        poison_k = poison_k.at[b, L:].set(1e4)
-        poison_v = poison_v.at[b, L:].set(-1e4)
-        clean_k = clean_k.at[b, L:].set(0.0)
-        clean_v = clean_v.at[b, L:].set(0.0)
+        poison_k = poison_k.at[b, :, L:].set(1e4)
+        poison_v = poison_v.at[b, :, L:].set(-1e4)
+        clean_k = clean_k.at[b, :, L:].set(0.0)
+        clean_v = clean_v.at[b, :, L:].set(0.0)
     o_poison = ops.decode_attention(q, poison_k, poison_v, lengths,
                                     impl="interpret", block_t=32)
     o_clean = ops.decode_attention(q, clean_k, clean_v, lengths,

@@ -81,8 +81,8 @@ def test_paged_engine_on_pruned_model():
     eng = Engine(pp, pcfg, EngineConfig(slots=2, max_len=32, paged=True,
                                         page_tokens=4))
     k = eng.state["blocks"][0]["kv"]["k"]
-    # (n_blocks, n_pages+1, page_tokens, KV, r_qk)
-    assert k.ndim == 5 and k.shape[2] == 4
+    # (n_blocks, n_pages+1, KV, page_tokens, r_qk)
+    assert k.ndim == 5 and k.shape[3] == 4 and k.shape[2] == pcfg.n_kv_heads
     assert k.shape[-1] == pcfg.clover.qk_rank < cfg.head_dim_
     prompt = np.arange(4, dtype=np.int32) + 5
     out = eng.run([Request(uid=0, prompt=prompt, max_new_tokens=4)])
@@ -123,8 +123,8 @@ def test_paged_engine_interpret_kernel_decode():
 def _rand_paged_case(key, B, H, KV, dq, dv, pt, n_p, n_pool, max_len):
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, H, dq))
-    k_pool = jax.random.normal(ks[1], (n_pool, pt, KV, dq))
-    v_pool = jax.random.normal(ks[2], (n_pool, pt, KV, dv))
+    k_pool = jax.random.normal(ks[1], (n_pool, KV, pt, dq))
+    v_pool = jax.random.normal(ks[2], (n_pool, KV, pt, dv))
     lengths = jax.random.randint(ks[3], (B,), 1, max_len + 1)
     # disjoint page tables, sentinel (= n_pool - 1) past each row's pages
     perm = np.random.default_rng(0).permutation(n_pool - 1)
@@ -179,13 +179,16 @@ def test_paged_ref_matches_dense_ref():
     B, H, KV, T, d, pt = 2, 4, 2, 32, 16, 8
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (B, H, d))
-    k = jax.random.normal(ks[1], (B, T, KV, d))
-    v = jax.random.normal(ks[2], (B, T, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, T, d))
+    v = jax.random.normal(ks[2], (B, KV, T, d))
     lens = jnp.array([10, 29], jnp.int32)
     n_p = T // pt
-    # per-slot pages laid out contiguously in one pool
-    kp = k.reshape(B * n_p, pt, KV, d)
-    vp = v.reshape(B * n_p, pt, KV, d)
+    # per-slot pages laid out contiguously in one head-major pool
+
+    def paginate(x):
+        return (x.reshape(B, KV, n_p, pt, d).transpose(0, 2, 1, 3, 4)
+                .reshape(B * n_p, KV, pt, d))
+    kp, vp = paginate(k), paginate(v)
     tab = jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
     o_paged = ref.paged_decode_attention_ref(q, kp, vp, tab, lens)
     o_dense = ref.decode_attention_ref(q, k, v, lens)

@@ -183,8 +183,8 @@ def _zero_pad(q, k, v, rq, rv, G):
     qz, kz, vz = q.copy(), k.copy(), v.copy()
     for h in range(len(rq)):
         qz[..., h * G:(h + 1) * G, rq[h]:] = 0.0
-        kz[..., h, rq[h]:] = 0.0
-        vz[..., h, rv[h]:] = 0.0
+        kz[:, h, :, rq[h]:] = 0.0           # head-major (B, KV, T, r)
+        vz[:, h, :, rv[h]:] = 0.0
     return qz, kz, vz
 
 
@@ -195,20 +195,22 @@ def test_ranked_decode_kernel_parity():
     rng = np.random.default_rng(0)
     B, KV, G, dq, dv, T, bt, rb = 3, 4, 2, 32, 24, 64, 16, 8
     q = rng.normal(size=(B, KV * G, dq)).astype(np.float32)
-    k = rng.normal(size=(B, T, KV, dq)).astype(np.float32)
-    v = rng.normal(size=(B, T, KV, dv)).astype(np.float32)
+    k = rng.normal(size=(B, KV, T, dq)).astype(np.float32)
+    v = rng.normal(size=(B, KV, T, dv)).astype(np.float32)
     lengths = np.array([5, 37, 64], np.int32)
     rq = np.array([8, 16, 32, 24], np.int32)
     rv = np.array([24, 8, 16, 24], np.int32)
     qz, kz, vz = _zero_pad(q, k, v, rq, rv, G)
+    qg = qz.reshape(B, KV, G, dq)             # the kernel's query layout
     scale = 1.0 / np.sqrt(dq)
-    out = flash_decode_ranked(qz, kz, vz, lengths, rq, rv, scale=scale,
+    out = flash_decode_ranked(qg, kz, vz, lengths, rq, rv, scale=scale,
                               block_t=bt, rank_block=rb, interpret=True)
     out_full = flash_decode_ranked(
-        qz, kz, vz, lengths, np.full(KV, dq, np.int32),
+        qg, kz, vz, lengths, np.full(KV, dq, np.int32),
         np.full(KV, dv, np.int32), scale=scale, block_t=bt,
         rank_block=rb, interpret=True)
     assert (np.asarray(out) == np.asarray(out_full)).all()
+    out = np.asarray(out).reshape(B, KV * G, dv)
     oracle = ref.decode_attention_ref(qz, kz, vz, lengths, scale=scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                atol=5e-5, rtol=5e-5)
@@ -228,23 +230,25 @@ def test_ranked_paged_decode_kernel_parity():
     rv = np.array([24, 8, 16, 24], np.int32)
     n_p = T // pt
     N = B * n_p + 1
-    pool_k = rng.normal(size=(N, pt, KV, dq)).astype(np.float32)
-    pool_v = rng.normal(size=(N, pt, KV, dv)).astype(np.float32)
+    pool_k = rng.normal(size=(N, KV, pt, dq)).astype(np.float32)
+    pool_v = rng.normal(size=(N, KV, pt, dv)).astype(np.float32)
     qz = q.copy()
     for h in range(KV):
         qz[:, h * G:(h + 1) * G, rq[h]:] = 0.0
-        pool_k[:, :, h, rq[h]:] = 0.0
-        pool_v[:, :, h, rv[h]:] = 0.0
+        pool_k[:, h, :, rq[h]:] = 0.0
+        pool_v[:, h, :, rv[h]:] = 0.0
     table = rng.permutation(N - 1)[:B * n_p].reshape(B, n_p).astype(np.int32)
+    qg = qz.reshape(B, KV, G, dq)             # the kernel's query layout
     scale = 1.0 / np.sqrt(dq)
-    out = paged_flash_decode_ranked(qz, pool_k, pool_v, table, lengths,
+    out = paged_flash_decode_ranked(qg, pool_k, pool_v, table, lengths,
                                     rq, rv, scale=scale, rank_block=rb,
                                     interpret=True)
     out_full = paged_flash_decode_ranked(
-        qz, pool_k, pool_v, table, lengths, np.full(KV, dq, np.int32),
+        qg, pool_k, pool_v, table, lengths, np.full(KV, dq, np.int32),
         np.full(KV, dv, np.int32), scale=scale, rank_block=rb,
         interpret=True)
     assert (np.asarray(out) == np.asarray(out_full)).all()
+    out = np.asarray(out).reshape(B, KV * G, dv)
     oracle = ref.paged_decode_attention_ref(qz, pool_k, pool_v, table,
                                             lengths, scale=scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),

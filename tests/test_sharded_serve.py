@@ -318,8 +318,9 @@ def test_tp4_one_kv_head_per_shard():
 
 
 def test_tp2_nondivisible_heads_replicate():
-    """KV-head counts that do not divide tp degrade to replication
-    (plan=None, sharding rules drop the axis) — correct, not parallel."""
+    """KV-head counts that do not divide tp no longer replicate: the
+    executor appends all-zero KV heads (exact) up to a multiple of tp,
+    so the weights and the pool still split — streams unchanged."""
     _need(2)
     cfg = get_config("phi3-medium-14b").reduced()
     assert cfg.n_kv_heads % 2 == 1
@@ -330,7 +331,9 @@ def test_tp2_nondivisible_heads_replicate():
     eng, got = _streams(params, cfg, dataclasses.replace(ecfg, tp=2),
                         prompts, max_new=3)
     assert got == want
-    assert eng.exe.plan is None
+    assert eng.exe.kv_pad == 1 and eng.exe.cfg.n_kv_heads == 2
+    pool = eng.state["blocks"][0]["kv"]["k"]
+    assert pool.sharding.spec[2] == "model"        # KV-head axis split
 
 
 @pytest.mark.slow

@@ -123,7 +123,7 @@ def test_pjit_train_step_matches_single_device():
                     'step': jax.sharding.NamedSharding(
                         mesh, jax.sharding.PartitionSpec())}
             d_sh = jax.sharding.NamedSharding(mesh, sh.data_specs(mesh))
-            with mesh:
+            with sh.use_mesh(mesh):
                 j = jax.jit(step, in_shardings=(p_sh, o_sh,
                                                 {'tokens': d_sh, 'labels': d_sh}),
                             out_shardings=None)
@@ -153,6 +153,7 @@ def test_moe_sharded_dispatch_matches_local():
         from repro.configs import get_config
         from repro.models import init_lm_params, forward
         from repro.launch.mesh import make_mesh
+        from repro.parallel import sharding as sh
 
         cfg = get_config('granite-moe-1b-a400m').reduced()
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
@@ -162,7 +163,7 @@ def test_moe_sharded_dispatch_matches_local():
         toks = jax.random.randint(key, (8, 16), 0, cfg.vocab_size)
         base, _ = forward(params, cfg, toks)      # no mesh: local path
         mesh = make_mesh((4, 2), ('data', 'model'))
-        with mesh:
+        with sh.use_mesh(mesh):
             out, _ = jax.jit(lambda p, t: forward(p, cfg, t))(params, toks)
         err = float(jnp.max(jnp.abs(out - base)))
         print(json.dumps({'err': err}))

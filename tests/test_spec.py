@@ -91,8 +91,8 @@ def test_verify_oracle_reduces_to_decode_at_w1():
     B, H, KV, Tt, d = 2, 4, 2, 24, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, 1, H, d))
-    k = jax.random.normal(ks[1], (B, Tt, KV, d))
-    v = jax.random.normal(ks[2], (B, Tt, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, Tt, d))       # head-major cache
+    v = jax.random.normal(ks[2], (B, KV, Tt, d))
     lens = jnp.array([9, 23], jnp.int32)
     o_w = ref.verify_decode_attention_ref(q, k, v, lens)
     o_d = ref.decode_attention_ref(q[:, 0], k, v, lens)
@@ -106,8 +106,8 @@ def test_verify_oracle_matches_causal_prefix():
     B, W, H, KV, Tt, d = 1, 4, 4, 2, 16, 8
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, W, H, d))
-    k = jax.random.normal(ks[1], (B, Tt, KV, d))
-    v = jax.random.normal(ks[2], (B, Tt, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, Tt, d))
+    v = jax.random.normal(ks[2], (B, KV, Tt, d))
     lens = jnp.array([13], jnp.int32)
     o_w = ref.verify_decode_attention_ref(q, k, v, lens)
     for j in range(W):
@@ -123,11 +123,11 @@ def test_verify_oracle_ignores_rolled_back_tail():
     B, W, H, KV, Tt, d = 2, 3, 4, 2, 20, 8
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (B, W, H, d))
-    k = jax.random.normal(ks[1], (B, Tt, KV, d))
-    v = jax.random.normal(ks[2], (B, Tt, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, Tt, d))
+    v = jax.random.normal(ks[2], (B, KV, Tt, d))
     lens = jnp.array([7, 15], jnp.int32)
     o1 = ref.verify_decode_attention_ref(q, k, v, lens)
-    pos = jnp.arange(Tt)[None, :, None, None]
+    pos = jnp.arange(Tt)[None, None, :, None]
     poison = pos >= lens[:, None, None, None]
     o2 = ref.verify_decode_attention_ref(
         q, jnp.where(poison, 1e4, k), jnp.where(poison, -1e4, v), lens)
@@ -143,10 +143,10 @@ def test_dense_decode_kernel_post_rollback():
     B, H, KV, Tt, d = 2, 4, 2, 32, 16
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, H, d))
-    k = jax.random.normal(ks[1], (B, Tt, KV, d))
-    v = jax.random.normal(ks[2], (B, Tt, KV, d))
+    k = jax.random.normal(ks[1], (B, KV, Tt, d))
+    v = jax.random.normal(ks[2], (B, KV, Tt, d))
     lens = jnp.array([5, 21], jnp.int32)       # rolled back below written
-    pos = jnp.arange(Tt)[None, :, None, None]
+    pos = jnp.arange(Tt)[None, None, :, None]
     poison = pos >= lens[:, None, None, None]
     kp = jnp.where(poison, 1e4, k)
     vp = jnp.where(poison, -1e4, v)
@@ -165,8 +165,8 @@ def test_paged_decode_kernel_post_rollback():
     n_pool = B * n_p + 1
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, H, d))
-    k_pool = jax.random.normal(ks[1], (n_pool, pt, KV, d))
-    v_pool = jax.random.normal(ks[2], (n_pool, pt, KV, d))
+    k_pool = jax.random.normal(ks[1], (n_pool, KV, pt, d))
+    v_pool = jax.random.normal(ks[2], (n_pool, KV, pt, d))
     # every slot owns ALL n_p of its pages (pre-rollback coverage) ...
     tab = jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
     # ... but lengths rolled back to mid-page values
@@ -182,8 +182,8 @@ def test_paged_decode_kernel_post_rollback():
             valid = np.clip(int(lens[b]) - ip * pt, 0, pt)
             poison = poison.at[page, valid:].set(True)
     poison = poison.at[n_pool - 1].set(True)             # sink row too
-    kp = jnp.where(poison[..., None, None], 1e4, k_pool)
-    vp = jnp.where(poison[..., None, None], -1e4, v_pool)
+    kp = jnp.where(poison[:, None, :, None], 1e4, k_pool)
+    vp = jnp.where(poison[:, None, :, None], -1e4, v_pool)
     o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret")
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
     del flat_pos
