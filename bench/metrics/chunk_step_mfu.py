@@ -1,0 +1,29 @@
+"""chunk_step_mfu: FLOPs of the real tokens of the window's chunk steps
+(prompt chunks and the decode tokens riding with them) over their device
+time times the chip's peak FLOP/s."""
+from bench import trace as tr, work
+from bench.peaks import peaks
+
+# the program the executor compiles for a chunk step
+NAMES = [r"jit_chunk_fn"]
+
+
+def read(ctx):
+    if not ctx.trace or not ctx.trace.devices:
+        return None
+    calls = [c for c in ctx.calls if c["kind"] == "chunk"]
+    if not calls:
+        return None
+    pk = peaks(ctx.device_kind)
+    flops = sum(work.chunk_step_flops(ctx.dims, c["rows"]) for c in calls)
+    shares = []
+    for runs in tr.programs(ctx.trace, NAMES):
+        if len(runs) != len(calls):
+            ctx.notes.append(f"[chunk_step_mfu] {len(runs)} chunk programs "
+                             f"traced, {len(calls)} calls made")
+            return None
+        device = sum(e - s for _, s, e in runs)
+        shares.append(100.0 * flops / (device * pk["flops"]))
+    ctx.notes.append(f"[chunk_step_mfu] {len(calls)} chunk steps, "
+                     f"{1e3 * device / len(calls):.3f} ms each on the device")
+    return sum(shares) / len(shares)
