@@ -243,6 +243,7 @@ def flash_decode_ranked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
+        name="flash_decode_ranked",
         interpret=interpret,
     )(lengths.astype(jnp.int32),
       jnp.minimum(qk_ranks, dq).astype(jnp.int32),
@@ -306,5 +307,6 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_decode",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k, v)

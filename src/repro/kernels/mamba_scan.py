@@ -103,6 +103,7 @@ def mamba_scan(dt: jnp.ndarray, A: jnp.ndarray, Bmat: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((tile, dS), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="mamba_scan",
         interpret=interpret,
     )(dt, Bmat, C, x, A, h0)
     return y, h_end

@@ -80,6 +80,7 @@ def page_copy(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray, *,
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="page_copy",
         interpret=interpret,
     )(src.astype(jnp.int32), dst.astype(jnp.int32), pool)
 
@@ -125,5 +126,6 @@ def page_restore(pool: jnp.ndarray, rows: jnp.ndarray, dst: jnp.ndarray,
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="page_restore",
         interpret=interpret,
     )(dst.astype(jnp.int32), rows.astype(pool.dtype), pool)

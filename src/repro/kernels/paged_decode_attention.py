@@ -242,6 +242,7 @@ def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
+        name="paged_flash_decode_ranked",
         interpret=interpret,
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32),
       jnp.minimum(qk_ranks, dq).astype(jnp.int32),
@@ -308,6 +309,7 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_flash_decode",
         interpret=interpret,
     )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), q,
       k_pool, v_pool)

@@ -108,6 +108,7 @@ def wkv6(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="wkv6",
         interpret=interpret,
     )(r, k, v, logw, u, s0)
     return out, s_end

@@ -297,6 +297,21 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         return jnp.einsum("bshv,hvd->bsd", ctx,
                           params["wo"][..., :dv, :].astype(x.dtype))
 
+    def _masked(k, v, mask):
+        """Masked softmax attention over dense head-major K/V (B, KV, T,
+        r) with a (B, S, T) mask: the (B, S, H, dv) context."""
+        qg = q.reshape(B, S, KV, G, dq)
+        logits = jnp.einsum("bskgq,bktq->bkgst", qg, k) * scale
+        if cfg.attn_logit_softcap > 0:
+            c = cfg.attn_logit_softcap
+            logits = jnp.tanh(logits / c) * c
+        logits = logits.astype(jnp.float32)
+        neg = jnp.finfo(jnp.float32).min
+        logits = jnp.where(mask[:, None, None, :, :], logits, neg)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
+        return jnp.einsum("bkgst,bktv->bskgv", probs,
+                          v).reshape(B, S, H, dv)
+
     # Partial-RoPE pruning keeps the rotated block intact at the front, so
     # RoPE always applies to the first rope_dims (<= dq) dims.
     rot = min(cfg.rope_dims, dq)
@@ -358,30 +373,39 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             page = jnp.where(pos >= write_floor[:, None], page, N - 1)
         off = pos % PT
         # (page, :, off) scatter: the (B, S) index pairs lead, so the
-        # update is the token-major (B, S, KV, r) projection itself
-        ck = kv_cache["k"].at[page, :, off].set(
-            _pad_rank(k, dq_c).astype(kv_cache["k"].dtype))
-        cv = kv_cache["v"].at[page, :, off].set(
-            _pad_rank(v, dv_c).astype(kv_cache["v"].dtype))
+        # update is the token-major (B, S, KV, r) projection itself.
+        # The named scopes (kv_write, kv_read, paged_decode, kv_gather)
+        # label the KV data movement in profiler traces.
+        with jax.named_scope("kv_write"):
+            ck = kv_cache["k"].at[page, :, off].set(
+                _pad_rank(k, dq_c).astype(kv_cache["k"].dtype))
+            cv = kv_cache["v"].at[page, :, off].set(
+                _pad_rank(v, dv_c).astype(kv_cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         if use_pallas and S == 1:  # paged flash-decoding: the hot path
             lengths = (cache_index + 1).astype(jnp.int32)
-            ctx = dispatch.paged_decode_attention(
-                q[:, 0], ck[..., :dq].astype(x.dtype),
-                cv[..., :dv].astype(x.dtype),
-                page_table, lengths, scale=scale,
-                **rank_kw)[:, None]                         # (B,1,H,dv)
+            with jax.named_scope("kv_read"):
+                kr = ck[..., :dq].astype(x.dtype)
+                vr = cv[..., :dv].astype(x.dtype)
+            with jax.named_scope("paged_decode"):
+                ctx = dispatch.paged_decode_attention(
+                    q[:, 0], kr, vr, page_table, lengths, scale=scale,
+                    **rank_kw)[:, None]                     # (B,1,H,dv)
             return _vo_out(ctx), new_cache
         # Chunked-prefill reads gather each slot's pages into a dense
-        # (B, KV, P*PT, r) view and reuse the masked path below; writes
+        # (B, KV, P*PT, r) view and run the masked path over it; writes
         # stay pool-resident (noted in DESIGN.md §6 as the cold path).
         from repro.kernels.ref import gather_pages
-        k = gather_pages(ck, page_table)[..., :dq].astype(x.dtype)
-        v = gather_pages(cv, page_table)[..., :dv].astype(x.dtype)
-        T = k.shape[2]
-        kv_pos = jnp.arange(T, dtype=jnp.int32)
-        qpos = cache_index[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        mask = kv_pos[None, None, :] <= qpos[:, :, None]      # (B, S, T)
+        with jax.named_scope("kv_gather"):
+            k = gather_pages(ck, page_table)[..., :dq].astype(x.dtype)
+            v = gather_pages(cv, page_table)[..., :dv].astype(x.dtype)
+            T = k.shape[2]
+            kv_pos = jnp.arange(T, dtype=jnp.int32)
+            qpos = (cache_index[:, None]
+                    + jnp.arange(S, dtype=jnp.int32)[None, :])
+            mask = kv_pos[None, None, :] <= qpos[:, :, None]  # (B, S, T)
+            ctx = _masked(k, v, mask)
+        return _vo_out(ctx), new_cache
     elif kv_cache is not None:
         # cache_index: scalar (whole batch at one position — prefill and
         # lockstep decode) or (B,) vector (per-slot positions — the
@@ -449,18 +473,7 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         qpos = jnp.arange(S, dtype=jnp.int32)
         mask = (qpos[None, :, None] >= qpos[None, None, :])
         mask = jnp.broadcast_to(mask, (B, S, T))
-
-    qg = q.reshape(B, S, KV, G, dq)
-    logits = jnp.einsum("bskgq,bktq->bkgst", qg, k) * scale
-    if cfg.attn_logit_softcap > 0:
-        c = cfg.attn_logit_softcap
-        logits = jnp.tanh(logits / c) * c
-    logits = logits.astype(jnp.float32)
-    neg = jnp.finfo(jnp.float32).min
-    logits = jnp.where(mask[:, None, None, :, :], logits, neg)
-    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bkgst,bktv->bskgv", probs, v).reshape(B, S, H, dv)
-    return _vo_out(ctx), new_cache
+    return _vo_out(_masked(k, v, mask)), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -484,6 +497,7 @@ def init_mlp(key, cfg, dtype) -> Params:
     return p
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params: Params, cfg, x: jnp.ndarray) -> jnp.ndarray:
     if "up_u" in params:  # CLOVER blockwise-decomposed Up (+ transition)
         h = jnp.einsum("bsd,dnr->bsnr", x, params["up_u"].astype(x.dtype))

@@ -166,6 +166,7 @@ def _embed(params, cfg, tokens, positions, frontend_embeds):
     return constrain(x, (BATCH, None, None))
 
 
+@jax.named_scope("lm_head")
 def _logits(params, cfg, x):
     from repro.parallel.sharding import constrain, BATCH, VOCAB
     if cfg.tie_embeddings:

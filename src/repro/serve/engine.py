@@ -75,6 +75,7 @@ from repro.serve.faults import FaultError, FaultPlan
 from repro.serve.memory import HostTier, PageAllocator, PrefixCache
 from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import DONE, Request, Scheduler
+from repro.serve.trace import span, step_span
 
 Params = Dict[str, Any]
 
@@ -265,8 +266,8 @@ class Engine:
 
     def stats(self) -> dict:
         """Serving metrics snapshot: lifecycle/fault counters, per-
-        priority-class TTFT/ITL percentiles (deterministic steps and
-        wall clock), scheduler and pool counters."""
+        priority-class TTFT/ITL percentiles on the step clock,
+        scheduler and pool counters."""
         out = self.metrics.snapshot()
         out["steps"] = self.steps
         out["max_active"] = self.max_active
@@ -339,12 +340,16 @@ class Engine:
             try:
                 if self.faults is not None and self.faults.fire("step"):
                     raise FaultError(f"injected {name} failure")
-                logits, state = fn(*args)
-                logits = np.asarray(logits)
+                with span("serve.dispatch"):
+                    logits, state = fn(*args)
+                with span("serve.logits_pull"):
+                    logits = np.asarray(logits)
                 if self.faults is not None and self.faults.fire("nan"):
                     logits = np.where(np.ones_like(logits, bool),
                                       np.nan, logits)
-                if not np.isfinite(logits[active]).all():
+                with span("serve.finite_check"):
+                    finite = np.isfinite(logits[active]).all()
+                if not finite:
                     raise FaultError(f"non-finite logits from {name}")
                 if attempt > 0:
                     self.metrics.bump("faults_recovered")
@@ -588,7 +593,8 @@ class Engine:
                         f" pages, pool has {alloc.n_pages}")
                 sched.preempt(victim, n_valid=int(self.written[victim]))
         if pairs:
-            self._copy_pages(pairs)
+            with span("serve.page_copy"):
+                self._copy_pages(pairs)
 
     # -- speculative round (DESIGN.md §8) ------------------------------
     def _spec_due(self) -> bool:
@@ -640,33 +646,34 @@ class Engine:
         logits, self.state = self._guarded(
             "verify_chunk", active, self.exe.verify_chunk,
             self.state, tokens, lengths, pages, wfloor, aids)
-        targets = np.argmax(logits, axis=-1)                   # (slots, W)
-        now = time.monotonic()
-        self.spec_rounds += 1
-        for s in range(slots):
-            if not active[s]:
-                continue
-            req = sched.slot_req[s]
-            a = 0
-            while a < k and drafts[s, a] == targets[s, a]:
-                a += 1
-            out = [int(t) for t in drafts[s, :a]] + [int(targets[s, a])]
-            # honor max_new_tokens / eos exactly as the one-token path
-            # would have: anything past the stop point is dropped (the
-            # slot retires this step, so the over-committed cache tail
-            # is unreachable)
-            out = out[:req.max_new_tokens - len(req.generated)]
-            if ecfg.eos_id >= 0 and ecfg.eos_id in out:
-                out = out[:out.index(ecfg.eos_id) + 1]
-            for t in out:
-                req.generated.append(t)
-                req.token_times.append(now)
-                req.token_steps.append(self.steps)
-                self._tokens_committed += 1
-                self.adapter_tokens[req.adapter_id] += 1
-            self.accept_hist[len(out)] += 1
-            sched.last_token[s] = targets[s, a]
-            self.written[s] = n0[s] + a + 1
+        with span("serve.sample"):
+            targets = np.argmax(logits, axis=-1)                   # (slots, W)
+            now = time.monotonic()
+            self.spec_rounds += 1
+            for s in range(slots):
+                if not active[s]:
+                    continue
+                req = sched.slot_req[s]
+                a = 0
+                while a < k and drafts[s, a] == targets[s, a]:
+                    a += 1
+                out = [int(t) for t in drafts[s, :a]] + [int(targets[s, a])]
+                # honor max_new_tokens / eos exactly as the one-token path
+                # would have: anything past the stop point is dropped (the
+                # slot retires this step, so the over-committed cache tail
+                # is unreachable)
+                out = out[:req.max_new_tokens - len(req.generated)]
+                if ecfg.eos_id >= 0 and ecfg.eos_id in out:
+                    out = out[:out.index(ecfg.eos_id) + 1]
+                for t in out:
+                    req.generated.append(t)
+                    req.token_times.append(now)
+                    req.token_steps.append(self.steps)
+                    self._tokens_committed += 1
+                    self.adapter_tokens[req.adapter_id] += 1
+                self.accept_hist[len(out)] += 1
+                sched.last_token[s] = targets[s, a]
+                self.written[s] = n0[s] + a + 1
         # roll back: commit per-slot lengths (idle slots advanced by 0)
         self.state = self.exe.commit_index(self.state,
                                            self.written.astype(np.int32))
@@ -686,60 +693,71 @@ class Engine:
         the guards) with NO host bookkeeping applied for the aborted
         call — the caller recovers."""
         sched = self.sched
-        spec = self._spec_due()
-        pages = wfloor = None
-        # newly admitted slots restart their tenure at their resume
-        # point — 0, or the first uncached position on a prefix hit
-        # (the device index follows via the executor's fresh-reset at
-        # plan time; the host mirror drives page coverage, COW
-        # detection AND the speculative rollback's index commit)
-        for s in range(self.ecfg.slots):
-            if sched.slot_req[s] is not None and sched.fresh[s]:
-                self.written[s] = int(sched.resume[s])
-        # pin IDLE rows' index at 0 via the same fresh-reset the newly
-        # admitted rows use: decode steps advance every row's device
-        # index (+1, active or not), so a long-idle slot's index would
-        # otherwise run past its page table and its scatter lane could
-        # alias another slot's live page (see models/layers.py)
-        active_rows = np.array([r is not None for r in sched.slot_req])
-        self.written[~active_rows] = 0
-        resume = np.where(active_rows, sched.resume, 0).astype(np.int32)
-        if self.alloc is not None:
-            self._ensure_pages(self.ecfg.spec_window if spec else 1)
-            pages = self.alloc.table_array()
-            # defense in depth: scatter-writes below each slot's resume
-            # point are rerouted to the garbage row on device, so even
-            # a host-side COW bug cannot corrupt a shared cached prefix
-            wfloor = resume
-            self.peak_page_util = max(self.peak_page_util,
-                                      self.alloc.utilization())
-        # recompute after _ensure_pages: preemption may have idled slots
-        active = np.array([r is not None for r in sched.slot_req])
-        aids = self._slot_aids()
-        self.max_active = max(self.max_active, int(active.sum()))
-        if sched.has_chunk_work():
-            tokens, lengths, fresh = sched.plan_chunk()
+        with span("serve.plan"):
+            spec = self._spec_due()
+            pages = wfloor = None
+            # newly admitted slots restart their tenure at their resume
+            # point — 0, or the first uncached position on a prefix hit
+            # (the device index follows via the executor's fresh-reset
+            # at plan time; the host mirror drives page coverage, COW
+            # detection AND the speculative rollback's index commit)
+            for s in range(self.ecfg.slots):
+                if sched.slot_req[s] is not None and sched.fresh[s]:
+                    self.written[s] = int(sched.resume[s])
+            # pin IDLE rows' index at 0 via the same fresh-reset the
+            # newly admitted rows use: decode steps advance every row's
+            # device index (+1, active or not), so a long-idle slot's
+            # index would otherwise run past its page table and its
+            # scatter lane could alias another slot's live page (see
+            # models/layers.py)
+            active_rows = np.array([r is not None for r in sched.slot_req])
+            self.written[~active_rows] = 0
+            resume = np.where(active_rows, sched.resume, 0).astype(np.int32)
+            if self.alloc is not None:
+                with span("serve.pages"):
+                    self._ensure_pages(self.ecfg.spec_window if spec else 1)
+                pages = self.alloc.table_array()
+                # defense in depth: scatter-writes below each slot's
+                # resume point are rerouted to the garbage row on
+                # device, so even a host-side COW bug cannot corrupt a
+                # shared cached prefix
+                wfloor = resume
+                self.peak_page_util = max(self.peak_page_util,
+                                          self.alloc.utilization())
+            # recompute after _ensure_pages: preemption may have idled
+            # slots
+            active = np.array([r is not None for r in sched.slot_req])
+            aids = self._slot_aids()
+            self.max_active = max(self.max_active, int(active.sum()))
+            chunk = sched.has_chunk_work()
+            if chunk:
+                tokens, lengths, fresh = sched.plan_chunk()
+            elif not active.any():
+                return 0
+            elif not spec:
+                tokens, fresh = sched.plan_decode()
+        if chunk:
             logits, self.state = self._guarded(
                 "prefill_chunk", lengths > 0, self.exe.prefill_chunk,
                 self.state, tokens, lengths, fresh | ~active,
                 resume, pages, wfloor, aids)
-            self.written += lengths        # device: index += lengths
-            self._prefill_consumed += int(lengths.sum())
-            self._emit(sched.advance_chunk(lengths), logits)
-        elif spec and active.any():
+            with span("serve.sample"):
+                self.written += lengths    # device: index += lengths
+                self._prefill_consumed += int(lengths.sum())
+                self._emit(sched.advance_chunk(lengths), logits)
+        elif spec:
             self._spec_round(pages, wfloor)
-        elif active.any():
-            tokens, fresh = sched.plan_decode()
+        else:
             logits, self.state = self._guarded(
                 "decode_step", active, self.exe.decode_step,
                 self.state, tokens, fresh | ~active,
                 resume, pages, wfloor, aids)
-            self.written += 1              # device: index += 1, all slots
-            self._emit(sched.advance_decode(), logits)
-        else:
-            return 0
-        sched.retire(self.written)
-        return len([r for r in sched.slot_req if r is not None])
+            with span("serve.sample"):
+                self.written += 1          # device: index += 1, all slots
+                self._emit(sched.advance_decode(), logits)
+        with span("serve.retire"):
+            sched.retire(self.written)
+            return len([r for r in sched.slot_req if r is not None])
 
     def _progress_marker(self) -> Tuple[int, int, int]:
         return (self._tokens_committed, self._prefill_consumed,
@@ -751,24 +769,29 @@ class Engine:
         boundary, recover from an aborted step, feed the watchdog.
         Returns the number of active slots after the step."""
         sched = self.sched
-        sched.now_step = self.steps
-        sched.enforce_deadlines()
-        sched.admit()
-        before = self._progress_marker()
-        try:
-            n_active = self._step_inner()
-        except _StepAbort:
-            self._recover()
-            n_active = 0
-        if self._progress_marker() != before:
-            self._last_progress = self.steps
-        elif (self.ecfg.watchdog_steps > 0 and sched.busy
-              and self.steps - self._last_progress
-              >= self.ecfg.watchdog_steps):
-            self._watchdog_shed()
-            self._last_progress = self.steps
-        self.steps += 1
-        return n_active
+        with step_span(self.steps):
+            sched.now_step = self.steps
+            with span("serve.deadlines"):
+                sched.enforce_deadlines()
+            with span("serve.admit"):
+                sched.admit()
+            before = self._progress_marker()
+            try:
+                n_active = self._step_inner()
+            except _StepAbort:
+                with span("serve.recover"):
+                    self._recover()
+                n_active = 0
+            if self._progress_marker() != before:
+                self._last_progress = self.steps
+            elif (self.ecfg.watchdog_steps > 0 and sched.busy
+                  and self.steps - self._last_progress
+                  >= self.ecfg.watchdog_steps):
+                with span("serve.recover"):
+                    self._watchdog_shed()
+                self._last_progress = self.steps
+            self.steps += 1
+            return n_active
 
     def run(self, requests: List[Request], max_steps: int = 100000,
             ) -> List[Request]:

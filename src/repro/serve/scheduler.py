@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.serve.config import EngineConfig
 from repro.serve.memory import PageAllocator, PrefixCache
+from repro.serve.trace import span
 
 # slot phases
 PREFILL = "prefill"     # prompt tokens remain; consumed chunk-wise
@@ -78,13 +79,16 @@ class Request:
     # prefix-cache hit size at the LAST admission: prompt tokens whose
     # K/V came from shared pages (their prefill chunks were skipped)
     cached_tokens: int = 0
-    # serving metrics, wall clock (monotonic): submit time, one stamp
-    # per emitted token (token_times[0] is first-token / end of prefill)
+    # serving metrics, wall clock (monotonic): submit time, first
+    # admission (None while never admitted), one stamp per emitted
+    # token (token_times[0] is first-token / end of prefill)
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     token_times: List[float] = field(default_factory=list)
     # serving metrics, deterministic clock (engine step indices) —
     # bit-reproducible TTFT/ITL, what the overload benchmark gates on
     submit_step: int = -1
+    admit_step: int = -1
     token_steps: List[int] = field(default_factory=list)
     finish_step: int = -1
     # queue ordering ticket (set by the scheduler; preemption/requeue
@@ -272,33 +276,35 @@ class Scheduler:
             assert L > 0 and L + remaining <= self.ecfg.max_len
             resume = 0
             if self.alloc is not None:
-                # speculative verify windows transiently overhang
-                # the committed length by up to spec_k tokens
-                slack = self.ecfg.spec_k
-                assert (self.alloc.pages_for(L + remaining + slack)
-                        <= self.alloc.n_pages)
-                hit_pages = 0
-                if self.prefix is not None:
-                    pages = self.prefix.match(eff, extra=req._trie_extra)
-                    if pages and self.alloc.map_shared(s, pages):
-                        # at least one token must remain to prefill
-                        # (its logits seed generation); a FULL hit
-                        # resumes at L-1 and the rewrite of that
-                        # position COWs the shared last page
-                        pt = self.alloc.page_tokens
-                        hit_pages = len(pages)
-                        resume = min(hit_pages * pt, L - 1)
-                ok = self.alloc.ensure(s, L)
-                if not ok and self.prefix is not None:
-                    # cached-but-idle prefixes are reclaimable
-                    # bytes: evict LRU trie pages nobody maps and
-                    # retry (matched pages are slot-mapped now, so
-                    # eviction can never touch THIS hit)
-                    short = (self.alloc.pages_for(L)
-                             - len(self.alloc.tables[s])
-                             - self.alloc.free_pages)
-                    if short > 0 and self.prefix.evict(short) > 0:
-                        ok = self.alloc.ensure(s, L)
+                with span("serve.prefix_match"):
+                    # speculative verify windows transiently overhang
+                    # the committed length by up to spec_k tokens
+                    slack = self.ecfg.spec_k
+                    assert (self.alloc.pages_for(L + remaining + slack)
+                            <= self.alloc.n_pages)
+                    hit_pages = 0
+                    if self.prefix is not None:
+                        pages = self.prefix.match(eff,
+                                                  extra=req._trie_extra)
+                        if pages and self.alloc.map_shared(s, pages):
+                            # at least one token must remain to prefill
+                            # (its logits seed generation); a FULL hit
+                            # resumes at L-1 and the rewrite of that
+                            # position COWs the shared last page
+                            pt = self.alloc.page_tokens
+                            hit_pages = len(pages)
+                            resume = min(hit_pages * pt, L - 1)
+                    ok = self.alloc.ensure(s, L)
+                    if not ok and self.prefix is not None:
+                        # cached-but-idle prefixes are reclaimable
+                        # bytes: evict LRU trie pages nobody maps and
+                        # retry (matched pages are slot-mapped now, so
+                        # eviction can never touch THIS hit)
+                        short = (self.alloc.pages_for(L)
+                                 - len(self.alloc.tables[s])
+                                 - self.alloc.free_pages)
+                        if short > 0 and self.prefix.evict(short) > 0:
+                            ok = self.alloc.ensure(s, L)
                 if not ok:
                     # head-of-line: the best candidate waits for pages
                     # (undo the shared mapping so the trie can evict)
@@ -312,14 +318,18 @@ class Scheduler:
                     # re-prefilling.  On a host_copy fault the callback
                     # returns what it managed (possibly 0); the resume
                     # point only ever advances over RESTORED pages.
-                    n_rest = self.restore(s, eff, hit_pages,
-                                          req._trie_extra)
+                    with span("serve.host_restore"):
+                        n_rest = self.restore(s, eff, hit_pages,
+                                              req._trie_extra)
                     if n_rest > 0:
                         resume = min((hit_pages + n_rest)
                                      * self.alloc.page_tokens, L - 1)
             self.queue.remove(req)
             req.cached_tokens = resume
             req.status = RUNNING
+            if req.admit_step < 0:
+                req.admit_step = self.now_step
+                req.t_admit = time.monotonic()
             if resume > 0:
                 self.prefix_hits += 1
                 self.prefix_hit_tokens += resume
