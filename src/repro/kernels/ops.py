@@ -21,7 +21,7 @@ resolved once per (impl alias, platform, mesh):
   paged-decode, page-copy, full-sequence attention) run PER SHARD
   under ``shard_map`` with serve-rules operand specs (slot batch over
   "data", KV heads over "model" — ``parallel.sharding.kernel_axes``).
-  Per-(slot, kv-head) grid cells are independent, so the sharded
+  Per-(slot, kv-head) results are independent, so the sharded
   outputs are bitwise identical to the single-device kernels.  Page
   ids stay HOST-GLOBAL: the pools' page-row axis is replicated
   (``serve_state_specs``), so scalar-prefetched page tables need no
@@ -288,8 +288,8 @@ class KernelDispatch:
 
         No padding is needed: the pool's ``page_tokens`` axis IS the
         block size, and page-table entries past each slot's in-use
-        pages are never dereferenced (the kernel clamps its sequential
-        axis per row).  Under a mesh the pools split along KV heads
+        pages are never dereferenced (the kernel walks only each row's
+        in-use page blocks).  Under a mesh the pools split along KV heads
         only; their page-row axis is REPLICATED, so the host-global
         page ids in ``page_table`` are valid row indices on every
         shard — the scalar-prefetched table crosses the shard_map

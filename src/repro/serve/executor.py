@@ -565,8 +565,8 @@ class ShardedExecutor(LocalExecutor):
     (``kernels.ops.KernelDispatch``).  Page ids stay host-global — the
     pools' page-row axis is replicated, so the scalar-prefetched page
     tables cross the shard boundary untranslated and each shard reads
-    its own KV-head slice of the same rows.  Per-(slot, kv-head) grid
-    cells are independent, so per-shard kernel outputs are bitwise
+    its own KV-head slice of the same rows.  Per-(slot, kv-head)
+    results are independent, so per-shard kernel outputs are bitwise
     identical to the single-device kernels.  The one combo that cannot
     run per shard — recurrent kernels under tp > 1 — is rejected with
     a ``ValueError`` up front (``validate_kernel_parallelism``), never

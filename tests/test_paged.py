@@ -120,32 +120,55 @@ def test_paged_engine_interpret_kernel_decode():
 # kernel: interpret parity vs the paged reference
 # ---------------------------------------------------------------------------
 
-def _rand_paged_case(key, B, H, KV, dq, dv, pt, n_p, n_pool, max_len):
+def _rand_paged_case(key, B, H, KV, dq, dv, pt, n_p, n_pool, max_len,
+                     lengths=None, extra=0):
+    """Random pools and disjoint page tables; each row owns its in-use
+    pages plus ``extra`` more (a rolled-back row keeps pages past its
+    length), and the sentinel row (``n_pool - 1``) past those."""
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (B, H, dq))
     k_pool = jax.random.normal(ks[1], (n_pool, KV, pt, dq))
     v_pool = jax.random.normal(ks[2], (n_pool, KV, pt, dv))
-    lengths = jax.random.randint(ks[3], (B,), 1, max_len + 1)
-    # disjoint page tables, sentinel (= n_pool - 1) past each row's pages
+    if lengths is None:
+        lengths = jax.random.randint(ks[3], (B,), 1, max_len + 1)
+    lengths = jnp.asarray(lengths, jnp.int32)
     perm = np.random.default_rng(0).permutation(n_pool - 1)
     tab = np.full((B, n_p), n_pool - 1, np.int32)
     off = 0
     for b in range(B):
-        used = -(-int(lengths[b]) // pt)
-        tab[b, :used] = perm[off:off + used]
-        off += used
+        owned = min(-(-int(lengths[b]) // pt) + extra, n_p)
+        tab[b, :owned] = perm[off:off + owned]
+        off += owned
     return q, k_pool, v_pool, jnp.asarray(tab), lengths
 
 
-@pytest.mark.parametrize("B,H,KV,dq,dv,pt,n_p", [
-    (2, 4, 2, 32, 24, 8, 4),     # GQA, asymmetric (CLOVER-pruned shape)
-    (3, 8, 1, 16, 16, 4, 6),     # MQA, partial last pages
-    (1, 16, 16, 8, 8, 16, 2),    # MHA
+@pytest.mark.parametrize("B,H,KV,dq,dv,pt,n_p,lens,extra", [
+    # GQA, asymmetric (CLOVER-pruned shape)
+    pytest.param(2, 4, 2, 32, 24, 8, 4, None, 0, id="2-4-2-32-24-8-4"),
+    # MQA, partial last pages
+    pytest.param(3, 8, 1, 16, 16, 4, 6, None, 0, id="3-8-1-16-16-4-6"),
+    # MHA
+    pytest.param(1, 16, 16, 8, 8, 16, 2, None, 0, id="1-16-16-8-8-16-2"),
+    # 16-token pages stream 8 a block: an 11-page table is 8 + 3
+    pytest.param(3, 4, 2, 32, 32, 16, 11, None, 0,
+                 id="table-not-block-multiple"),
+    # one token, one whole block, one past it, the full table
+    pytest.param(4, 4, 2, 32, 32, 16, 20, (1, 128, 129, 320), 0,
+                 id="block-edge-lengths"),
+    # GPT-2 XL after a 50% prune: 25 MHA heads at rank 32
+    pytest.param(2, 25, 25, 32, 32, 16, 10, (160, 37), 0,
+                 id="gpt2-xl-width"),
+    # 4 queries per KV head
+    pytest.param(2, 16, 4, 64, 64, 16, 12, (190, 17), 0, id="gqa-g4"),
+    # rows own 3 pages past ceil(length / page_tokens), as after a
+    # speculative rollback
+    pytest.param(3, 4, 2, 16, 16, 16, 12, (20, 129, 100), 3,
+                 id="rolled-back-pages"),
 ])
-def test_paged_decode_kernel_sweep(B, H, KV, dq, dv, pt, n_p):
+def test_paged_decode_kernel_sweep(B, H, KV, dq, dv, pt, n_p, lens, extra):
     q, kp, vp, tab, lens = _rand_paged_case(
         jax.random.PRNGKey(B + H), B, H, KV, dq, dv, pt, n_p,
-        n_pool=B * n_p + 1, max_len=n_p * pt)
+        n_pool=B * n_p + 1, max_len=n_p * pt, lengths=lens, extra=extra)
     o_ref = ref.paged_decode_attention_ref(q, kp, vp, tab, lens)
     o_pal = ops.paged_decode_attention(q, kp, vp, tab, lens,
                                        impl="interpret")
@@ -156,22 +179,27 @@ def test_paged_decode_kernel_sweep(B, H, KV, dq, dv, pt, n_p):
 def test_paged_decode_kernel_ignores_garbage_pages():
     """Poisoning the sink row and every unreferenced pool row must not
     change the output — the indirection + length mask fully isolate a
-    slot from other slots' (and nobody's) pages."""
-    B, H, KV, dq, dv, pt, n_p = 2, 4, 2, 16, 16, 4, 4
-    n_pool = B * n_p + 1
-    q, kp, vp, tab, lens = _rand_paged_case(
-        jax.random.PRNGKey(9), B, H, KV, dq, dv, pt, n_p,
-        n_pool=n_pool, max_len=n_p * pt)
-    o1 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret")
-    used = set()
-    for b in range(B):
-        used |= {int(tab[b, i]) for i in range(-(-int(lens[b]) // pt))}
-    for row in range(n_pool):
-        if row not in used:
-            kp = kp.at[row].set(1e4)
-            vp = vp.at[row].set(-1e4)
-    o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret")
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+    slot from other slots' (and nobody's) pages.  The second case walks
+    rows of several page blocks that own pages past their length."""
+    for pt, n_p, lens, extra in ((4, 4, None, 0), (4, 40, (150, 61), 2)):
+        B, H, KV, dq, dv = 2, 4, 2, 16, 16
+        n_pool = B * n_p + 1
+        q, kp, vp, tab, lens = _rand_paged_case(
+            jax.random.PRNGKey(9), B, H, KV, dq, dv, pt, n_p,
+            n_pool=n_pool, max_len=n_p * pt, lengths=lens, extra=extra)
+        o1 = ops.paged_decode_attention(q, kp, vp, tab, lens,
+                                        impl="interpret")
+        used = set()
+        for b in range(B):
+            used |= {int(tab[b, i]) for i in range(-(-int(lens[b]) // pt))}
+        for row in range(n_pool):
+            if row not in used:
+                kp = kp.at[row].set(1e4)
+                vp = vp.at[row].set(-1e4)
+        o2 = ops.paged_decode_attention(q, kp, vp, tab, lens,
+                                        impl="interpret")
+        np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
+                                   atol=1e-5)
 
 
 def test_paged_ref_matches_dense_ref():

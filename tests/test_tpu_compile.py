@@ -9,7 +9,9 @@ TPU compiler is installed here, no chip is needed, nothing runs.
 
 Widths: GPT-2 XL after a 50% CLOVER prune (25 MHA heads, head_dim 64,
 rank 32) and a GQA width (phi3-medium: 10 KV heads, 4 queries per KV
-head, head_dim 128), bf16, 16-token pages.
+head, head_dim 128), bf16, 16-token pages.  The paged decode kernel is
+also compiled at the benchmark engine's own shape, where its VMEM
+footprint is real.
 
 The topology is described inside a module fixture — never at import —
 so every pytest-xdist worker collects the same tests and only the one
@@ -93,3 +95,22 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, width):
     fn, args = _case(kernel, width, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_compiles_at_benchmark_shape(one_chip):
+    """``paged_flash_decode`` at ``bench/configs/gpt2-xl-clover-r32.json``'s
+    engine: 32 slots, a 68-entry page table (1,024 + 64 tokens of 16),
+    1,536 pages plus the sink row, 25 KV heads at rank 32; one Pallas
+    call."""
+    KV, _, _, r = WIDTHS["gpt2-xl-pruned"]
+    slots, table, rows = 32, 68, 1537
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((rows, KV, PAGE_TOKENS, r))
+    args = (s((slots, KV, r)), pool, pool, s((slots, table), jnp.int32),
+            s((slots,), jnp.int32))
+    fn = KernelDispatch(impl="pallas").paged_decode_attention
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
