@@ -142,6 +142,16 @@ class Recorder:
                 self.admitted_prompt_tokens += len(sched.slot_prompt[s])
 
 
+def bytes_per_device(arrays) -> Dict[int, int]:
+    """Bytes each device holds of ``arrays``, by device id."""
+    out: Dict[int, int] = {}
+    for a in arrays:
+        for piece in a.addressable_shards:
+            out[piece.device.id] = out.get(piece.device.id, 0) \
+                + piece.data.nbytes
+    return out
+
+
 def _warm(eng, waves, until_decoding: bool):
     """Serve the warm-up waves one after the other: each to completion,
     or (an initial wave) until every one is decoding and a decode step
@@ -174,6 +184,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     is called with the engine once it is built (to break the timed
     path), ``"served"`` with what the check samples from."""
     from repro.serve import Engine, Request
+    from repro.serve.executor import ShardedExecutor
     t_start = time.monotonic() if t_start is None else t_start
     hooks = hooks or {}
     bench = load_json(root, "BENCHMARK.json")
@@ -185,9 +196,22 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     ecfg = bm.engine_config(conf, impl)
     complog = CompileLog()
 
-    params = bm.make_params(seed, d)
-    eng = Engine(params, cfg, ecfg)
-    del params
+    params = bm.make_params(seed, d, ecfg.tp)
+    if ecfg.tp > 1:
+        # the executor the engine would build from these weights, built
+        # here so that the weights as drawn (whose attention leaves it
+        # copies to pad their heads) are freed before the engine makes
+        # its KV pool, which it first holds whole on one chip
+        exe = ShardedExecutor(params, cfg, ecfg)
+        del params
+        eng = Engine(None, cfg, ecfg, executor=exe)
+        del exe
+    else:
+        eng = Engine(params, cfg, ecfg)
+        del params
+    placed = {"params": bytes_per_device(jax.tree.leaves(eng.exe.params)),
+              "KV pool": bytes_per_device(jax.tree.leaves(
+                  eng.state["blocks"]))}
     if "engine" in hooks:
         hooks["engine"](eng)
     rec = Recorder(eng, spans) if trace else None
@@ -261,7 +285,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
         t0=t0, t1=t1, seconds=seconds, t_close=t_close, setup_s=setup_s,
         requests=warm_reqs + list(reqs.values()),
         window_requests=list(reqs.values()), due=due, submitted=submitted,
-        slots=ecfg.slots, chunk=ecfg.chunk, dims=d,
+        slots=ecfg.slots, chunk=ecfg.chunk, dims=d, chips=cell["chips"],
         trace=tw, calls=rec.calls if rec else [],
         admitted_prompt_tokens=rec.admitted_prompt_tokens if rec else 0,
         hit_tokens=eng.sched.prefix_hit_tokens - hits0,
@@ -292,6 +316,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     # -- the check against the reference ------------------------------
     limits = load_json(root, "bench", "limits", f"{cell['name']}.json")
+    held = bytes_per_device(jax.live_arrays()).get(jax.devices()[0].id, 0)
     check = correct.check(seed, d, served, mix["check_requests"],
                           mix["max_total"], limits)
     # every request that arrived inside the window, or was served a
@@ -332,14 +357,21 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
         f"{sum(gc_s) * 1e3:.3f} ms in all, longest "
         f"{max(gc_s, default=0.0) * 1e3:.3f} ms")
     log(f"[engine] stats {json.dumps(stats, default=str)}")
+    for what, per in placed.items():
+        log(f"[engine] {what} bytes per device {per}, in all "
+            f"{sum(per.values())}")
     late = [submitted[u] - due[u] for u in submitted if due[u] > t0]
     if late:
         log(f"[generator] {len(late)} arrivals submitted; lateness p50 "
             f"{np.percentile(late, 50) * 1e3:.3f} ms, max "
             f"{max(late) * 1e3:.3f} ms")
     log(f"[window] {len(copies)} copy-on-write page copies")
+    log(f"[memory] peak_bytes_in_use per device "
+        f"{[int(m.get('peak_bytes_in_use', 0)) for m in mem]}")
     for line in ctx.notes:
         log(line)
+    log(f"[check] the reference began with {held} bytes of live arrays "
+        f"on device 0")
     log(f"[check] {check['requests']} requests sampled, "
         f"{check['compared']} served tokens compared")
     result["checks"] = check["limits"]

@@ -1,6 +1,8 @@
 """chunk_step_mfu: FLOPs of the real tokens of the window's chunk steps
 (prompt chunks and the decode tokens riding with them) over their device
-time times the chip's peak FLOP/s."""
+time times the chip's peak FLOP/s.  On a cell of several chips each
+device is held to its share of the work, the logical FLOPs over the
+chips, against its own time: the mean over devices."""
 from bench import trace as tr, work
 from bench.peaks import peaks
 
@@ -15,7 +17,8 @@ def read(ctx):
     if not calls:
         return None
     pk = peaks(ctx.device_kind)
-    flops = sum(work.chunk_step_flops(ctx.dims, c["rows"]) for c in calls)
+    flops = sum(work.chunk_step_flops(ctx.dims, c["rows"])
+                for c in calls) / ctx.chips
     shares = []
     for runs in tr.programs(ctx.trace, NAMES):
         if len(runs) != len(calls):

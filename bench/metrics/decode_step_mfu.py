@@ -2,7 +2,9 @@
 time over device time, summed over the window's decode steps.  A step's
 least time is the larger of its needed FLOPs over peak FLOP/s and its
 needed bytes (weights once, plus the held K/V of its active rows at the
-kept ranks) over peak bandwidth (``bench/work.py``)."""
+kept ranks) over peak bandwidth (``bench/work.py``).  On a cell of
+several chips a device's least time is that of its share, the logical
+FLOPs and bytes over the chips: the mean over devices."""
 from bench import trace as tr, work
 from bench.peaks import peaks
 
@@ -23,7 +25,8 @@ def read(ctx):
             return None
         least, bound = 0.0, {"flops": 0, "bytes": 0}
         for c in calls:
-            t, b = work.least_time(*work.decode_step(ctx.dims, c["held"]),
+            flops, nbytes = work.decode_step(ctx.dims, c["held"])
+            t, b = work.least_time(flops / ctx.chips, nbytes / ctx.chips,
                                    pk)
             least += t
             bound[b] += 1

@@ -3,7 +3,9 @@ its roofline: per call, the least time of its logical work (per active
 row and real KV head: the queries, the K/V of the held tokens at the
 kept ranks, the output; ``bench/work.py``) over the kernel's device
 time, summed over the window's decode steps.  A call is matched to its
-decode step by lying inside that step's program on the device."""
+decode step by lying inside that step's program on the device.  On a
+cell of several chips each device's calls are held to their share of
+the logical work, over the chips: the mean over devices."""
 import numpy as np
 
 from bench import trace as tr, work
@@ -35,8 +37,9 @@ def read(ctx):
             inside = [o for o in ops[lo:hi] if o[1] <= e]
             if not inside:
                 continue
-            t, _ = work.least_time(*work.paged_decode_call(ctx.dims,
-                                                           c["held"]), pk)
+            flops, nbytes = work.paged_decode_call(ctx.dims, c["held"])
+            t, _ = work.least_time(flops / ctx.chips, nbytes / ctx.chips,
+                                   pk)
             least += t * len(inside)
             spent += sum(b - a for a, b in inside)
             n_calls += len(inside)

@@ -3,12 +3,15 @@
 The weights are the benchmark's, not the program's: one jitted call
 draws every leaf on the device from ``--seed``, in the dtype they are
 served in, at the served (CLOVER-pruned) shapes, as an operator loading
-a pruned checkpoint would hand them over.  Each layer's leaves come
-from ``fold_in(key, layer)``, so the reference can draw any one layer
-again, alone, and get the same values (``layer_params``).
+a pruned checkpoint would hand them over; a tensor-parallel
+configuration's leaves are drawn in place on their shards.  Each
+layer's leaves come from ``fold_in(key, layer)``, so the reference can
+draw any one layer again, alone, and get the same values
+(``layer_params``).
 """
 from __future__ import annotations
 
+import functools
 from pathlib import Path
 
 import jax
@@ -128,7 +131,20 @@ def build(key, d: dict) -> dict:
     return top
 
 
-def make_params(seed: int, d: dict) -> dict:
-    """Every weight, on the device, from one jitted call."""
+def make_params(seed: int, d: dict, tp: int = 1) -> dict:
+    """Every weight, on the device, from one jitted call.  At ``tp`` above
+    1 the call draws each leaf straight into the sharding the program's
+    tensor-parallel serving rules give it, over a ``("data", "model")``
+    mesh with ``model=tp``, so no device ever holds the whole model; the
+    values are the one-device draw's."""
+    if tp == 1:
+        return jax.block_until_ready(
+            jax.jit(lambda k: build(k, d))(base_key(seed)))
+    from repro.launch.mesh import make_host_mesh
+    from repro.parallel import sharding as sh
+    fn = functools.partial(build, d=d)
+    mesh = make_host_mesh(model=tp)
+    shapes = jax.eval_shape(fn, base_key(seed))
+    out = sh.shardings(sh.param_specs(shapes, mesh, sh.serve_rules()), mesh)
     return jax.block_until_ready(
-        jax.jit(lambda k: build(k, d))(base_key(seed)))
+        jax.jit(fn, out_shardings=out)(base_key(seed)))

@@ -13,6 +13,7 @@ a small hand-made one (``tests/bench``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
 import re
 import shutil
@@ -29,6 +30,9 @@ WINDOW = "bench.window"
 class Trace:
     devices: Dict[str, Dict[str, List[Interval]]]
     host: List[Interval]
+    # ``device_busy``'s answer, kept: several readers ask for it
+    busy_cache: Optional[list] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def window(self) -> Tuple[float, float]:
         return next((s, e) for n, s, e in self.host if n == WINDOW)
@@ -45,17 +49,20 @@ class Busy:
     fast "how much of [a, b] is covered" queries."""
 
     def __init__(self, intervals, lo: float, hi: float):
-        iv = sorted((max(s, lo), min(e, hi)) for s, e in intervals)
-        merged: List[List[float]] = []
-        for s, e in iv:
-            if e <= s:
-                continue
-            if merged and s <= merged[-1][1]:
-                merged[-1][1] = max(merged[-1][1], e)
-            else:
-                merged.append([s, e])
-        arr = np.asarray(merged, float).reshape(-1, 2)
-        self.starts, self.ends = arr[:, 0], arr[:, 1]
+        iv = np.asarray(intervals, float).reshape(-1, 2)
+        s, e = np.maximum(iv[:, 0], lo), np.minimum(iv[:, 1], hi)
+        keep = e > s
+        s, e = s[keep], e[keep]
+        order = np.argsort(s, kind="stable")
+        s, e = s[order], e[order]
+        # an interval that starts after every earlier one has ended
+        # opens a new run; a run ends at the largest end within it
+        reach = np.maximum.accumulate(e)
+        opens = np.ones(len(s), bool)
+        opens[1:] = s[1:] > reach[:-1]
+        first = np.flatnonzero(opens)
+        last = np.append(first[1:] - 1, len(s) - 1)[:len(first)]
+        self.starts, self.ends = s[first], reach[last]
         self.cum = np.concatenate([[0.0], np.cumsum(self.ends - self.starts)])
         self.total = float(self.cum[-1])
 
@@ -76,9 +83,11 @@ class Busy:
 
 
 def device_busy(trace: Trace) -> List[Busy]:
-    lo, hi = trace.window()
-    return [Busy([(s, e) for _, s, e in d["ops"]], lo, hi)
-            for d in trace.devices.values()]
+    if trace.busy_cache is None:
+        lo, hi = trace.window()
+        trace.busy_cache = [Busy([(s, e) for _, s, e in d["ops"]], lo, hi)
+                            for d in trace.devices.values()]
+    return trace.busy_cache
 
 
 def matches(name: str, patterns) -> bool:
@@ -173,6 +182,7 @@ def _innermost_idle(spans: List[Interval], busy_dev: Busy,
 CONTAINERS = ("while", "conditional", "call")
 
 
+@functools.lru_cache(maxsize=None)
 def op_label(name: str) -> str:
     """A short, stable label for a device op: the HLO instruction name
     without its number (the TPU trace names an op by its whole HLO

@@ -128,7 +128,8 @@ def backlog_traced(tiny_root):
 
 # -- traffic ----------------------------------------------------------
 
-@pytest.mark.parametrize("mix", sorted(TINY_MIXES) + ["chat", "longgen"])
+@pytest.mark.parametrize("mix", sorted(TINY_MIXES)
+                         + ["chat", "longgen", "docqa"])
 def test_traffic_is_fixed_by_the_seed(mix):
     body = TINY_MIXES.get(mix) or json.loads(
         (ROOT / "bench" / "traffic" / f"{mix}.json").read_text())
@@ -234,7 +235,7 @@ def _hand_ctx():
              {"kind": "decode", "active": 2, "held": [17, 12]},
              {"kind": "decode", "active": 1, "held": [18]}]
     return types.SimpleNamespace(trace=_hand_trace(), calls=calls, dims=d,
-                                 slots=4, chunk=16, notes=[],
+                                 slots=4, chunk=16, notes=[], chips=1,
                                  device_kind="TPU v5 lite",
                                  admitted_prompt_tokens=40, hit_tokens=16)
 
