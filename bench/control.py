@@ -4,13 +4,14 @@
         --seconds <s>
 
 For each seed, in one process: a whole run of the cell (set-up, window,
-check), then the float8 control (``bench/reference.py``: the reference
-computed with both operands of every product rounded to float8, in the
-program's place) through the same ``correct.check``, with the cell's
-limits, on the same sample of served requests.  Prints one JSON line
-per seed: the program's check (``correct`` true, its widest gap a lower
-reading) and the control's (``correct`` false, its widest gap an upper
-reading).  The benchmark's own runs never run this.
+check), then the float8 control (``bench/reference.py``: the family's
+reference computed with both operands of every product rounded to
+float8, in the program's place) through the same ``correct.check``,
+with the cell's limits, on the same sample of served requests.  Prints
+one JSON line per seed: the program's check (``correct`` true, its
+widest gap a lower reading) and the control's (``correct`` false, its
+widest gap an upper reading).  The benchmark's own runs never run
+this.
 """
 import argparse
 import json
@@ -28,7 +29,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
-    from bench import correct, harness, model as bm, reference
+    from bench import correct, harness, load_family, reference
     cell = harness.open_cell(ROOT, args.workload, "bench/control.py")
     conf = harness.load_json(ROOT, "bench", "configs",
                              f"{cell['config']}.json")
@@ -36,14 +37,15 @@ def main(argv=None) -> int:
                             f"{cell['traffic']}.json")
     limits = harness.load_json(ROOT, "bench", "limits",
                                f"{cell['name']}.json")
-    d = bm.dims(conf)
+    fam = load_family(ROOT, conf)
+    d = fam.dims(conf)
     for seed in (int(s) for s in args.seeds.split(",")):
         got = {}
         run = harness.run_cell(cell, seed, args.seconds, False,
                                hooks={"served": lambda r: got.update(rows=r)})
         t0 = time.monotonic()
-        ctrl = correct.check(seed, d, got["rows"], mix["check_requests"],
-                             mix["max_total"], limits,
+        ctrl = correct.check(seed, fam, d, got["rows"],
+                             mix["check_requests"], mix["max_total"], limits,
                              gaps=reference.control_gaps)
         print(json.dumps({"seed": seed,
                           "program": {"correct": run["correct"],

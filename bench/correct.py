@@ -4,9 +4,9 @@ After the window, a sample of the requests that were served (drawn
 from the seed, always with the longest one and one of each traffic
 class in it, so a class that takes another path, such as a full
 prefix hit that copies a page on write, is always compared) is run
-through the
-plain float32 reference (``bench/reference.py``) over each prompt and
-its served tokens, teacher-forced.  The number compared is the widest
+through the plain float32 reference of the configuration's family
+(``logits`` of ``bench/families/<arch>.py``) over each prompt and its
+served tokens, teacher-forced.  The number compared is the widest
 gap by which a served token's reference logit lies below the
 reference's best logit at that position: 0 where every served token is
 the reference's own greedy choice.  Greedy serving in bfloat16 picks a
@@ -70,16 +70,17 @@ def batch(rows: List[Served], n: int, length: int):
     return tokens, targets
 
 
-def check(seed: int, d: dict, served: List[Served], n: int, length: int,
-          limits: Dict[str, dict],
+def check(seed: int, fam, d: dict, served: List[Served], n: int,
+          length: int, limits: Dict[str, dict],
           gaps: Callable = reference.served_gaps) -> dict:
-    """``gaps``: the served tokens' (the program's), or the control's
+    """``fam`` is the family, ``d`` its ``dims``.  ``gaps``: the served
+    tokens' (the program's), or the control's
     (``reference.control_gaps``) at the same positions."""
     rows = sample(served, n, seed)
     tokens, targets = batch(rows, n, length)
     out_of_vocab = int(((targets >= d["V"]) | (targets < -1)).sum())
     compared = int((targets >= 0).sum())
-    value = (float(gaps(seed, d, tokens, targets).max())
+    value = (float(gaps(fam, seed, d, tokens, targets).max())
              if compared else float("nan"))
     lim = limits["max_logit_gap"]["limit"]
     ok = bool(compared > 0 and out_of_vocab == 0 and np.isfinite(value)

@@ -4,7 +4,8 @@
 tests call it directly with the Pallas kernels in interpret mode.
 
 Set-up (``setup_s``, from process start to the window opening): the
-weights from the seed in one jitted call, the engine, the traffic, and
+weights from the seed in one jitted call (by the configuration's
+family, ``bench/families/<arch>.py``), the engine, the traffic, and
 a warm-up that compiles every step shape the window uses (the mix's
 initial wave, or its system prompts and a full prefix hit that copies
 a page on write; ``bench/generator.py``).  The window then drives
@@ -28,7 +29,8 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import correct, generator, load_module, model as bm, trace as tr
+from bench import (DEFAULT_ARCH, correct, generator, load_family,
+                   load_module, model as bm, trace as tr)
 from bench.model import ROOT
 
 
@@ -191,12 +193,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     conf = load_json(root, "bench", "configs", f"{cell['config']}.json")
     mix = load_json(root, "bench", "traffic", f"{cell['traffic']}.json")
     spans = load_json(root, "bench", "spans.json")
-    d = bm.dims(conf)
-    cfg = bm.arch_config(conf, impl)
+    fam = load_family(root, conf)
+    d = fam.dims(conf)
+    cfg = fam.arch_config(conf, impl)
     ecfg = bm.engine_config(conf, impl)
     complog = CompileLog()
 
-    params = bm.make_params(seed, d, ecfg.tp)
+    params = fam.make_params(seed, d, ecfg.tp)
     if ecfg.tp > 1:
         # the executor the engine would build from these weights, built
         # here so that the weights as drawn (whose attention leaves it
@@ -285,7 +288,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
         t0=t0, t1=t1, seconds=seconds, t_close=t_close, setup_s=setup_s,
         requests=warm_reqs + list(reqs.values()),
         window_requests=list(reqs.values()), due=due, submitted=submitted,
-        slots=ecfg.slots, chunk=ecfg.chunk, dims=d, chips=cell["chips"],
+        slots=ecfg.slots, chunk=ecfg.chunk, family=fam, dims=d,
+        chips=cell["chips"],
         trace=tw, calls=rec.calls if rec else [],
         admitted_prompt_tokens=rec.admitted_prompt_tokens if rec else 0,
         hit_tokens=eng.sched.prefix_hit_tokens - hits0,
@@ -317,7 +321,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     # -- the check against the reference ------------------------------
     limits = load_json(root, "bench", "limits", f"{cell['name']}.json")
     held = bytes_per_device(jax.live_arrays()).get(jax.devices()[0].id, 0)
-    check = correct.check(seed, d, served, mix["check_requests"],
+    check = correct.check(seed, fam, d, served, mix["check_requests"],
                           mix["max_total"], limits)
     # every request that arrived inside the window, or was served a
     # token inside it (a queue waiting from the start, a first wave)
@@ -383,25 +387,33 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
 def open_cell(root: Path, workload: str, tool: str) -> dict:
     """The cell ``workload`` of ``BENCHMARK.json``, with JAX's persistent
     compilation cache in the checkout (unless ``JAX_COMPILATION_CACHE_DIR``
-    names one), once the chips it asks for are found.  Otherwise says
-    why on stderr and exits 2, before anything is printed on stdout."""
+    names one), once its configuration's family and the chips it asks
+    for are found.  Otherwise says why on stderr and exits 2, before
+    anything is printed on stdout or any weight is drawn."""
+    bench = load_json(root, "BENCHMARK.json")
+    cell = next((w for w in bench["workloads"] if w["name"] == workload),
+                None)
+    if cell is None:
+        _refuse(tool, f"no workload {workload!r} in BENCHMARK.json")
+    conf = load_json(root, "bench", "configs", f"{cell['config']}.json")
+    arch = conf.get("arch", DEFAULT_ARCH)
+    fam = Path("bench", "families", f"{arch}.py")
+    if not (root / fam).is_file():
+        _refuse(tool, f"configuration {cell['config']} names arch "
+                      f"{arch!r}; there is no {fam.as_posix()}")
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           str(root / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    bench = load_json(root, "BENCHMARK.json")
-    cell = next((w for w in bench["workloads"] if w["name"] == workload),
-                None)
     devices = jax.devices()
-    if cell is None:
-        why = f"no workload {workload!r} in BENCHMARK.json"
-    elif devices[0].platform != "tpu":
-        why = f"needs a TPU; JAX found {devices[0].platform!r}"
-    elif len(devices) < cell["chips"]:
-        why = (f"cell {cell['name']} needs {cell['chips']} chips; JAX "
-               f"found {len(devices)}")
-    else:
-        return cell
+    if devices[0].platform != "tpu":
+        _refuse(tool, f"needs a TPU; JAX found {devices[0].platform!r}")
+    if len(devices) < cell["chips"]:
+        _refuse(tool, f"cell {cell['name']} needs {cell['chips']} chips; "
+                      f"JAX found {len(devices)}")
+    return cell
+
+
+def _refuse(tool: str, why: str):
     log(f"{tool}: {why}")
     raise SystemExit(2)
-

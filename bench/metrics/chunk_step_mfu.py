@@ -1,9 +1,10 @@
 """chunk_step_mfu: FLOPs of the real tokens of the window's chunk steps
-(prompt chunks and the decode tokens riding with them) over their device
-time times the chip's peak FLOP/s.  On a cell of several chips each
-device is held to its share of the work, the logical FLOPs over the
-chips, against its own time: the mean over devices."""
-from bench import trace as tr, work
+(prompt chunks and the decode tokens riding with them; the family's
+``chunk_step_flops``) over their device time times the chip's peak
+FLOP/s.  On a cell of several chips each device is held to its share
+of the work, the logical FLOPs over the chips, against its own time:
+the mean over devices."""
+from bench import trace as tr
 from bench.peaks import peaks
 
 # the program the executor compiles for a chunk step
@@ -17,7 +18,7 @@ def read(ctx):
     if not calls:
         return None
     pk = peaks(ctx.device_kind)
-    flops = sum(work.chunk_step_flops(ctx.dims, c["rows"])
+    flops = sum(ctx.family.chunk_step_flops(ctx.dims, c["rows"])
                 for c in calls) / ctx.chips
     shares = []
     for runs in tr.programs(ctx.trace, NAMES):

@@ -1,10 +1,11 @@
 """decode_step_mfu: the decode step's share of the chip's peak: least
 time over device time, summed over the window's decode steps.  A step's
 least time is the larger of its needed FLOPs over peak FLOP/s and its
-needed bytes (weights once, plus the held K/V of its active rows at the
-kept ranks) over peak bandwidth (``bench/work.py``).  On a cell of
-several chips a device's least time is that of its share, the logical
-FLOPs and bytes over the chips: the mean over devices."""
+needed bytes (weights once, plus the held cache of its active rows at
+the kept ranks) over peak bandwidth, as the family's ``decode_step``
+counts them.  On a cell of several chips a device's least time is that
+of its share, the logical FLOPs and bytes over the chips: the mean over
+devices."""
 from bench import trace as tr, work
 from bench.peaks import peaks
 
@@ -25,7 +26,7 @@ def read(ctx):
             return None
         least, bound = 0.0, {"flops": 0, "bytes": 0}
         for c in calls:
-            flops, nbytes = work.decode_step(ctx.dims, c["held"])
+            flops, nbytes = ctx.family.decode_step(ctx.dims, c["held"])
             t, b = work.least_time(flops / ctx.chips, nbytes / ctx.chips,
                                    pk)
             least += t

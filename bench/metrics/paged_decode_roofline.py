@@ -1,11 +1,12 @@
 """paged_decode_roofline: the paged decode-attention kernel's share of
-its roofline: per call, the least time of its logical work (per active
-row and real KV head: the queries, the K/V of the held tokens at the
-kept ranks, the output; ``bench/work.py``) over the kernel's device
-time, summed over the window's decode steps.  A call is matched to its
-decode step by lying inside that step's program on the device.  On a
-cell of several chips each device's calls are held to their share of
-the logical work, over the chips: the mean over devices."""
+its roofline: per call, the least time of its logical work (the
+family's ``kernel_work["paged_flash_decode"]``: the queries, the held
+tokens' cache at the kept ranks and the output, of the active rows and
+real heads alone) over the kernel's device time, summed over the
+window's decode steps.  A call is matched to its decode step by lying
+inside that step's program on the device.  On a cell of several chips
+each device's calls are held to their share of the logical work, over
+the chips: the mean over devices."""
 import numpy as np
 
 from bench import trace as tr, work
@@ -22,6 +23,7 @@ def read(ctx):
         return None
     calls = [c for c in ctx.calls if c["kind"] == "decode"]
     pk = peaks(ctx.device_kind)
+    count = ctx.family.kernel_work["paged_flash_decode"]
     shares = []
     for runs, dev in zip(tr.programs(ctx.trace, PROGRAMS),
                          ctx.trace.devices.values()):
@@ -37,7 +39,7 @@ def read(ctx):
             inside = [o for o in ops[lo:hi] if o[1] <= e]
             if not inside:
                 continue
-            flops, nbytes = work.paged_decode_call(ctx.dims, c["held"])
+            flops, nbytes = count(ctx.dims, c["held"])
             t, _ = work.least_time(flops / ctx.chips, nbytes / ctx.chips,
                                    pk)
             least += t * len(inside)

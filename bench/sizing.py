@@ -78,7 +78,7 @@ def main(argv):
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from bench import model as bm
+    from bench import load_family, model as bm
     from repro.kernels.ops import KernelDispatch
     from repro.models import transformer as T
     from repro.parallel import sharding as sh
@@ -86,16 +86,17 @@ def main(argv):
 
     conf = json.loads((ROOT / "bench" / "configs" / f"{argv[0]}.json")
                       .read_text())
-    d = bm.dims(conf)
+    fam = load_family(ROOT, conf)
+    d = fam.dims(conf)
     tp = int(conf["engine"].get("tp", 1))
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     # the chip donates the state into every step; this CPU process
     # would not, and the analysis would count the pool twice
     ex._donation_supported = lambda: True
-    cfg = dataclasses.replace(bm.arch_config(conf, "xla"),
+    cfg = dataclasses.replace(fam.arch_config(conf, "xla"),
                               kernel_impl=KernelDispatch("pallas"))
-    shapes = jax.eval_shape(lambda k: bm.build(k, d), jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(lambda k: fam.build(k, d), jax.random.PRNGKey(0))
     make, params = described_executor(shapes, cfg, tp, topo)
     for pages in map(int, argv[1:]):
         ecfg = dataclasses.replace(bm.engine_config(conf, "xla"),

@@ -3,12 +3,15 @@
 Nothing here measures speed.  The tests check what a CPU can: that a
 seed fixes the traffic, that the FLOP and byte counts equal hand
 counts, that each trace reader computes its number from a small trace
-made by hand, that the command refuses to run without a TPU, and — on
-a throwaway cell built from files alone (configuration, mixes, an
-arrival process), in a temporary root, with the Pallas kernels in
-interpret mode — that a whole run passes its check, that the float8
-control fails it, and that a broken timed path fails it.
+made by hand, that the command refuses to run without a TPU, that the
+decoder family draws the same weights and reference logits bit for bit
+as before it was a family, and — on a throwaway cell built from files
+alone (configuration, mixes, an arrival process, an architecture), in
+a temporary root, with the Pallas kernels in interpret mode — that a
+whole run passes its check, that the float8 control fails it, and that
+a broken timed path fails it.
 """
+import hashlib
 import json
 import os
 import shutil
@@ -24,7 +27,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import correct, generator, harness, model as bm  # noqa: E402
-from bench import reference, trace as tr, work  # noqa: E402
+from bench import load_family, reference, trace as tr, work  # noqa: E402
 
 TINY_MODEL = {
     "name": "tiny", "family": "dense", "n_layers": 2, "d_model": 64,
@@ -67,14 +70,16 @@ TINY_MIXES = {
 # 352 positions of the fixed sample below.
 TINY_LIMIT = 0.012
 SECONDS = 3.0
+# the family of every configuration that names no ``arch``
+DEC = load_family(ROOT, {})
 
 
 def make_root(root: Path) -> Path:
     """A benchmark root holding a throwaway configuration, two mixes
-    and two cells, written as files only; the metric readers and the
-    span list are the repository's own."""
+    and two cells, written as files only; the metric readers, the
+    families and the span list are the repository's own."""
     (root / "bench").mkdir()
-    for sub in ("metrics", "arrivals"):
+    for sub in ("metrics", "arrivals", "families"):
         shutil.copytree(ROOT / "bench" / sub, root / "bench" / sub)
     shutil.copy(ROOT / "bench" / "spans.json", root / "bench")
     for sub in ("configs", "traffic", "limits"):
@@ -185,22 +190,22 @@ def test_an_arrival_process_added_as_a_file(tmp_path):
 # -- work counts ------------------------------------------------------
 
 def test_work_counts_equal_hand_counts():
-    d = bm.dims({"model": TINY_MODEL})
+    d = DEC.dims({"model": TINY_MODEL})
     D, H, dq, dv, F, V, L = 64, 4, 8, 8, 128, 128, 2
     per_layer = 2 * D * (H * dq * 2 + H * dv) + 2 * H * dv * D + 2 * 2 * D * F
-    assert work.matmul_flops_per_token(d) == L * per_layer
-    assert work.head_flops(d) == 2 * D * V
-    assert work.kv_bytes_per_token(d) == L * H * (dq + dv) * 2
+    assert DEC.matmul_flops_per_token(d) == L * per_layer
+    assert DEC.head_flops(d) == 2 * D * V
+    assert DEC.kv_bytes_per_token(d) == L * H * (dq + dv) * 2
     layer_w = D * (H * dq * 2 + H * dv) + H * dv * D + 2 * D * F + 4 * D
-    assert work.weight_bytes(d) == (L * layer_w + V * D) * 2
-    flops, nbytes = work.decode_step(d, [5, 9])
+    assert DEC.weight_bytes(d) == (L * layer_w + V * D) * 2
+    flops, nbytes = DEC.decode_step(d, [5, 9])
     assert flops == 2 * (L * per_layer + 2 * D * V) \
         + L * 2 * H * (5 + 9) * (dq + dv)
-    assert nbytes == work.weight_bytes(d) + 14 * L * H * (dq + dv) * 2
+    assert nbytes == DEC.weight_bytes(d) + 14 * L * H * (dq + dv) * 2
     # a row holding 3 tokens writes 2 more: contexts 4 and 5
-    assert work.chunk_step_flops(d, [(3, 2), (0, 0)]) == \
+    assert DEC.chunk_step_flops(d, [(3, 2), (0, 0)]) == \
         2 * L * per_layer + 2 * D * V + L * 2 * H * (4 + 5) * (dq + dv)
-    kf, kb = work.paged_decode_call(d, [5, 9])
+    kf, kb = DEC.kernel_work["paged_flash_decode"](d, [5, 9])
     assert kf == 2 * H * 14 * (dq + dv)
     assert kb == 14 * H * (dq + dv) * 2 + 2 * H * (dq + dv) * 2
     pk = {"flops": 1e12, "hbm_bw": 1e9}
@@ -230,12 +235,13 @@ def _hand_trace():
 
 
 def _hand_ctx():
-    d = bm.dims({"model": TINY_MODEL})
+    d = DEC.dims({"model": TINY_MODEL})
     calls = [{"kind": "chunk", "active": 2, "rows": [(0, 16), (10, 1)]},
              {"kind": "decode", "active": 2, "held": [17, 12]},
              {"kind": "decode", "active": 1, "held": [18]}]
-    return types.SimpleNamespace(trace=_hand_trace(), calls=calls, dims=d,
-                                 slots=4, chunk=16, notes=[], chips=1,
+    return types.SimpleNamespace(trace=_hand_trace(), calls=calls,
+                                 family=DEC, dims=d, slots=4, chunk=16,
+                                 notes=[], chips=1,
                                  device_kind="TPU v5 lite",
                                  admitted_prompt_tokens=40, hit_tokens=16)
 
@@ -253,19 +259,35 @@ def _pk():
     ("chunk_fill", lambda c: 100 * 17 / (4 * 16)),
     ("prefix_hit_share", lambda c: 100 * 16 / 40),
     ("decode_step_mfu", lambda c: 100 * (
-        work.least_time(*work.decode_step(c.dims, [17, 12]), _pk())[0]
-        + work.least_time(*work.decode_step(c.dims, [18]), _pk())[0]) / 0.2),
-    ("chunk_step_mfu", lambda c: 100 * work.chunk_step_flops(
+        work.least_time(*DEC.decode_step(c.dims, [17, 12]), _pk())[0]
+        + work.least_time(*DEC.decode_step(c.dims, [18]), _pk())[0]) / 0.2),
+    ("chunk_step_mfu", lambda c: 100 * DEC.chunk_step_flops(
         c.dims, [(0, 16), (10, 1)]) / (0.2 * _pk()["flops"])),
     ("paged_decode_roofline", lambda c: 100 * 2 * (
-        work.least_time(*work.paged_decode_call(c.dims, [17, 12]), _pk())[0]
-        + work.least_time(*work.paged_decode_call(c.dims, [18]), _pk())[0])
+        work.least_time(*DEC.paged_decode_call(c.dims, [17, 12]), _pk())[0]
+        + work.least_time(*DEC.paged_decode_call(c.dims, [18]), _pk())[0])
         / 0.08),
 ])
 def test_trace_reader_on_a_hand_made_trace(name, expect):
     ctx = _hand_ctx()
     got = harness.load_reader(ROOT, name).read(ctx)
     assert got == pytest.approx(expect(ctx), rel=1e-9)
+
+
+def test_the_readers_take_the_familys_counts():
+    """Every decode step of the hand-made trace is bound by its bytes,
+    so a family that counts twice the bytes reads twice the share."""
+    read = harness.load_reader(ROOT, "decode_step_mfu").read
+    ctx = _hand_ctx()
+    base = read(ctx)
+    assert "bound by bytes in 2 of 2 decode steps" in ctx.notes[0]
+
+    def doubled(d, held):
+        flops, nbytes = DEC.decode_step(d, held)
+        return flops, 2 * nbytes
+    ctx = _hand_ctx()
+    ctx.family = types.SimpleNamespace(decode_step=doubled)
+    assert read(ctx) == pytest.approx(2 * base, rel=1e-12)
 
 
 def test_breakdown_names_ops_and_idle_spans():
@@ -324,19 +346,62 @@ def test_reference_matches_the_program_in_float32():
     from repro.models import transformer as T
     conf = {"model": dict(TINY_MODEL, param_dtype="float32",
                           compute_dtype="float32")}
-    d = bm.dims(conf)
+    d = DEC.dims(conf)
     seed = 2 ** 32 + 9
-    params = bm.make_params(seed, d)
-    one = bm.layer_params(bm.layer_key(seed, 1), d)
+    params = DEC.make_params(seed, d)
+    one = DEC.layer_params(bm.layer_key(seed, 1), d, 1)
     for a, b in zip(jax.tree.leaves(params["blocks"][0]),
                     jax.tree.leaves(one)):
         assert np.array_equal(np.asarray(a[1]), np.asarray(b))
     toks = np.random.default_rng(0).integers(0, d["V"], (2, 24))
     with jax.default_matmul_precision("highest"):
-        prog, _ = T.forward(params, bm.arch_config(conf, "xla"),
+        prog, _ = T.forward(params, DEC.arch_config(conf, "xla"),
                             jnp.asarray(toks, jnp.int32))
-    ref = reference.logits(seed, d, toks.astype(np.int32))
+    ref = DEC.logits(seed, d, toks.astype(np.int32))
     assert float(jnp.max(jnp.abs(prog - ref))) < 1e-5
+
+
+# sha256 of every weight leaf's bytes (tree order) and of the float32
+# and float8 reference logits of two rows of 24 tokens, seed 41, as
+# ``bench/model.py`` and ``bench/reference.py`` gave them before the
+# decoder became a family
+PARENT_HASHES = {
+    "tiny": {
+        "weights": "a1b2bf7e28373bd14dd89aca72adab710af3e4bfad0f553933239244"
+                   "fc1a8fa2",
+        "f32": "a50dae4e9db0bb913535010e3ccee8b7b37226624d76ededb4609aed3fe6"
+               "6ca2",
+        "fp8": "6faf884957cb8e7ad61a4d0d927887928fed64fc9ac8ab1fe0afcbc54bc9"
+               "48ed"},
+    "tiny-phi3": {
+        "weights": "98be3d272da7b1d638aa115dad9e385980e20caae5812a9bc6a9fe4d"
+                   "92626d07",
+        "f32": "9a1cc26f5c32d3bee2c3cb4c5628bf1e1ef5508636a0eb0d33db0e8806636"
+               "c62",
+        "fp8": "54ffeea1afcd3869773ffd9abe8fe662506c3e997e29a3422110265c1b40"
+               "4cf7"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_HASHES))
+def test_the_decoder_family_draws_and_computes_as_before(name):
+    import jax
+    if name == "tiny":
+        model = TINY_MODEL
+    else:
+        sys.path.insert(0, str(Path(__file__).resolve().parent))
+        from tp4_probe import TINY_PHI3 as model
+    d = DEC.dims({"model": model})
+    h = hashlib.sha256()
+    for leaf in jax.tree.leaves(DEC.make_params(41, d)):
+        h.update(np.asarray(leaf).tobytes())
+    got = {"weights": h.hexdigest()}
+    toks = np.random.default_rng(0).integers(0, d["V"], (2, 24))
+    for precision in ("f32", "fp8"):
+        out = DEC.logits(41, d, toks.astype(np.int32), precision=precision)
+        got[precision] = hashlib.sha256(
+            np.asarray(out, np.float32).tobytes()).hexdigest()
+    assert got == PARENT_HASHES[name]
 
 
 # -- the command ------------------------------------------------------
@@ -383,6 +448,72 @@ def test_a_cell_added_from_files_alone_runs_and_is_correct(chat_run):
     assert lim["limit"] == TINY_LIMIT and lim["value"] <= TINY_LIMIT
 
 
+# a family added as a new file: the decoder, with a size of its own
+TOY_FAMILY = '''"""The decoder, with one size of its own in its dims."""
+from pathlib import Path
+
+from bench import load_module
+
+_dec = load_module(Path(__file__).resolve().parents[2], "families",
+                   "decoder")
+arch_config, build, layer_params, top_params, make_params, logits = (
+    _dec.arch_config, _dec.build, _dec.layer_params, _dec.top_params,
+    _dec.make_params, _dec.logits)
+decode_step, chunk_step_flops, weight_bytes, kv_bytes_per_token = (
+    _dec.decode_step, _dec.chunk_step_flops, _dec.weight_bytes,
+    _dec.kv_bytes_per_token)
+kernel_work = _dec.kernel_work
+
+
+def dims(conf):
+    return dict(_dec.dims(conf), toy=conf["toy"])
+'''
+
+
+def test_an_architecture_added_as_a_file(tmp_path, capsys):
+    """A configuration that names its own ``arch`` is served, checked
+    against its family's reference and read by the metric readers,
+    with new files alone; one naming an absent family is refused with
+    the path looked for, before anything is drawn."""
+    root = make_root(tmp_path)
+    (root / "bench/families/toy.py").write_text(TOY_FAMILY)
+    (root / "bench/metrics/toy_size.py").write_text(
+        "def read(ctx):\n    return ctx.dims['toy']\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for arch, cell in (("toy", "toy.tinybacklog"),
+                       ("absent", "absent.tinybacklog")):
+        conf = {"name": arch, "arch": arch, "toy": 7, "model": TINY_MODEL,
+                "engine": TINY_ENGINE, "chips": 1}
+        (root / f"bench/configs/{arch}.json").write_text(json.dumps(conf))
+        (root / f"bench/limits/{cell}.json").write_text(json.dumps(
+            {"max_logit_gap": {"limit": TINY_LIMIT}}))
+        bench["configs"].append({"name": arch, "source": "tests",
+                                 "file": f"bench/configs/{arch}.json",
+                                 "reduced": [], "why": arch})
+        bench["workloads"].append({"name": cell, "config": arch,
+                                   "traffic": "tinybacklog", "chips": 1,
+                                   "why": "t"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("toy.tinybacklog")
+    bench["per_layer"].append({"name": "toy_size", "unit": "1",
+                               "better": "higher", "source": "program_counter",
+                               "layer": "toy", "moves": "output_tok_s",
+                               "workloads": ["toy.tinybacklog"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    run = _run(root, "toy.tinybacklog", trace=True)
+    assert run["correct"], run["checks"]
+    assert run["checks"]["tokens_compared"]["value"] > 0
+    assert run["metrics"]["toy_size"]["value"] == 7
+    assert 0 < run["metrics"]["batch_occupancy"]["value"] <= 100
+
+    with pytest.raises(SystemExit) as out:
+        harness.open_cell(root, "absent.tinybacklog", "test")
+    assert out.value.code == 2
+    assert "bench/families/absent.py" in capsys.readouterr().err
+
+
 def test_a_traced_run_reports_counters_and_no_device_numbers_on_cpu(
         backlog_traced):
     assert backlog_traced["correct"]
@@ -400,13 +531,13 @@ def test_the_float8_control_fails_the_limit():
     below the configuration's bfloat16) in the program's place, read
     teacher-forced at every position of a fixed sample, goes through
     the same check and comes out not correct."""
-    d = bm.dims({"model": TINY_MODEL})
+    d = DEC.dims({"model": TINY_MODEL})
     rng = np.random.default_rng(3)
     rows = [(rng.integers(0, 128, 8).astype(np.int32),
              rng.integers(0, 128, 88).tolist(), 0) for _ in range(4)]
     limits = {"max_logit_gap": {"limit": TINY_LIMIT}}
     for seed in (41, 42, 43):
-        out = correct.check(seed, d, rows, 4, 96, limits,
+        out = correct.check(seed, DEC, d, rows, 4, 96, limits,
                             gaps=reference.control_gaps)
         assert out["compared"] == 4 * 88
         assert not out["correct"], out["limits"]

@@ -22,9 +22,10 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(ROOT))
 
-from bench import correct, generator, harness, model as bm  # noqa: E402
-from bench import reference, trace as tr, work  # noqa: E402
+from bench import correct, generator, harness, reference  # noqa: E402
+from bench import trace as tr  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
+from test_bench_harness import DEC  # noqa: E402
 from tp4_probe import FAULTS, TINY_PHI3, TP, TP4_LIMIT, f32  # noqa: E402
 
 
@@ -35,15 +36,15 @@ def test_reference_matches_the_program_in_float32_on_a_phi3_shaped_model():
     import jax.numpy as jnp
     from repro.models import transformer as T
     conf = {"model": f32(TINY_PHI3)}
-    d = bm.dims(conf)
+    d = DEC.dims(conf)
     assert d["H"] // d["KV"] == 4 and d["rope"] and not d["tied"]
     seed = 2 ** 32 + 21
-    params = bm.make_params(seed, d)
+    params = DEC.make_params(seed, d)
     toks = np.random.default_rng(1).integers(0, d["V"], (2, 40))
     with jax.default_matmul_precision("highest"):
-        prog, _ = T.forward(params, bm.arch_config(conf, "xla"),
+        prog, _ = T.forward(params, DEC.arch_config(conf, "xla"),
                             jnp.asarray(toks, jnp.int32))
-    ref = reference.logits(seed, d, toks.astype(np.int32))
+    ref = DEC.logits(seed, d, toks.astype(np.int32))
     assert float(jnp.max(jnp.abs(ref))) > 1.0
     assert float(jnp.max(jnp.abs(prog - ref))) < 1e-5
 
@@ -52,13 +53,13 @@ def test_the_float8_control_fails_the_tp4_limit():
     """The float8 control on the Phi-3-shaped model, read teacher-forced
     at every position of a fixed sample, fails the tiny tp=4 cell's
     limit."""
-    d = bm.dims({"model": TINY_PHI3})
+    d = DEC.dims({"model": TINY_PHI3})
     rng = np.random.default_rng(4)
     rows = [(rng.integers(0, 128, 8).astype(np.int32),
              rng.integers(0, 128, 88).tolist(), 0) for _ in range(4)]
     limits = {"max_logit_gap": {"limit": TP4_LIMIT}}
     for seed in (51, 52, 53):
-        out = correct.check(seed, d, rows, 4, 96, limits,
+        out = correct.check(seed, DEC, d, rows, 4, 96, limits,
                             gaps=reference.control_gaps)
         assert not out["correct"], out["limits"]
 
@@ -108,10 +109,11 @@ def _two_device_trace(collectives=True):
 
 
 def _tp_ctx(trace, chips):
-    d = bm.dims({"model": TINY_PHI3})
+    d = DEC.dims({"model": TINY_PHI3})
     calls = [{"kind": "chunk", "active": 2, "rows": [(0, 16), (10, 1)]}]
-    return types.SimpleNamespace(trace=trace, calls=calls, dims=d, chips=chips,
-                                 slots=4, chunk=16, notes=[],
+    return types.SimpleNamespace(trace=trace, calls=calls, family=DEC,
+                                 dims=d, chips=chips, slots=4, chunk=16,
+                                 notes=[],
                                  device_kind="TPU v5 lite")
 
 
@@ -134,7 +136,7 @@ def test_chunk_step_mfu_holds_each_chip_to_its_share(chips):
     counted from the published heads; the mean over devices."""
     ctx = _tp_ctx(_two_device_trace(), chips)
     got = harness.load_reader(ROOT, "chunk_step_mfu").read(ctx)
-    flops = work.chunk_step_flops(ctx.dims, [(0, 16), (10, 1)]) / chips
+    flops = DEC.chunk_step_flops(ctx.dims, [(0, 16), (10, 1)]) / chips
     pk = peaks("TPU v5 lite")["flops"]
     assert got == pytest.approx(
         100 * (flops / (0.4 * pk) + flops / (0.5 * pk)) / 2, rel=1e-9)

@@ -23,7 +23,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src"), str(HERE)]
 
 import numpy as np  # noqa: E402
 
-from test_bench_harness import (SECONDS, TINY_ENGINE,  # noqa: E402
+from test_bench_harness import (DEC, SECONDS, TINY_ENGINE,  # noqa: E402
                                 make_root)
 
 TINY_PHI3 = {
@@ -52,10 +52,9 @@ def f32(model: dict) -> dict:
 def check_draw(seed: int) -> dict:
     """Every leaf of the sharded draw equals the one-device draw."""
     import jax
-    from bench import model as bm
-    d = bm.dims({"model": TINY_PHI3})
-    one = bm.make_params(seed, d)
-    four = bm.make_params(seed, d, TP)
+    d = DEC.dims({"model": TINY_PHI3})
+    one = DEC.make_params(seed, d)
+    four = DEC.make_params(seed, d, TP)
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(one)[0]]
     a, b = jax.tree.leaves(one), jax.tree.leaves(four)
@@ -74,20 +73,20 @@ def check_forward(seed: int) -> dict:
     its shards) against the reference on the published 3 heads."""
     import jax
     import jax.numpy as jnp
-    from bench import model as bm, reference
+    from bench import model as bm
     from repro.models import transformer as T
     from repro.parallel.sharding import use_mesh
     from repro.serve.executor import ShardedExecutor
     conf = {"model": f32(TINY_PHI3), "engine": dict(TINY_ENGINE, tp=TP)}
-    d = bm.dims(conf)
-    exe = ShardedExecutor(bm.make_params(seed, d, TP),
-                          bm.arch_config(conf, "xla"),
+    d = DEC.dims(conf)
+    exe = ShardedExecutor(DEC.make_params(seed, d, TP),
+                          DEC.arch_config(conf, "xla"),
                           bm.engine_config(conf, "xla"))
     toks = np.random.default_rng(seed).integers(0, d["V"], (2, 24))
     with use_mesh(exe.mesh), jax.default_matmul_precision("highest"):
         prog, _ = T.forward(exe.params, dataclasses.replace(
             exe.cfg, kernel_impl="xla"), jnp.asarray(toks, jnp.int32))
-    ref = reference.logits(seed, d, toks.astype(np.int32))
+    ref = DEC.logits(seed, d, toks.astype(np.int32))
     return {"kv_pad": exe.kv_pad,
             "max_abs_diff": float(jnp.max(jnp.abs(prog - ref))),
             "ref_max_abs": float(jnp.max(jnp.abs(ref)))}
