@@ -118,15 +118,18 @@ def run(verbose: bool = True):
     pt = 8
     n_p = Si // pt
     perm = np.random.default_rng(0).permutation(n_p)
-    def pages(x):                       # (1, KV, T, r) -> (n_p, KV, pt, r)
-        return x[0].reshape(KVi, n_p, pt, -1).transpose(1, 0, 2, 3)
 
-    kp = jnp.concatenate([pages(kh)[perm],
-                          jnp.full((1, KVi, pt, dqi), 1e4)])   # + sink row
-    vp = jnp.concatenate([pages(vh)[perm],
-                          jnp.full((1, KVi, pt, dvi), -1e4)])
+    def pages(x, r):                    # (1, T, KV, r) -> (n_p, pt, lanes)
+        rows = ref.heads_to_lanes(x[0], ref.page_lanes(KVi, r))
+        return rows.reshape(n_p, pt, -1)
+
+    kp = pages(ki, dqi)[perm]
+    vp = pages(vi, dvi)[perm]
+    kp = jnp.concatenate([kp, jnp.full((1,) + kp.shape[1:], 1e4)])  # sink
+    vp = jnp.concatenate([vp, jnp.full((1,) + vp.shape[1:], -1e4)])
     tab = jnp.asarray(np.argsort(perm)[None, :], jnp.int32)
     pag = ops.paged_decode_attention(qi[:, 0], kp, vp, tab, lens,
+                                     kv_heads=KVi, head_widths=(dqi, dvi),
                                      impl="interpret")
     paged_ok = bool(np.allclose(
         np.asarray(pag),
@@ -134,7 +137,8 @@ def run(verbose: bool = True):
         atol=2e-4))
     # page_copy (COW prefix caching): row-to-row clone incl. sentinel
     # self-copy padding must match the oracle bit-for-bit
-    pool = jax.random.normal(ks[3], (2, n_p + 1, KVi, pt, dqi))
+    pool = jax.random.normal(ks[3], (2, n_p + 1, pt,
+                                     ref.page_lanes(KVi, dqi)))
     csrc = jnp.array([0, 2, n_p], jnp.int32)
     cdst = jnp.array([3, 1, n_p], jnp.int32)
     copy_ok = bool(np.array_equal(

@@ -186,7 +186,9 @@ from repro.configs import get_config
 from repro.core import (AdapterRegistry, RankBudget, apply_rank_budget,
                         budget_kept_energy, clover_decompose, clover_prune,
                         plan_rank_budget)
+from repro.kernels.ref import page_lanes
 from repro.models import init_lm_params
+from repro.parallel.sharding import kv_shards
 from repro.serve import (DONE, Engine, EngineConfig, FaultPlan, Request,
                          greedy_reference, rank_pool_bytes)
 
@@ -320,15 +322,17 @@ def _paged_kernel_wall_ms(dispatch, cfg) -> float:
     B, H, KV, d = N_REQUESTS, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     n_pp = MAX_LEN // PAGE_TOKENS
     n_pages = B * n_pp + 1
+    shards = kv_shards(dispatch.mesh, KV)
+    lanes = page_lanes(KV, d, shards)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((n_pages, PAGE_TOKENS, KV, d)),
+    kp = jnp.asarray(rng.standard_normal((n_pages, PAGE_TOKENS, lanes)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, PAGE_TOKENS, KV, d)),
+    vp = jnp.asarray(rng.standard_normal((n_pages, PAGE_TOKENS, lanes)),
                      jnp.float32)
     table = jnp.asarray(rng.integers(0, n_pages - 1, (B, n_pp)), jnp.int32)
     lens = jnp.asarray(rng.integers(1, MAX_LEN, B), jnp.int32)
     f = jax.jit(lambda *x: dispatch.paged_decode_attention(
-        *x, scale=d ** -0.5))
+        *x, kv_heads=KV, head_widths=(d, d), scale=d ** -0.5))
     f(q, kp, vp, table, lens).block_until_ready()      # compile
     best = float("inf")
     for _ in range(3):

@@ -11,12 +11,15 @@ resolved once per (impl alias, platform, mesh):
   lowering — it never silently becomes the interpreter),
   ``"interpret"`` (Pallas kernels in interpret mode — the CPU
   validation path, an explicit choice).
-* Layout: KV caches and page pools are HEAD-MAJOR — dense ``(B, KV,
-  T, r)``, paged ``(N, KV, page_tokens, r)`` — and the decode kernels
-  take queries as ``(B, KV, G, d)``; these entry points accept the
-  model's ``(B, H, d)`` queries and reshape them (free: H is KV groups
-  of G consecutive heads).  Full-sequence attention keeps token-major
-  activations at this API and transposes them for the kernel.
+* Layout: dense KV caches are HEAD-MAJOR ``(B, KV, T, r)`` and the
+  dense decode kernels take queries as ``(B, KV, G, d)``; these entry
+  points accept the model's ``(B, H, d)`` queries and reshape them
+  (free: H is KV groups of G consecutive heads).  Page pools are
+  TOKEN-MAJOR and LANE-DENSE ``(N, page_tokens, lanes)``, every KV head
+  of a token side by side in one row (``ref.page_lanes``), so the paged
+  entry points take the pool's ``kv_heads`` and ``head_widths``.
+  Full-sequence attention keeps token-major activations at this API and
+  transposes them for the kernel.
 * ``mesh``: when set, the serving hot-path kernels (flash-decode,
   paged-decode, page-copy, full-sequence attention) run PER SHARD
   under ``shard_map`` with serve-rules operand specs (slot batch over
@@ -26,7 +29,7 @@ resolved once per (impl alias, platform, mesh):
   ids stay HOST-GLOBAL: the pools' page-row axis is replicated
   (``serve_state_specs``), so scalar-prefetched page tables need no
   shard-local translation — each shard dereferences the same rows and
-  reads its own head slice.
+  reads its own lane block (its own heads, ``ref.page_lanes``).
 
 The module-level functions (``clover_attention`` et al.) are the thin
 string-alias compatibility layer over ``resolve`` — existing call
@@ -50,8 +53,7 @@ from repro.kernels.clover_attention import flash_attention as _flash
 from repro.kernels.decode_attention import (
     flash_decode as _decode, flash_decode_ranked as _decode_ranked)
 from repro.kernels.paged_decode_attention import (
-    paged_flash_decode as _paged_decode,
-    paged_flash_decode_ranked as _paged_decode_ranked)
+    paged_flash_decode as _paged_decode)
 from repro.kernels.wkv6 import wkv6 as _wkv6
 
 IMPLS = ("ref", "xla", "pallas", "interpret")
@@ -110,17 +112,10 @@ def _decode_body(q, k, v, lengths, *, scale, block_t, interpret):
 
 
 def _rank_block(rank_block: int, dq: int, dv: int) -> int:
-    """The rank block both ranked kernels walk: ``rank_block`` (a lane
+    """The rank block the dense ranked kernel walks: ``rank_block`` (a lane
     multiple) for wide heads, else the whole width — the only block
     widths the TPU lowering accepts."""
     return min(rank_block, max(8, max(dq, dv)))
-
-
-def _paged_decode_body(q, k_pool, v_pool, page_table, lengths, *, scale,
-                       interpret):
-    return _ungroup(_paged_decode(_group(q, k_pool.shape[1]), k_pool,
-                                  v_pool, page_table, lengths, scale=scale,
-                                  interpret=interpret))
 
 
 def _decode_ranked_body(q, k, v, lengths, qk_ranks, vo_ranks, *,
@@ -137,21 +132,6 @@ def _decode_ranked_body(q, k, v, lengths, qk_ranks, vo_ranks, *,
         _pad_to(_pad_to(k, 2, bt), -1, rb),
         _pad_to(_pad_to(v, 2, bt), -1, rb), lengths, qk_ranks, vo_ranks,
         scale=scale, block_t=bt, rank_block=rb, interpret=interpret)
-    return _ungroup(out)[..., :dv]
-
-
-def _paged_decode_ranked_body(q, k_pool, v_pool, page_table, lengths,
-                              qk_ranks, vo_ranks, *, scale, rank_block,
-                              interpret):
-    dq, dv = q.shape[-1], v_pool.shape[-1]
-    rb = _rank_block(rank_block, dq, dv)
-    if scale is None:
-        scale = float(1.0 / (dq ** 0.5))
-    out = _paged_decode_ranked(
-        _group(_pad_to(q, -1, rb), k_pool.shape[1]),
-        _pad_to(k_pool, -1, rb), _pad_to(v_pool, -1, rb), page_table,
-        lengths, qk_ranks, vo_ranks, scale=scale, rank_block=rb,
-        interpret=interpret)
     return _ungroup(out)[..., :dv]
 
 
@@ -275,89 +255,85 @@ class KernelDispatch:
         return fn(q, k, v, lengths)
 
     def paged_decode_attention(self, q, k_pool, v_pool, page_table,
-                               lengths, *,
+                               lengths, *, kv_heads: int,
+                               head_widths: Tuple[int, int],
                                scale: Optional[float] = None,
                                qk_ranks: Optional[jnp.ndarray] = None,
                                vo_ranks: Optional[jnp.ndarray] = None,
-                               rank_block: int = 128) -> jnp.ndarray:
+                               ) -> jnp.ndarray:
         """Flash-decoding vs a PAGED (possibly CLOVER-rank) KV cache.
 
-        q (B,H,dq), k_pool (N,KV,page_tokens,dq), v_pool (N,KV,
-        page_tokens,dv), page_table (B,n_p) int32, lengths (B,) ->
-        (B,H,dv).
+        q (B,H,dq), k_pool (N,page_tokens,Lk), v_pool (N,page_tokens,
+        Lv) in page-row order: ``kv_heads`` heads of ``head_widths =
+        (wk, wv)`` lanes, one lane block per shard of this dispatch's
+        mesh (``ref.page_lanes``); dq <= wk; page_table (B,n_p) int32,
+        lengths (B,) -> (B,H,wv).
 
         No padding is needed: the pool's ``page_tokens`` axis IS the
         block size, and page-table entries past each slot's in-use
         pages are never dereferenced (the kernel walks only each row's
-        in-use page blocks).  Under a mesh the pools split along KV heads
-        only; their page-row axis is REPLICATED, so the host-global
-        page ids in ``page_table`` are valid row indices on every
-        shard — the scalar-prefetched table crosses the shard_map
-        boundary untranslated.  With ``qk_ranks``/``vo_ranks`` ((KV,)
-        int32) the per-head rank-clamped kernel runs instead
-        (non-uniform ``RankBudget`` plans, DESIGN.md §14).
+        in-use page blocks).  Under a mesh the pools split along their
+        lane axis, one block of heads a shard; their page-row axis is
+        REPLICATED, so the host-global page ids in ``page_table`` are
+        valid row indices on every shard — the scalar-prefetched table
+        crosses the shard_map boundary untranslated.  With
+        ``qk_ranks``/``vo_ranks`` ((KV,) int32) the kernel clamps each
+        head to its kept ranks (non-uniform ``RankBudget`` plans,
+        DESIGN.md §14).
         """
-        ranked = qk_ranks is not None or vo_ranks is not None
+        b, m = self._axes(batch=q.shape[0], kv_heads=kv_heads)
+        shards = 1 if m is None else self.mesh.shape[m]
         if not self.kernel_path:
-            return _ref.paged_decode_attention_ref(q, k_pool, v_pool,
-                                                   page_table, lengths,
-                                                   scale=scale,
-                                                   qk_ranks=qk_ranks,
-                                                   vo_ranks=vo_ranks)
-        b, m = self._axes(batch=q.shape[0], kv_heads=k_pool.shape[1])
-        if ranked:
-            dq, dv = q.shape[-1], v_pool.shape[-1]
-            KV = k_pool.shape[1]
-            qk_ranks = (jnp.full((KV,), dq, jnp.int32)
-                        if qk_ranks is None else qk_ranks.astype(jnp.int32))
-            vo_ranks = (jnp.full((KV,), dv, jnp.int32)
-                        if vo_ranks is None else vo_ranks.astype(jnp.int32))
-            body = functools.partial(_paged_decode_ranked_body, scale=scale,
-                                     rank_block=rank_block,
-                                     interpret=self.interpret)
-            if b is None and m is None:
-                return body(q, k_pool, v_pool, page_table, lengths,
-                            qk_ranks, vo_ranks)
-            fn = self._shard(body,
-                             in_specs=(P(b, m, None), P(None, m, None, None),
-                                       P(None, m, None, None), P(b, None),
-                                       P(b), P(m), P(m)),
-                             out_specs=P(b, m, None))
-            return fn(q, k_pool, v_pool, page_table, lengths, qk_ranks,
-                      vo_ranks)
-        body = functools.partial(_paged_decode_body, scale=scale,
+            return _ref.paged_decode_attention_ref(
+                q, k_pool, v_pool, page_table, lengths, kv_heads=kv_heads,
+                head_widths=head_widths, shards=shards, scale=scale,
+                qk_ranks=qk_ranks, vo_ranks=vo_ranks)
+        ranks, rank_specs = (), ()
+        if qk_ranks is not None or vo_ranks is not None:
+            ranks = tuple(jnp.full((kv_heads,), w, jnp.int32) if r is None
+                          else r.astype(jnp.int32)
+                          for r, w in zip((qk_ranks, vo_ranks), head_widths))
+            rank_specs = (P(m), P(m))
+
+        def body(q, k_pool, v_pool, page_table, lengths, *ranks):
+            rq, rv = ranks if ranks else (None, None)
+            return _paged_decode(q, k_pool, v_pool, page_table, lengths,
+                                 kv_heads=kv_heads // shards,
+                                 head_widths=head_widths, scale=scale,
+                                 qk_ranks=rq, vo_ranks=rv,
                                  interpret=self.interpret)
+        args = (q, k_pool, v_pool, page_table, lengths) + ranks
         if b is None and m is None:
-            return body(q, k_pool, v_pool, page_table, lengths)
+            return body(*args)
         fn = self._shard(body,
-                         in_specs=(P(b, m, None), P(None, m, None, None),
-                                   P(None, m, None, None), P(b, None),
-                                   P(b)),
+                         in_specs=(P(b, m, None), P(None, None, m),
+                                   P(None, None, m), P(b, None), P(b))
+                         + rank_specs,
                          out_specs=P(b, m, None))
-        return fn(q, k_pool, v_pool, page_table, lengths)
+        return fn(*args)
 
     def page_copy(self, pool, src, dst) -> jnp.ndarray:
         """Batched KV-page clone — the device half of copy-on-write
         prefix caching (serve.engine, DESIGN.md §9).
 
-        pool (n_blocks, N, KV, page_tokens, r), src/dst (m,) int32
+        pool (n_blocks, N, page_tokens, lanes), src/dst (m,) int32
         pool-row ids -> pool with row ``dst[i]`` a copy of row
         ``src[i]``, all other rows untouched.  Pure DMA, no compute.
         On the non-kernel paths this is the jnp oracle ("xla" included
         — there is no einsum equivalent to fall back to).  Under a
-        mesh each shard clones its own KV-head slice of the same
-        host-global rows.
+        mesh each shard clones its own lane block of the same
+        host-global rows (a copy moves every lane alike).
         """
         if not self.kernel_path:
             return _ref.page_copy_ref(pool, src, dst)
         from repro.kernels.page_copy import page_copy as _page_copy
         body = functools.partial(_page_copy, interpret=self.interpret)
-        _, m = self._axes(batch=1, kv_heads=pool.shape[2])
+        _, m = self._axes(batch=1, kv_heads=pool.shape[-1])
         if m is None:
             return body(pool, src, dst)
         fn = self._shard(body,
-                         in_specs=(P(None, None, m, None, None), P(), P()),
-                         out_specs=P(None, None, m, None, None))
+                         in_specs=(P(None, None, None, m), P(), P()),
+                         out_specs=P(None, None, None, m))
         return fn(pool, src, dst)
 
     def page_restore(self, pool, rows, dst) -> jnp.ndarray:
@@ -365,26 +341,26 @@ class KernelDispatch:
         content into pool rows (hierarchical KV, serve.memory
         ``HostTier``; DESIGN.md §12).
 
-        pool (n_blocks, N, KV, page_tokens, r), rows (n_blocks, W, KV,
-        page_tokens, r), dst (W,) int32 pool-row ids -> pool with
+        pool (n_blocks, N, page_tokens, lanes), rows (n_blocks, W,
+        page_tokens, lanes), dst (W,) int32 pool-row ids -> pool with
         row ``dst[i]`` holding ``rows[:, i]``, all other rows
         untouched.  Pure DMA, no compute.  On the non-kernel paths
         this is the jnp oracle ("xla" included — there is no einsum
         equivalent).  Under a mesh the restore rows arrive replicated
-        and each shard scatters its own KV-head slice into the same
+        and each shard scatters its own lane block into the same
         host-global rows.
         """
         if not self.kernel_path:
             return _ref.page_restore_ref(pool, rows, dst)
         from repro.kernels.page_copy import page_restore as _page_restore
         body = functools.partial(_page_restore, interpret=self.interpret)
-        _, m = self._axes(batch=1, kv_heads=pool.shape[2])
+        _, m = self._axes(batch=1, kv_heads=pool.shape[-1])
         if m is None:
             return body(pool, rows, dst)
         fn = self._shard(body,
-                         in_specs=(P(None, None, m, None, None),
-                                   P(None, None, m, None, None), P()),
-                         out_specs=P(None, None, m, None, None))
+                         in_specs=(P(None, None, None, m),
+                                   P(None, None, None, m), P()),
+                         out_specs=P(None, None, None, m))
         return fn(pool, rows, dst)
 
     # -- recurrent kernels (never shard_map'd: cross-step state) -------
@@ -504,32 +480,36 @@ def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
                                           rank_block=rank_block)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "rank_block", "impl"))
+@functools.partial(jax.jit, static_argnames=("kv_heads", "head_widths",
+                                             "scale", "impl"))
 def paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
+                           kv_heads: int, head_widths: Tuple[int, int],
                            scale: Optional[float] = None,
                            qk_ranks=None, vo_ranks=None,
-                           rank_block: int = 128,
                            impl: str = "ref") -> jnp.ndarray:
     """Flash-decoding vs a PAGED (possibly CLOVER-rank) KV cache.
 
-    q (B,H,dq), k_pool (N,KV,page_tokens,dq), v_pool (N,KV,page_tokens,dv),
-    page_table (B,n_p) int32, lengths (B,) -> (B,H,dv).
-    qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
-    (non-uniform ``RankBudget`` plans, DESIGN.md §14).
+    q (B,H,dq), k_pool (N,page_tokens,Lk), v_pool (N,page_tokens,Lv) in
+    page-row order (``ref.page_lanes``: ``kv_heads`` heads of
+    ``head_widths = (wk, wv)`` lanes), page_table (B,n_p) int32,
+    lengths (B,) -> (B,H,wv).  qk_ranks / vo_ranks: optional (KV,)
+    int32 per-head kept ranks (non-uniform ``RankBudget`` plans,
+    DESIGN.md §14).
     """
     return resolve(impl).paged_decode_attention(q, k_pool, v_pool,
                                                 page_table, lengths,
+                                                kv_heads=kv_heads,
+                                                head_widths=head_widths,
                                                 scale=scale,
                                                 qk_ranks=qk_ranks,
-                                                vo_ranks=vo_ranks,
-                                                rank_block=rank_block)
+                                                vo_ranks=vo_ranks)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def page_copy(pool, src, dst, *, impl: str = "ref") -> jnp.ndarray:
     """Batched KV-page clone (copy-on-write prefix caching).
 
-    pool (n_blocks, N, KV, page_tokens, r), src/dst (m,) int32 pool-row
+    pool (n_blocks, N, page_tokens, lanes), src/dst (m,) int32 pool-row
     ids -> pool with row ``dst[i]`` a copy of row ``src[i]``.
     """
     return resolve(impl).page_copy(pool, src, dst)
@@ -539,8 +519,8 @@ def page_copy(pool, src, dst, *, impl: str = "ref") -> jnp.ndarray:
 def page_restore(pool, rows, dst, *, impl: str = "ref") -> jnp.ndarray:
     """Batched host-tier page restore (hierarchical KV spill/restore).
 
-    pool (n_blocks, N, KV, page_tokens, r), rows (n_blocks, W, KV,
-    page_tokens, r), dst (W,) int32 pool-row ids -> pool with row
+    pool (n_blocks, N, page_tokens, lanes), rows (n_blocks, W,
+    page_tokens, lanes), dst (W,) int32 pool-row ids -> pool with row
     ``dst[i]`` holding ``rows[:, i]``.
     """
     return resolve(impl).page_restore(pool, rows, dst)

@@ -3,7 +3,7 @@ caching AND of the host-RAM spill tier's restore path (DESIGN.md §9,
 §12; serve.memory / serve.engine).
 
 Two entry points share one shape contract — a grid of row-to-row
-(KV, page_tokens, r) slab moves over the layer-stacked pool, driven by
+(page_tokens, lanes) slab moves over the layer-stacked pool, driven by
 scalar-prefetched page-id vectors, exactly the indirection idiom of
 ``paged_decode_attention.py`` (the BlockSpec index maps dereference the
 id vectors BEFORE the body runs, so each slab streams straight to its
@@ -27,10 +27,13 @@ in one batch; see ``Engine._copy_pages``).  Padding a short batch is
 legal in both directions: sentinel->sentinel self-copies are no-ops,
 and restore padding scatters all-zero slabs into the garbage row.
 
-Pool rows are head-major (KV, page_tokens, r) slabs; on real TPUs keep
-``page_tokens`` a multiple of the dtype sublane tile (8 for f32, 16
-for bf16) — the same layout rule the paged decode kernel already
-imposes.  Tests run interpret mode where any size is legal.
+Pool rows are token-major, lane-dense (page_tokens, lanes) slabs
+(every KV head of a token side by side, ``ref.page_lanes``; DESIGN.md
+§6), so a page is one contiguous slab whose block spans both of its
+dims whole; on real TPUs keep ``page_tokens`` a multiple of the dtype
+sublane tile (8 for f32, 16 for bf16) — the same layout rule the paged
+decode kernel already imposes.  Tests run interpret mode where any size
+is legal.
 """
 from __future__ import annotations
 
@@ -47,28 +50,28 @@ def _page_copy_kernel(src_ref, dst_ref, in_ref, out_ref):
 
 def page_copy(pool: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray, *,
               interpret: bool = False) -> jnp.ndarray:
-    """pool: (n_blocks, N, KV, page_tokens, r) — one layer-stacked KV
+    """pool: (n_blocks, N, page_tokens, lanes) — one layer-stacked KV
     pool leaf;  src, dst: (m,) int32 pool-row ids (pairs disjoint
     except sentinel self-copy padding).  Returns the pool with row
     ``dst[i]`` holding a copy of row ``src[i]`` for every i, all other
     rows untouched.  -> same shape/dtype as ``pool``.
     """
-    n_blocks, N, KV, pt, r = pool.shape
+    n_blocks, N, pt, lanes = pool.shape
     m = src.shape[0]
 
     def _src_block(i, b, src, dst):
-        return (b, src[i], 0, 0, 0)
+        return (b, src[i], 0, 0)
 
     def _dst_block(i, b, src, dst):
-        return (b, dst[i], 0, 0, 0)
+        return (b, dst[i], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # pairs are the OUTER (sequential) axis so pair i+1 reads pair
         # i's writes if the host ever chains them; blocks inner
         grid=(m, n_blocks),
-        in_specs=[pl.BlockSpec((1, 1, KV, pt, r), _src_block)],
-        out_specs=pl.BlockSpec((1, 1, KV, pt, r), _dst_block),
+        in_specs=[pl.BlockSpec((1, 1, pt, lanes), _src_block)],
+        out_specs=pl.BlockSpec((1, 1, pt, lanes), _dst_block),
     )
     return pl.pallas_call(
         _page_copy_kernel,
@@ -92,29 +95,29 @@ def _page_restore_kernel(dst_ref, rows_ref, pool_ref, out_ref):
 
 def page_restore(pool: jnp.ndarray, rows: jnp.ndarray, dst: jnp.ndarray,
                  *, interpret: bool = False) -> jnp.ndarray:
-    """pool: (n_blocks, N, KV, page_tokens, r) — one layer-stacked KV
-    pool leaf;  rows: (n_blocks, W, KV, page_tokens, r) — externally
+    """pool: (n_blocks, N, page_tokens, lanes) — one layer-stacked KV
+    pool leaf;  rows: (n_blocks, W, page_tokens, lanes) — externally
     sourced slab content (host-tier restore);  dst: (W,) int32 pool-row
     ids (freshly-allocated pages; padding entries repeat the sentinel
     row with zero slabs).  Returns the pool with row ``dst[i]`` holding
     ``rows[:, i]`` for every i, all other rows untouched.  -> same
     shape/dtype as ``pool``.
     """
-    n_blocks, N, KV, pt, r = pool.shape
+    n_blocks, N, pt, lanes = pool.shape
     W = rows.shape[1]
 
     def _rows_block(i, b, dst):
-        return (b, i, 0, 0, 0)
+        return (b, i, 0, 0)
 
     def _dst_block(i, b, dst):
-        return (b, dst[i], 0, 0, 0)
+        return (b, dst[i], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(W, n_blocks),
-        in_specs=[pl.BlockSpec((1, 1, KV, pt, r), _rows_block),
-                  pl.BlockSpec((1, 1, KV, pt, r), _dst_block)],
-        out_specs=pl.BlockSpec((1, 1, KV, pt, r), _dst_block),
+        in_specs=[pl.BlockSpec((1, 1, pt, lanes), _rows_block),
+                  pl.BlockSpec((1, 1, pt, lanes), _dst_block)],
+        out_specs=pl.BlockSpec((1, 1, pt, lanes), _dst_block),
     )
     return pl.pallas_call(
         _page_restore_kernel,

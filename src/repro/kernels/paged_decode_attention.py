@@ -4,15 +4,18 @@ CLOVER-rank KV cache (vLLM-style page pool + per-slot page tables).
 The dense `flash_decode` streams a per-slot cache of shape
 ``(B, KV, capacity, r)`` — every slot reserves (and, before the
 index-map clamp, streamed) full capacity regardless of actual length.
-Here the cache is one global pool ``(n_pages + 1, KV, page_tokens, r)``
+Here the cache is one global pool ``(n_pages + 1, page_tokens, L)``
 shared by all slots; each slot owns an ordered list of page ids (its
 page table row) and positions map through the indirection
-``pool[table[b, p // page_tokens], :, p % page_tokens]``.  The pool is
-HEAD-MAJOR within a page so each streamed block ``(page_tokens, r)``
-spans its array's last two dims whole, as the TPU lowering requires
-(DESIGN.md §4, §6).  Rank pruning
-composes with paging: smaller r -> more tokens per HBM byte -> more
-resident sequences per pool (DESIGN.md §6).
+``pool[table[b, p // page_tokens], p % page_tokens]``.  The pool is
+TOKEN-MAJOR and LANE-DENSE: a page row holds every KV head of one token
+side by side, ``r`` lanes a head, the row rounded up to a multiple of
+128 lanes (``ref.page_lanes``; DESIGN.md §4, §6).  A rank-32 head thus
+costs 32 lanes, not a 128-lane tile of its own, and each streamed block
+``(page_tokens, L)`` spans its array's last two dims whole, as the TPU
+lowering requires.  Rank pruning composes with paging: smaller r ->
+more tokens per HBM byte -> more resident sequences per pool
+(DESIGN.md §6).
 
 Kernel schedule — one grid step per (row, block of ``ppb`` pages), all
 KV heads at once:
@@ -22,15 +25,13 @@ KV heads at once:
     The grid holds only the blocks each row's length puts in use —
     its length is the batch's total block count, known on the device —
     so pages past a row's length cost neither a grid step nor a DMA.
-  * A page for all heads is one contiguous ``(KV, pt, r)`` slab.  A step
-    streams ``ppb`` of them for K and for V, each through its own
-    BlockSpec whose index map dereferences the page table BEFORE the
-    body runs (the gather through the indirection is done by the
-    pipeline, which prefetches the next step's pages meanwhile; Mosaic
-    refuses a manual DMA of one page out of a pool whose rank is under
-    the 128-lane tile).  ``ppb`` comes from the shapes alone: the
-    fewest pages holding 128 tokens, capped by the table width and by
-    VMEM.
+  * A page is one contiguous ``(pt, L)`` slab.  A step streams ``ppb``
+    of them for K and for V, each through its own BlockSpec whose index
+    map dereferences the page table BEFORE the body runs (the pipeline
+    gathers through the indirection and prefetches the next step's
+    pages meanwhile).  ``ppb`` comes from the shapes alone: the fewest
+    pages holding ``_BLOCK_TOKENS`` tokens, capped by the table width
+    and by VMEM.
   * Page slot ``j`` of the row's last block may lie past its in-use
     pages: the slot then keeps naming the page it held in the block
     before (Pallas skips the DMA for a revisited block index), or, in
@@ -38,11 +39,18 @@ KV heads at once:
     ``tj < length`` mask drops those positions.  Table entries past a
     row's in-use pages may be a sentinel id (the pool's spare garbage
     row); they are never dereferenced.
-  * The body contracts each KV head on its own (batched einsums over
-    the head axis), with online-softmax state per (head, query).
+  * The body contracts every head at once without a lane shuffle: the
+    queries arrive BLOCK-DIAGONAL, ``(H, Lk)`` with query head ``h``'s
+    ranks in its KV head's lanes and zeros elsewhere, so ``Q_bd @
+    K_blk^T`` is each head's logits ``(H, bt)``; ``P @ V_blk`` is
+    ``(H, Lv)``, of which each head keeps its own KV head's lanes (the
+    output mask).  The online-softmax state is per query head.
 
-Per row the whole ``(KV, G, dq)`` query slab (queries arrive as
-``(B, KV, G, dq)``) stays resident in VMEM across the page stream.
+The per-head rank clamp of a non-uniform ``RankBudget`` plan (DESIGN.md
+§14) lives in the same two masks: Q lanes at or above a head's kept
+Q-K rank are zero, output lanes at or above its kept V-O rank are
+dropped.  The pad lanes of a row are read by the DMA but reach no
+output (zero query lanes, masked output lanes).
 
 Page size: ``page_tokens`` is the sublane dim of the streamed slabs, so
 keep it a multiple of the dtype tile (8 for f32, 16 for bf16) on real
@@ -51,33 +59,33 @@ TPUs; tests run interpret mode where any size is legal.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import lanes_to_heads
+
 NEG_INF = float(-1e30)
-# a block of pages holds at least this many tokens (one MXU width)
+# a block of pages holds at least this many tokens
 _BLOCK_TOKENS = 128
 # double-buffered K+V blocks stay within half of v5e's 16 MiB default
 # scoped VMEM
 _VMEM_BLOCK_BUDGET = 8 << 20
 
 
-def _pages_per_block(page_tokens: int, n_p: int, kv: int, dq: int,
-                     dv: int, itemsize: int) -> int:
+def _pages_per_block(page_tokens: int, n_p: int, lanes: int,
+                     itemsize: int) -> int:
     """Pages per grid step, from the shapes alone: the fewest holding at
     least ``_BLOCK_TOKENS`` tokens, capped by the table width, and halved
-    while the double-buffered K+V page blocks (padded to the VMEM tile)
-    would exceed ``_VMEM_BLOCK_BUDGET``."""
+    while the double-buffered K+V page blocks (``lanes`` = Lk + Lv,
+    rows padded to the sublane tile) would exceed ``_VMEM_BLOCK_BUDGET``."""
     ppb = max(1, min(n_p, -(-_BLOCK_TOKENS // page_tokens)))
     sub = 8 * max(1, 4 // itemsize)              # sublane tile: 8 f32, 16 bf16
     rows = -(-page_tokens // sub) * sub
-    lanes = sum(-(-d // 128) * 128 for d in (dq, dv))
-    while ppb > 1 and 2 * ppb * kv * rows * lanes * itemsize \
-            > _VMEM_BLOCK_BUDGET:
+    while ppb > 1 and 2 * ppb * rows * lanes * itemsize > _VMEM_BLOCK_BUDGET:
         ppb //= 2
     return ppb
 
@@ -90,9 +98,9 @@ def _row_blocks(length, page_tokens: int, ppb: int, n_p: int):
     return n_used, (n_used + ppb - 1) // ppb
 
 
-def _paged_decode_kernel(len_ref, tab_ref, row_ref, blk_ref, q_ref, *refs,
-                         scale: float, page_tokens: int, ppb: int,
-                         n_p: int):
+def _paged_decode_kernel(len_ref, tab_ref, row_ref, blk_ref, q_ref,
+                         mask_ref, *refs, scale: float, page_tokens: int,
+                         ppb: int, n_p: int, groups: int):
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
     step = pl.program_id(0)
@@ -112,198 +120,63 @@ def _paged_decode_kernel(len_ref, tab_ref, row_ref, blk_ref, q_ref, *refs,
 
     @pl.when(to < length)
     def _body():
-        q = q_ref[0]                                           # (KV, G, dq)
-        # the block's pages side by side along the token axis; a page
-        # slot past the row's in-use pages holds one of its live pages
-        # again, and the length mask drops it
-        k = jnp.concatenate([r[0] for r in k_refs], axis=1)    # (KV, bt, dq)
-        v = jnp.concatenate([r[0] for r in v_refs], axis=1)    # (KV, bt, dv)
-        logits = jnp.einsum('hgd,htd->hgt', q, k,
-                            preferred_element_type=jnp.float32) * scale
-        tj = to + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-        logits = jnp.where(tj < length, logits, NEG_INF)
-        m_prev = m_scr[...]                                    # (KV, G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, 2, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, 2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.einsum(
-            'hgt,htd->hgd', p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(blk == n_blk - 1)
-    def _fin():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-
-
-def _paged_decode_ranked_kernel(len_ref, tab_ref, rq_ref, rv_ref, q_ref,
-                                k_ref, v_ref, o_ref, m_scr, l_scr, p_scr,
-                                acc_scr, *, scale: float, page_tokens: int,
-                                n_p: int, rb: int, n_rq: int, n_rv: int):
-    """Paged flash-decoding with a per-head rank clamp (DESIGN.md §14).
-
-    Same phase schedule as the dense ``_decode_ranked_kernel``: the
-    innermost grid axis walks rank blocks — kept Q-K blocks accumulate
-    the logits tile in ``p_scr``, the ``ir == n_rq`` step runs the
-    online-softmax update, kept V-O blocks accumulate their context
-    slice — with the K/V index maps composing BOTH clamps: the page
-    axis through ``tab[b, min(ip, n_used-1)]`` and the rank axis
-    through the scalar-prefetched per-head kept ranks.
-    """
-    b = pl.program_id(0)
-    kv = pl.program_id(1)
-    ip = pl.program_id(2)
-    ir = pl.program_id(3)
-
-    @pl.when((ip == 0) & (ir == 0))
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(ir == 0)
-    def _zero_logits():
-        p_scr[...] = jnp.zeros_like(p_scr)
-
-    length = len_ref[b]
-    to = ip * page_tokens
-    live = to < length
-
-    @pl.when(live & (ir < n_rq) & (ir * rb < rq_ref[kv]))
-    def _k_phase():
-        q = q_ref[0, 0]                                    # (G, rb)
-        k = k_ref[0, 0]                                    # (pt, rb)
-        p_scr[...] += jax.lax.dot_general(
+        q = q_ref[0]                                           # (H, Lk)
+        # the block's pages one after the other along the token axis; a
+        # page slot past the row's in-use pages holds one of its live
+        # pages again, and the length mask drops it
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)    # (bt, Lk)
+        v = jnp.concatenate([r[0] for r in v_refs], axis=0)    # (bt, Lv)
+        logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(live & (ir == n_rq))
-    def _softmax():
-        logits = p_scr[...] * scale
+            preferred_element_type=jnp.float32) * scale        # (H, bt)
         tj = to + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         logits = jnp.where(tj < length, logits, NEG_INF)
-        m_prev = m_scr[...]                                # (G, 1)
+        m_prev = m_scr[...]                                    # (H, 1)
         m_new = jnp.maximum(m_prev, jnp.max(logits, 1, keepdims=True))
         p = jnp.exp(logits - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, 1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha[None]
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
-        p_scr[...] = p
 
-    @pl.when(live & (ir >= n_rq) & ((ir - n_rq) * rb < rv_ref[kv]))
-    def _v_phase():
-        v = v_ref[0, 0]                                    # (pt, rb)
-        p = p_scr[...]                                     # (G, pt)
-        iv = ir - n_rq
-        upd = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[pl.ds(iv, 1)] = acc_scr[pl.ds(iv, 1)] + upd[None]
-
-    @pl.when((ip == n_p - 1) & (ir == n_rq + n_rv - 1))
+    @pl.when(blk == n_blk - 1)
     def _fin():
-        denom = jnp.maximum(l_scr[...], 1e-30)             # (G, 1)
-        for i in range(n_rv):                              # static
-            o_ref[0, 0, :, i * rb:(i + 1) * rb] = (
-                acc_scr[i] / denom).astype(o_ref.dtype)
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)      # (H, Lv)
+        # query head h of group g keeps its KV head's lanes: one (Lv,)
+        # row per group, already in page-row order
+        for g in range(groups):                                # static
+            o_ref[0, g:g + 1, :] = jnp.sum(
+                jnp.where(mask_ref[g] > 0, o, 0.0), axis=0,
+                keepdims=True).astype(o_ref.dtype)
 
 
-def paged_flash_decode_ranked(q: jnp.ndarray, k_pool: jnp.ndarray,
-                              v_pool: jnp.ndarray, page_table: jnp.ndarray,
-                              lengths: jnp.ndarray, qk_ranks: jnp.ndarray,
-                              vo_ranks: jnp.ndarray, *,
-                              scale: Optional[float] = None,
-                              rank_block: int = 128,
-                              interpret: bool = False) -> jnp.ndarray:
-    """``paged_flash_decode`` with a scalar-prefetched PER-HEAD rank
-    clamp for non-uniform ``RankBudget`` plans (DESIGN.md §14).
-
-    q: (B, KV, G, dq);  pools (N, KV, page_tokens, dq|dv);  qk_ranks /
-    vo_ranks: (KV,) int32 kept ranks per kv head (values clamped to the
-    pool widths).  dq/dv must be multiples of ``rank_block`` (ops.py
-    pads; the ``mask_head_ranks`` zero-pad convention makes padding
-    exact), and the TPU lowering wants ``rank_block`` a multiple of 128
-    or the whole padded width.  -> (B, KV, G, dv)  Rank blocks at or past a head's
-    kept rank revisit the resident block (no DMA) and ``pl.when``
-    skips their compute, so a pruned head's rank tail is free — the
-    rank analogue of the post-rollback length clamp below.
-    """
-    B, KV, G, dq = q.shape
-    pt = k_pool.shape[2]
-    dv = v_pool.shape[-1]
-    rb = rank_block
-    n_p = page_table.shape[1]
-    assert dq % rb == 0 and dv % rb == 0, (dq, dv, rb)
-    if scale is None:
-        scale = float(1.0 / (dq ** 0.5))
-    n_rq, n_rv = dq // rb, dv // rb
-
-    kernel = functools.partial(
-        _paged_decode_ranked_kernel, scale=scale, page_tokens=pt,
-        n_p=n_p, rb=rb, n_rq=n_rq, n_rv=n_rv)
-
-    def _nblk(r):
-        return jnp.maximum((r + rb - 1) // rb, 1)
-
-    def _q_block(b, kv, ip, ir, lens, tab, rq, rv):
-        return (b, kv, 0, jnp.minimum(ir, _nblk(rq[kv]) - 1))
-
-    def _k_block(b, kv, ip, ir, lens, tab, rq, rv):
-        n_used = jnp.maximum((lens[b] + pt - 1) // pt, 1)
-        return (tab[b, jnp.minimum(ip, n_used - 1)], kv, 0,
-                jnp.minimum(ir, _nblk(rq[kv]) - 1))
-
-    def _v_block(b, kv, ip, ir, lens, tab, rq, rv):
-        n_used = jnp.maximum((lens[b] + pt - 1) // pt, 1)
-        return (tab[b, jnp.minimum(ip, n_used - 1)], kv, 0,
-                jnp.clip(ir - n_rq, 0, _nblk(rv[kv]) - 1))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, KV, n_p, n_rq + n_rv),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, rb), _q_block),
-            pl.BlockSpec((1, 1, pt, rb), _k_block),
-            pl.BlockSpec((1, 1, pt, rb), _v_block),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, G, dv),
-            lambda b, kv, ip, ir, lens, tab, rq, rv: (b, kv, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, pt), jnp.float32),
-            pltpu.VMEM((n_rv, G, rb), jnp.float32),
-        ],
-    )
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")),
-        name="paged_flash_decode_ranked",
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32),
-      jnp.minimum(qk_ranks, dq).astype(jnp.int32),
-      jnp.minimum(vo_ranks, dv).astype(jnp.int32), q, k_pool, v_pool)
+def _rank_lanes(width: int, ranks: Optional[jnp.ndarray], kv_of):
+    """(len(kv_of), width) bool: rank i of a head of KV head ``kv_of``
+    is kept (``i < ranks[kv]``; all of them without a plan)."""
+    if ranks is None:
+        return jnp.ones((kv_of.shape[0], width), bool)
+    limit = jnp.asarray(ranks, jnp.int32)[kv_of]
+    return jnp.arange(width)[None, :] < limit[:, None]
 
 
 def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
                        v_pool: jnp.ndarray, page_table: jnp.ndarray,
-                       lengths: jnp.ndarray, *,
+                       lengths: jnp.ndarray, *, kv_heads: int,
+                       head_widths: Tuple[int, int],
                        scale: Optional[float] = None,
+                       qk_ranks: Optional[jnp.ndarray] = None,
+                       vo_ranks: Optional[jnp.ndarray] = None,
                        interpret: bool = False) -> jnp.ndarray:
-    """q: (B, KV, G, dq);  k_pool: (N, KV, page_tokens, dq);
-    v_pool: (N, KV, page_tokens, dv);  page_table: (B, n_p) int32 page
-    ids into the pool (entries past ceil(lengths[b]/page_tokens) are
-    never dereferenced and may be any in-range id, e.g. a garbage-sink
-    sentinel);  lengths: (B,) int32.  -> (B, KV, G, dv)
+    """q: (B, H, dq);  k_pool: (N, page_tokens, Lk);  v_pool: (N,
+    page_tokens, Lv) — one shard's pools in page-row order,
+    ``head_widths = (wk, wv)`` lanes a head (``ref.page_lanes``), dq <=
+    wk (a rank-sliced draft reads each head's leading dq lanes);
+    page_table: (B, n_p) int32 page ids into the pool (entries past
+    ceil(lengths[b]/page_tokens) are never dereferenced and may be any
+    in-range id, e.g. a garbage-sink sentinel);  lengths: (B,) int32;
+    qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
+    (DESIGN.md §14).  -> (B, H, wv)
 
     POST-ROLLBACK contract (speculative decoding, serve.engine): after
     a verify round rejects draft tokens, ``lengths`` decrements while
@@ -314,17 +187,40 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
     a row's allocated page count may exceed ``ceil(lengths[b] /
     page_tokens)`` freely.
     """
-    B, KV, G, dq = q.shape
-    pt = k_pool.shape[2]
-    dv = v_pool.shape[-1]
+    B, H, dq = q.shape
+    KV = kv_heads
+    G = H // KV
+    wk, wv = head_widths
+    _, pt, Lk = k_pool.shape
+    Lv = v_pool.shape[-1]
     n_p = page_table.shape[1]
+    assert dq <= wk and KV * wk <= Lk and KV * wv <= Lv, \
+        (q.shape, k_pool.shape, v_pool.shape, kv_heads, head_widths)
     if scale is None:
         scale = float(1.0 / (dq ** 0.5))
-    ppb = _pages_per_block(pt, n_p, KV, dq, dv, k_pool.dtype.itemsize)
+    ppb = _pages_per_block(pt, n_p, Lk + Lv, k_pool.dtype.itemsize)
+
+    # block-diagonal queries (B, H, Lk): head h's ranks in its KV head's
+    # lanes, zero elsewhere (the Q-K rank clamp zeroes the lanes at or
+    # above each head's kept rank)
+    kv_of = jnp.arange(H) // G
+    qw = jnp.pad(q, [(0, 0), (0, 0), (0, wk - dq)])
+    qw = jnp.where(_rank_lanes(wk, qk_ranks, kv_of)[None], qw, 0)
+    own = (kv_of[:, None] == jnp.arange(KV)[None, :])          # (H, KV)
+    q_bd = jnp.where(own[None, :, :, None], qw[:, :, None, :], 0)
+    q_bd = jnp.pad(q_bd.reshape(B, H, KV * wk).astype(k_pool.dtype),
+                   [(0, 0), (0, 0), (0, Lk - KV * wk)])
+    # output mask (G, H, Lv): group g keeps its query heads' own lanes
+    # below their kept V-O ranks
+    lanes = jnp.where(own[:, :, None], _rank_lanes(wv, vo_ranks, kv_of)[
+        :, None, :], False).reshape(H, KV * wv)
+    lanes = jnp.pad(lanes, [(0, 0), (0, Lv - KV * wv)])
+    of_group = jnp.arange(H)[None, :] % G == jnp.arange(G)[:, None]
+    out_mask = (lanes[None] & of_group[:, :, None]).astype(jnp.float32)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, page_tokens=pt, ppb=ppb,
-        n_p=n_p)
+        n_p=n_p, groups=G)
     # The grid walks only the live (row, page block) steps, row by row:
     # its length is the batch's total block count, known on the device.
     # Step s belongs to the row whose blocks end past it; one compare of
@@ -340,7 +236,10 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
     step_blk = steps - jnp.sum(jnp.where(past, n_blk, 0), axis=1)
 
     def row(s, lens, tab, rows, blks):
-        return (rows[s], 0, 0, 0)
+        return (rows[s], 0, 0)
+
+    def whole(s, lens, tab, rows, blks):
+        return (0, 0, 0)
 
     def page_slot(j):
         # Page slot j of block blk holds table entry blk * ppb + j.  Past
@@ -354,30 +253,34 @@ def paged_flash_decode(q: jnp.ndarray, k_pool: jnp.ndarray,
             last = jnp.maximum(n_used - 1 - j, 0) // ppb
             ip = jnp.minimum(jnp.minimum(blks[s], last) * ppb + j,
                              n_used - 1)
-            return (tab[b, ip], 0, 0, 0)
+            return (tab[b, ip], 0, 0)
         return index
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(ends[-1],),
-        in_specs=[pl.BlockSpec((1, KV, G, dq), row)]
-        + [pl.BlockSpec((1, KV, pt, dq), page_slot(j)) for j in range(ppb)]
-        + [pl.BlockSpec((1, KV, pt, dv), page_slot(j)) for j in range(ppb)],
-        out_specs=pl.BlockSpec((1, KV, G, dv), row),
+        in_specs=[pl.BlockSpec((1, H, Lk), row),
+                  pl.BlockSpec((G, H, Lv), whole)]
+        + [pl.BlockSpec((1, pt, Lk), page_slot(j)) for j in range(ppb)]
+        + [pl.BlockSpec((1, pt, Lv), page_slot(j)) for j in range(ppb)],
+        out_specs=pl.BlockSpec((1, G, Lv), row),
         scratch_shapes=[
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, 1), jnp.float32),
-            pltpu.VMEM((KV, G, dv), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, Lv), jnp.float32),
         ],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, Lv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_flash_decode",
         interpret=interpret,
-    )(lengths, page_table.astype(jnp.int32), step_row, step_blk, q,
-      *[k_pool] * ppb, *[v_pool] * ppb)
+    )(lengths, page_table.astype(jnp.int32), step_row, step_blk, q_bd,
+      out_mask, *[k_pool] * ppb, *[v_pool] * ppb)
+    # (B, G, Lv) page-row order -> (B, H, wv), H = KV groups of G
+    return lanes_to_heads(out, KV, wv).transpose(0, 2, 1, 3).reshape(
+        B, H, wv)

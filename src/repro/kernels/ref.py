@@ -2,9 +2,11 @@
 behind DESIGN.md §4's kernels and the §9/§12 page movers).
 
 These are the semantics the kernels must reproduce bit-approximately;
-tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.  KV caches
-and page pools are head-major, as the kernels store them: dense
-``(B, KV, T, r)``, paged ``(N, KV, page_tokens, r)``.
+tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.  Dense KV
+caches are head-major ``(B, KV, T, r)``.  Page pools are token-major and
+lane-dense ``(N, page_tokens, lanes)``: a page row holds every KV head
+of one token side by side (``page_lanes`` / ``heads_to_lanes`` /
+``lanes_to_heads`` below are the one definition of that order).
 """
 from __future__ import annotations
 
@@ -12,6 +14,40 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+# the TPU vector lane width: a page row's lane count is a multiple of it
+LANES = 128
+
+
+def page_lanes(kv_heads: int, width: int, shards: int = 1) -> int:
+    """Lanes of one page row (DESIGN.md §6): ``shards`` equal blocks,
+    one per tensor-parallel shard, each holding its ``kv_heads //
+    shards`` heads side by side at ``width`` lanes a head, rounded up to
+    a multiple of 128.  Head ``h`` of a block owns its lanes ``[h *
+    width, (h + 1) * width)``; the pad lanes after them are never read.
+    With one shard, or whenever a block's heads fill whole lane tiles,
+    this is ``KV * width`` rounded up to 128."""
+    per = kv_heads // shards * width
+    return shards * (-(-per // LANES) * LANES)
+
+
+def heads_to_lanes(x: jnp.ndarray, lanes: int,
+                   shards: int = 1) -> jnp.ndarray:
+    """(..., KV, width) -> (..., lanes) in page-row order, pad lanes 0."""
+    *lead, kv, width = x.shape
+    per = kv // shards * width
+    x = x.reshape(*lead, shards, per)
+    x = jnp.pad(x, [(0, 0)] * (len(lead) + 1) + [(0, lanes // shards - per)])
+    return x.reshape(*lead, lanes)
+
+
+def lanes_to_heads(x: jnp.ndarray, kv_heads: int, width: int,
+                   shards: int = 1) -> jnp.ndarray:
+    """(..., lanes) in page-row order -> (..., KV, width)."""
+    *lead, lanes = x.shape
+    x = x.reshape(*lead, shards, lanes // shards)
+    x = x[..., :kv_heads // shards * width]
+    return x.reshape(*lead, kv_heads, width)
 
 
 def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -42,11 +78,11 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def _rank_mask(x: jnp.ndarray, ranks: Optional[jnp.ndarray]) -> jnp.ndarray:
     """Zero dims >= ranks[kv] of a (B, KV, T, d) cache — the exact-
     truncation oracle semantics of a non-uniform per-head rank plan
-    (DESIGN.md §14).  The rank-clamped kernels skip whole
-    ``rank_block``-wide blocks instead; both agree whenever every rank
-    is a block multiple OR the data already obeys the
-    ``mask_head_ranks`` zero-pad convention (zeroed dims contribute
-    exactly 0 either way)."""
+    (DESIGN.md §14).  The paged kernel masks the same lanes exactly;
+    the dense rank-clamped kernel skips whole ``rank_block``-wide
+    blocks instead, which agrees whenever every rank is a block
+    multiple OR the data already obeys the ``mask_head_ranks`` zero-pad
+    convention (zeroed dims contribute exactly 0 either way)."""
     if ranks is None:
         return x
     d = x.shape[-1]
@@ -121,6 +157,9 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
                                v_pool: jnp.ndarray,
                                page_table: jnp.ndarray,
                                lengths: jnp.ndarray, *,
+                               kv_heads: int,
+                               head_widths: Tuple[int, int],
+                               shards: int = 1,
                                scale: Optional[float] = None,
                                qk_ranks: Optional[jnp.ndarray] = None,
                                vo_ranks: Optional[jnp.ndarray] = None,
@@ -130,25 +169,35 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
     to the dense oracle (lengths mask everything past each slot's valid
     tokens, so sentinel/garbage pages never influence the output).
 
-    q: (B, H, dq);  k_pool: (N, KV, page_tokens, dq);
-    v_pool: (N, KV, page_tokens, dv);  page_table: (B, n_p) int32;
-    lengths: (B,) int32;  qk_ranks / vo_ranks: optional (KV,) int32
-    per-head kept ranks (see ``decode_attention_ref``).  -> (B, H, dv)
+    q: (B, H, dq);  k_pool: (N, page_tokens, Lk);  v_pool: (N,
+    page_tokens, Lv), both in page-row order with ``head_widths = (wk,
+    wv)`` lanes a head over ``shards`` lane blocks (``page_lanes``);
+    dq <= wk (a rank-sliced draft reads each head's leading dq lanes);
+    page_table: (B, n_p) int32;  lengths: (B,) int32;  qk_ranks /
+    vo_ranks: optional (KV,) int32 per-head kept ranks (see
+    ``decode_attention_ref``).  -> (B, H, wv)
     """
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
-    return decode_attention_ref(q, k, v, lengths, scale=scale,
-                                qk_ranks=qk_ranks, vo_ranks=vo_ranks)
+    wk, wv = head_widths
+    dq = q.shape[-1]
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(dq).astype(jnp.float32)
+
+    def heads(pool, width):                 # (B, KV, T, width)
+        return lanes_to_heads(gather_pages(pool, page_table), kv_heads,
+                              width, shards).transpose(0, 2, 1, 3)
+    qw = jnp.pad(q, [(0, 0), (0, 0), (0, wk - dq)])
+    return decode_attention_ref(qw, heads(k_pool, wk), heads(v_pool, wv),
+                                lengths, scale=scale, qk_ranks=qk_ranks,
+                                vo_ranks=vo_ranks)
 
 
 def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     """Dense per-slot view of a paged pool through the page table:
-    pool (N, KV, page_tokens, r), page_table (B, n_p) -> (B, KV,
-    n_p * page_tokens, r)."""
+    pool (N, page_tokens, lanes), page_table (B, n_p) -> (B, n_p *
+    page_tokens, lanes), token-major like the pool."""
     B, n_p = page_table.shape
-    _, KV, pt, r = pool.shape
-    return pool[page_table].transpose(0, 2, 1, 3, 4).reshape(
-        B, KV, n_p * pt, r)
+    _, pt, lanes = pool.shape
+    return pool[page_table].reshape(B, n_p * pt, lanes)
 
 
 def page_copy_ref(pool: jnp.ndarray, src: jnp.ndarray,
@@ -157,7 +206,7 @@ def page_copy_ref(pool: jnp.ndarray, src: jnp.ndarray,
     serve.engine).  Rows ``dst[i]`` become copies of rows ``src[i]``;
     every other row is untouched.
 
-    pool: (n_blocks, N, KV, page_tokens, r);  src, dst: (m,) int32.
+    pool: (n_blocks, N, page_tokens, lanes);  src, dst: (m,) int32.
     Pairs are disjoint except sentinel self-copies (dst may repeat the
     sentinel row as padding), and all reads see the INPUT pool — a page
     can be a src of one pair and the dst of a LATER pair only after the
@@ -176,8 +225,8 @@ def page_restore_ref(pool: jnp.ndarray, rows: jnp.ndarray,
     writes rows whose bytes came from outside it — host RAM spill
     slabs copied back before a prefix-cache restore resumes prefill.
 
-    pool: (n_blocks, N, KV, page_tokens, r);
-    rows: (n_blocks, W, KV, page_tokens, r) — slab ``rows[:, i]``
+    pool: (n_blocks, N, page_tokens, lanes);
+    rows: (n_blocks, W, page_tokens, lanes) — slab ``rows[:, i]``
     lands in pool row ``dst[i]``;  dst: (W,) int32.  Real dst entries
     are distinct freshly-allocated pages; padding repeats the sentinel
     row with all-zero slabs (duplicate scatter targets therefore all

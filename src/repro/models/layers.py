@@ -229,11 +229,13 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
     (tokens, rank) blocks whole (DESIGN.md §4).
 
     Paged decode: ``page_table`` (B, n_p) int32 is given and ``kv_cache``
-    holds the global pools {"k": (N, KV, page_tokens, dq), "v": (N, KV,
-    page_tokens, dv)} shared by all slots; ``cache_index`` must be
-    the (B,) per-slot vector.  Position p of slot b lives at
-    ``pool[page_table[b, p // page_tokens], :, p % page_tokens]``.  The
-    table must cover positions [0, cache_index + S) per slot — entries
+    holds the global pools {"k": (N, page_tokens, Lk), "v": (N,
+    page_tokens, Lv)} shared by all slots, token-major and lane-dense
+    (one token's KV heads side by side, ``kernels.ref.page_lanes``);
+    ``cache_index`` must be the (B,) per-slot vector.  Position p of
+    slot b lives at ``pool[page_table[b, p // page_tokens], p %
+    page_tokens]``.  The table must cover positions [0, cache_index +
+    S) per slot — entries
     may be a sentinel id addressing the pool's spare garbage row, where
     padding/idle-slot writes land harmlessly (DESIGN.md §6).  With
     prefix caching a slot's table may map pages SHARED with other
@@ -297,11 +299,12 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         return jnp.einsum("bshv,hvd->bsd", ctx,
                           params["wo"][..., :dv, :].astype(x.dtype))
 
-    def _masked(k, v, mask):
-        """Masked softmax attention over dense head-major K/V (B, KV, T,
-        r) with a (B, S, T) mask: the (B, S, H, dv) context."""
+    def _masked(k, v, mask, kv_spec="bktq"):
+        """Masked softmax attention over dense K/V with a (B, S, T)
+        mask: the (B, S, H, dv) context.  K/V are head-major (B, KV, T,
+        r), or token-major (B, T, KV, r) with ``kv_spec="btkq"``."""
         qg = q.reshape(B, S, KV, G, dq)
-        logits = jnp.einsum("bskgq,bktq->bkgst", qg, k) * scale
+        logits = jnp.einsum(f"bskgq,{kv_spec}->bkgst", qg, k) * scale
         if cfg.attn_logit_softcap > 0:
             c = cfg.attn_logit_softcap
             logits = jnp.tanh(logits / c) * c
@@ -309,8 +312,8 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         neg = jnp.finfo(jnp.float32).min
         logits = jnp.where(mask[:, None, None, :, :], logits, neg)
         probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-        return jnp.einsum("bkgst,bktv->bskgv", probs,
-                          v).reshape(B, S, H, dv)
+        return jnp.einsum(f"bkgst,{kv_spec.replace('q', 'v')}->bskgv",
+                          probs, v).reshape(B, S, H, dv)
 
     # Partial-RoPE pruning keeps the rotated block intact at the front, so
     # RoPE always applies to the first rope_dims (<= dq) dims.
@@ -354,7 +357,17 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         # garbage inside a slot's own last page sits beyond its causal
         # horizon until the slot itself overwrites it — the same
         # masked-or-overwritten invariant as the dense cache.
-        N, PT = kv_cache["k"].shape[0], kv_cache["k"].shape[2]
+        from repro.kernels.ref import (gather_pages, heads_to_lanes,
+                                       lanes_to_heads, page_lanes)
+        from repro.parallel.sharding import kv_shards
+        N, PT, Lk = kv_cache["k"].shape
+        Lv = kv_cache["v"].shape[-1]
+        # lane blocks: one per shard of the executor's mesh, as it built
+        # the pool (transformer.init_decode_state_paged)
+        shards = kv_shards(getattr(attn_impl, "mesh", None), KV)
+        assert (Lk, Lv) == (page_lanes(KV, dq_c, shards),
+                            page_lanes(KV, dv_c, shards)), \
+            ((Lk, Lv), KV, (dq_c, dv_c), shards)
         P = page_table.shape[1]
         pos = cache_index[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         # Positions past the table (an idle slot whose index ran beyond
@@ -372,39 +385,42 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             # COW path means this never fires for valid traffic.
             page = jnp.where(pos >= write_floor[:, None], page, N - 1)
         off = pos % PT
-        # (page, :, off) scatter: the (B, S) index pairs lead, so the
-        # update is the token-major (B, S, KV, r) projection itself.
+        # (page, off) scatter: the (B, S) index pairs lead and each
+        # update is one whole page row, the token's heads side by side,
+        # so the pool is written in place in the order it is stored.
         # The named scopes (kv_write, kv_read, paged_decode, kv_gather)
         # label the KV data movement in profiler traces.
         with jax.named_scope("kv_write"):
-            ck = kv_cache["k"].at[page, :, off].set(
-                _pad_rank(k, dq_c).astype(kv_cache["k"].dtype))
-            cv = kv_cache["v"].at[page, :, off].set(
-                _pad_rank(v, dv_c).astype(kv_cache["v"].dtype))
+            ck = kv_cache["k"].at[page, off].set(heads_to_lanes(
+                _pad_rank(k, dq_c).astype(kv_cache["k"].dtype), Lk, shards))
+            cv = kv_cache["v"].at[page, off].set(heads_to_lanes(
+                _pad_rank(v, dv_c).astype(kv_cache["v"].dtype), Lv, shards))
         new_cache = {"k": ck, "v": cv}
         if use_pallas and S == 1:  # paged flash-decoding: the hot path
             lengths = (cache_index + 1).astype(jnp.int32)
             with jax.named_scope("kv_read"):
-                kr = ck[..., :dq].astype(x.dtype)
-                vr = cv[..., :dv].astype(x.dtype)
+                kr, vr = ck.astype(x.dtype), cv.astype(x.dtype)
             with jax.named_scope("paged_decode"):
                 ctx = dispatch.paged_decode_attention(
-                    q[:, 0], kr, vr, page_table, lengths, scale=scale,
-                    **rank_kw)[:, None]                     # (B,1,H,dv)
+                    q[:, 0], kr, vr, page_table, lengths, kv_heads=KV,
+                    head_widths=(dq_c, dv_c), scale=scale,
+                    **rank_kw)[:, None, :, :dv]             # (B,1,H,dv)
             return _vo_out(ctx), new_cache
         # Chunked-prefill reads gather each slot's pages into a dense
-        # (B, KV, P*PT, r) view and run the masked path over it; writes
-        # stay pool-resident (noted in DESIGN.md §6 as the cold path).
-        from repro.kernels.ref import gather_pages
+        # token-major (B, P*PT, KV, r) view and run the masked path over
+        # it; writes stay pool-resident (noted in DESIGN.md §6 as the
+        # cold path).
         with jax.named_scope("kv_gather"):
-            k = gather_pages(ck, page_table)[..., :dq].astype(x.dtype)
-            v = gather_pages(cv, page_table)[..., :dv].astype(x.dtype)
-            T = k.shape[2]
+            k = lanes_to_heads(gather_pages(ck, page_table), KV, dq_c,
+                               shards)[..., :dq].astype(x.dtype)
+            v = lanes_to_heads(gather_pages(cv, page_table), KV, dv_c,
+                               shards)[..., :dv].astype(x.dtype)
+            T = k.shape[1]
             kv_pos = jnp.arange(T, dtype=jnp.int32)
             qpos = (cache_index[:, None]
                     + jnp.arange(S, dtype=jnp.int32)[None, :])
             mask = kv_pos[None, None, :] <= qpos[:, :, None]  # (B, S, T)
-            ctx = _masked(k, v, mask)
+            ctx = _masked(k, v, mask, kv_spec="btkq")
         return _vo_out(ctx), new_cache
     elif kv_cache is not None:
         # cache_index: scalar (whole batch at one position — prefill and

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import (ArchConfig, MIXER_ATTN, MIXER_MAMBA,
                                 MIXER_RWKV, MLP_DENSE, MLP_MOE, MLP_RWKV)
+from repro.kernels.ref import page_lanes
 from repro.models import layers as L
 from repro.models import mamba as M
 from repro.models import moe as MOE
@@ -274,18 +275,23 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_len: int) -> Params:
 
 
 def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
-                            page_tokens: int) -> Params:
+                            page_tokens: int, shards: int = 1) -> Params:
     """Decode state whose KV caches are PAGED: one global pool
-    ``(n_pages + 1, KV, page_tokens, r)`` per attention layer (stacked
-    over ``n_blocks``) instead of a dense per-slot ``(batch, KV,
-    max_len, r)``.  Both are head-major (DESIGN.md §4).  Row
-    ``n_pages`` is the spare garbage row that sentinel
-    page-table entries address (padding / idle-slot writes land there).
-    Recurrent (mamba/rwkv) leaves stay per-slot — they are O(1) in
-    sequence length, so paging buys them nothing.  ``index`` is the
-    (batch,) per-slot position vector; the (batch, n_p) page table is
-    host-owned (serve.engine's ``PageAllocator``) and passed into each
-    step alongside the state.
+    ``(n_pages + 1, page_tokens, lanes)`` per attention layer and per K
+    and V (stacked over ``n_blocks``) instead of a dense per-slot
+    ``(batch, KV, max_len, r)``.  A page row is TOKEN-MAJOR and
+    LANE-DENSE: one token's KV heads side by side, ``r`` lanes each, the
+    row rounded up to a multiple of 128 lanes, in ``shards`` blocks of
+    heads, one per tensor-parallel shard (``kernels.ref.page_lanes``;
+    DESIGN.md §4, §6).  GPT-2 XL at rank 32 stores 25 x 32 = 800 lanes
+    in 896; a rank-128 head fills whole tiles with no pad.  Row
+    ``n_pages`` is the spare garbage row that sentinel page-table entries
+    address (padding / idle-slot writes land there).  Recurrent
+    (mamba/rwkv) leaves stay per-slot — they are O(1) in sequence
+    length, so paging buys them nothing.  ``index`` is the (batch,)
+    per-slot position vector; the (batch, n_p) page table is host-owned
+    (serve.engine's ``PageAllocator``) and passed into each step
+    alongside the state.
     """
     dense = init_decode_state(cfg, batch, 1)   # non-KV leaves + layout
     kv_dtype = _dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
@@ -294,9 +300,8 @@ def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
         names = [getattr(p, "key", None) for p in path]
         if "kv" not in names:
             return leaf
-        r = leaf.shape[-1]          # qk or vo rank
-        KV = leaf.shape[-3]
-        return jnp.zeros((cfg.n_blocks, n_pages + 1, KV, page_tokens, r),
+        lanes = page_lanes(leaf.shape[-3], leaf.shape[-1], shards)
+        return jnp.zeros((cfg.n_blocks, n_pages + 1, page_tokens, lanes),
                          kv_dtype)
 
     blocks = jax.tree_util.tree_map_with_path(repage, dense["blocks"])

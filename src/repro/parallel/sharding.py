@@ -259,10 +259,10 @@ def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
                       rules: Optional[ShardingRules] = None) -> Params:
     """PartitionSpec tree for the serving engine's decode state.
 
-    KV leaves shard along the KV-HEAD axis (axis 2 in both head-major
-    layouts: dense ``(nb, B, KV, T, r)`` and paged ``(nb, n_pages+1,
-    KV, PT, r)``); the dense layout additionally shards slots over
-    "data".  The
+    KV leaves shard along their heads: the dense head-major ``(nb, B,
+    KV, T, r)`` along KV (and its slots over "data"), the paged pool
+    ``(nb, n_pages+1, PT, lanes)`` along its lanes, one block of heads
+    a shard (``kernels.ref.page_lanes`` with ``kv_shards`` blocks).  The
     paged pool's page-row axis is replicated — page ids are global, the
     host-side allocator/trie address the same rows on every shard.
     Recurrent (mamba/rwkv) leaves shard only their slot axis over
@@ -276,7 +276,7 @@ def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
         if getattr(path[-1], "key", "") == "index":
             return P()
         if "kv" in names:
-            axes = ((None, None, KV_HEADS, None, None) if paged
+            axes = ((None, None, None, KV_HEADS) if paged
                     else (None, BATCH, KV_HEADS, None, None))
         else:
             axes = (None, BATCH) + (None,) * (leaf.ndim - 2)
@@ -327,6 +327,15 @@ def kernel_axes(mesh: Optional[Mesh], *, batch: int, kv_heads: int,
             else None
 
     return pick(BATCH, batch), pick(KV_HEADS, kv_heads)
+
+
+def kv_shards(mesh: Optional[Mesh], kv_heads: int) -> int:
+    """Lane blocks of a paged pool (``kernels.ref.page_lanes``): the
+    extent of the mesh axis that splits the KV heads (``kernel_axes``),
+    else 1.  The executor builds its pools with it and the model's
+    paged reads and writes address them with it."""
+    _, m = kernel_axes(mesh, batch=1, kv_heads=kv_heads)
+    return 1 if m is None else mesh.shape[m]
 
 
 def shard_map_call(body, mesh: Mesh, in_specs, out_specs):

@@ -402,9 +402,10 @@ class LocalExecutor:
     def init_state(self) -> Params:
         cfg, ecfg = self.cfg, self.ecfg
         if ecfg.paged:
-            state = T.init_decode_state_paged(cfg, ecfg.slots,
-                                              ecfg.pool_pages,
-                                              ecfg.page_tokens)
+            from repro.parallel.sharding import kv_shards
+            state = T.init_decode_state_paged(
+                cfg, ecfg.slots, ecfg.pool_pages, ecfg.page_tokens,
+                shards=kv_shards(self.dispatch.mesh, cfg.n_kv_heads))
         else:
             state = T.init_decode_state(cfg, ecfg.slots, ecfg.capacity)
             # per-slot positions: (slots,) index vector so slots at
@@ -469,7 +470,7 @@ class LocalExecutor:
     def page_restore(self, state, rows, dst) -> Params:
         """Host->device restore scatter: slab ``rows[leaf][:, i]`` lands
         in pool row ``dst[i]`` of the matching KV leaf.  ``rows`` is a
-        list of (n_blocks, W, KV, page_tokens, r) arrays in the same
+        list of (n_blocks, W, page_tokens, lanes) arrays in the same
         tree order ``read_page`` produces; short batches arrive
         zero-padded with sentinel dst entries (one fixed W = no new
         compiled shapes per batch size)."""
@@ -539,7 +540,8 @@ class ShardedExecutor(LocalExecutor):
     ~equal pruned FLOPs/bytes), PERMUTES the attention head axes to
     realize the plan, and places params/state with the serving rules:
     heads / ff / vocab over "model", slot batch over "data", KV and
-    page pools sharded along their KV-HEAD axis.  The page allocator
+    page pools sharded along their heads (a pool's lanes, one block
+    of heads a shard).  The page allocator
     and prefix trie stay host-global — page ids mean the same thing on
     every shard.  ``plan_salt`` folds the head layout into the prefix-
     cache salt so rank-plan/layout reuse stays correct.
@@ -565,7 +567,7 @@ class ShardedExecutor(LocalExecutor):
     (``kernels.ops.KernelDispatch``).  Page ids stay host-global — the
     pools' page-row axis is replicated, so the scalar-prefetched page
     tables cross the shard boundary untranslated and each shard reads
-    its own KV-head slice of the same rows.  Per-(slot, kv-head)
+    its own heads' lane block of the same rows.  Per-(slot, kv-head)
     results are independent, so per-shard kernel outputs are bitwise
     identical to the single-device kernels.  The one combo that cannot
     run per shard — recurrent kernels under tp > 1 — is rejected with

@@ -488,7 +488,9 @@ def rank_pool_bytes(plan, *, page_tokens: int, n_pages: int,
 
     The PHYSICAL pools are sized by the plan's global max widths — the
     transformer lax.scans a stacked state pytree, so every layer's pool
-    shares one shape ``(n_pages + 1, KV, page_tokens, max_rank)``.
+    shares one shape ``(n_pages + 1, page_tokens, lanes)``, its rows
+    ``KV x max_rank`` wide before the lane pad (``kernels.ref
+    .page_lanes``, not counted here).
     This helper reports what those bytes BUY per layer: the bytes the
     kept ranks actually use (``kept``), the uniform-max footprint the
     stack allocates (``allocated``), and the layerwise breakdown — the

@@ -150,8 +150,7 @@ def test_tp_pads_nondivisible_kv_heads_subprocess():
         assert eng.exe.kv_pad == 3
         pool = eng.state["blocks"][0]["kv"]["k"]
         shards = {s.data.shape for s in pool.addressable_shards}
-        assert shards == {(pool.shape[0], pool.shape[1], 1) +
-                          pool.shape[3:]}, shards
+        assert shards == {pool.shape[:3] + (pool.shape[3] // 4,)}, shards
         print("PAD_OK")
     """)
     env = dict(os.environ, PYTHONPATH=str(SRC))

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops as kops
+from repro.kernels import ops as kops, ref
 from repro.launch.mesh import make_host_mesh
 
 
@@ -136,26 +136,61 @@ def test_decode_attention_shard_parity():
                                                  np.asarray(b))
 
 
+def _paged_pool(rng, N, PT, KV, r, shards):
+    """A ragged head-major pool re-laid-out in page-row order, ``shards``
+    lane blocks of heads (``ref.page_lanes``)."""
+    heads = _ragged_kv(rng, (N, PT, KV, r), head_axis=2, rank_axis=3)
+    return ref.heads_to_lanes(heads, ref.page_lanes(KV, r, shards), shards)
+
+
 def test_paged_decode_attention_shard_parity():
     _need(2)
     mesh = make_host_mesh(model=2)
     rng = np.random.default_rng(1)
-    B, H, KV, dq, dv = 4, 8, 4, 16, 12
+    # two heads a shard fill whole lane tiles, so the one-block (single
+    # device) and two-block (tp=2) page rows are the same bytes
+    B, H, KV, dq, dv = 4, 8, 4, 128, 64
     N, PT, nP = 11, 8, 5                 # row N-1 = the garbage row
     q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    kp = _ragged_kv(rng, (N, KV, PT, dq), head_axis=1, rank_axis=3)
-    vp = _ragged_kv(rng, (N, KV, PT, dv), head_axis=1, rank_axis=3)
+    kp = _paged_pool(rng, N, PT, KV, dq, shards=2)
+    vp = _paged_pool(rng, N, PT, KV, dv, shards=2)
+    assert kp.shape[-1] == ref.page_lanes(KV, dq, 1)
     # host-global page ids, including sentinel entries past each slot's
     # coverage — identical tables must dereference identically per shard
     table = jnp.asarray(rng.integers(0, N, (B, nP)), jnp.int32)
     lens = jnp.asarray([3, 24, 40, 9], jnp.int32)
     single = kops.resolve("interpret")
     sharded = kops.resolve("interpret", mesh=mesh)
-    a = jax.jit(lambda *x: single.paged_decode_attention(*x, scale=0.3))(
+    geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=0.3)
+    a = jax.jit(lambda *x: single.paged_decode_attention(*x, **geo))(
         q, kp, vp, table, lens)
-    b = jax.jit(lambda *x: sharded.paged_decode_attention(*x, scale=0.3))(
+    b = jax.jit(lambda *x: sharded.paged_decode_attention(*x, **geo))(
         q, kp, vp, table, lens)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_paged_decode_attention_shard_lane_blocks():
+    """Heads of a shard that do not fill a lane tile: each shard's block
+    is padded on its own (2 x 16 = 32 lanes in 128), and the tp=2 kernel
+    matches the oracle reading the same two-block rows."""
+    _need(2)
+    mesh = make_host_mesh(model=2)
+    rng = np.random.default_rng(5)
+    B, H, KV, dq, dv = 4, 8, 4, 16, 12
+    N, PT, nP = 11, 8, 5
+    q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
+    kp = _paged_pool(rng, N, PT, KV, dq, shards=2)
+    vp = _paged_pool(rng, N, PT, KV, dv, shards=2)
+    assert kp.shape[-1] == 256 != ref.page_lanes(KV, dq, 1)
+    table = jnp.asarray(rng.integers(0, N, (B, nP)), jnp.int32)
+    lens = jnp.asarray([3, 24, 40, 9], jnp.int32)
+    geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=0.3)
+    a = jax.jit(lambda *x: kops.resolve("ref", mesh=mesh)
+                .paged_decode_attention(*x, **geo))(q, kp, vp, table, lens)
+    b = jax.jit(lambda *x: kops.resolve("interpret", mesh=mesh)
+                .paged_decode_attention(*x, **geo))(q, kp, vp, table, lens)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                               rtol=5e-5)
 
 
 def test_page_copy_shard_parity():
@@ -163,7 +198,8 @@ def test_page_copy_shard_parity():
     mesh = make_host_mesh(model=2)
     rng = np.random.default_rng(2)
     nb, N, PT, KV, r = 2, 10, 8, 4, 12
-    pool = _ragged_kv(rng, (nb, N, KV, PT, r), head_axis=2, rank_axis=4)
+    pool = jnp.stack([_paged_pool(rng, N, PT, KV, r, shards=2)
+                      for _ in range(nb)])
     src = jnp.asarray([1, 3, 6], jnp.int32)
     dst = jnp.asarray([5, 7, 0], jnp.int32)
     single = kops.resolve("interpret")

@@ -150,7 +150,10 @@ def test_trie_evict_lru_leaf_first_and_skips_mapped():
 
 @pytest.mark.parametrize("nb,N,pt,KV,r", [(3, 9, 4, 2, 16), (1, 5, 8, 1, 8)])
 def test_page_copy_kernel_matches_ref(nb, N, pt, KV, r):
-    pool = jax.random.normal(jax.random.PRNGKey(0), (nb, N, KV, pt, r))
+    # page rows: the token's heads side by side, padded to 128 lanes
+    pool = ref.heads_to_lanes(
+        jax.random.normal(jax.random.PRNGKey(0), (nb, N, pt, KV, r)),
+        ref.page_lanes(KV, r))
     # distinct pairs + sentinel self-copy padding (row N-1)
     src = jnp.array([0, 3, N - 1], jnp.int32)
     dst = jnp.array([2, 1, N - 1], jnp.int32)

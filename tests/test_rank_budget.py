@@ -9,6 +9,7 @@ import textwrap
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,7 +19,7 @@ from repro.core import (RankBudget, apply_rank_budget, budget_kept_energy,
 from repro.core.prune import snap_rank, threshold_ratios
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode_ranked
-from repro.kernels.paged_decode_attention import paged_flash_decode_ranked
+from repro.kernels.paged_decode_attention import paged_flash_decode
 from repro.serve.memory import PageAllocator, PrefixCache
 
 SRC = str(Path(__file__).resolve().parent.parent / "src")
@@ -223,34 +224,38 @@ def test_ranked_decode_kernel_parity():
 
 def test_ranked_paged_decode_kernel_parity():
     rng = np.random.default_rng(1)
-    B, KV, G, dq, dv, T, pt, rb = 3, 4, 2, 32, 24, 64, 8, 8
+    B, KV, G, dq, dv, T, pt = 3, 4, 2, 32, 24, 64, 8
     q = rng.normal(size=(B, KV * G, dq)).astype(np.float32)
     lengths = np.array([5, 37, 64], np.int32)
     rq = np.array([8, 16, 32, 24], np.int32)
     rv = np.array([24, 8, 16, 24], np.int32)
     n_p = T // pt
     N = B * n_p + 1
-    pool_k = rng.normal(size=(N, KV, pt, dq)).astype(np.float32)
-    pool_v = rng.normal(size=(N, KV, pt, dv)).astype(np.float32)
+    pool_k = rng.normal(size=(N, pt, KV, dq)).astype(np.float32)
+    pool_v = rng.normal(size=(N, pt, KV, dv)).astype(np.float32)
     qz = q.copy()
     for h in range(KV):
         qz[:, h * G:(h + 1) * G, rq[h]:] = 0.0
-        pool_k[:, h, :, rq[h]:] = 0.0
-        pool_v[:, h, :, rv[h]:] = 0.0
+        pool_k[:, :, h, rq[h]:] = 0.0
+        pool_v[:, :, h, rv[h]:] = 0.0
+    # the pools in page-row order: a token's heads side by side
+    pool_k = ref.heads_to_lanes(jnp.asarray(pool_k), ref.page_lanes(KV, dq))
+    pool_v = ref.heads_to_lanes(jnp.asarray(pool_v), ref.page_lanes(KV, dv))
     table = rng.permutation(N - 1)[:B * n_p].reshape(B, n_p).astype(np.int32)
-    qg = qz.reshape(B, KV, G, dq)             # the kernel's query layout
     scale = 1.0 / np.sqrt(dq)
-    out = paged_flash_decode_ranked(qg, pool_k, pool_v, table, lengths,
-                                    rq, rv, scale=scale, rank_block=rb,
-                                    interpret=True)
-    out_full = paged_flash_decode_ranked(
-        qg, pool_k, pool_v, table, lengths, np.full(KV, dq, np.int32),
-        np.full(KV, dv, np.int32), scale=scale, rank_block=rb,
-        interpret=True)
+    geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=scale,
+               interpret=True)
+    out = paged_flash_decode(qz, pool_k, pool_v, table, lengths,
+                             qk_ranks=rq, vo_ranks=rv, **geo)
+    out_full = paged_flash_decode(
+        qz, pool_k, pool_v, table, lengths,
+        qk_ranks=np.full(KV, dq, np.int32),
+        vo_ranks=np.full(KV, dv, np.int32), **geo)
     assert (np.asarray(out) == np.asarray(out_full)).all()
-    out = np.asarray(out).reshape(B, KV * G, dv)
     oracle = ref.paged_decode_attention_ref(qz, pool_k, pool_v, table,
-                                            lengths, scale=scale)
+                                            lengths, kv_heads=KV,
+                                            head_widths=(dq, dv),
+                                            scale=scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                atol=5e-5, rtol=5e-5)
 

@@ -165,16 +165,18 @@ def test_paged_decode_kernel_post_rollback():
     n_pool = B * n_p + 1
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (B, H, d))
-    k_pool = jax.random.normal(ks[1], (n_pool, KV, pt, d))
-    v_pool = jax.random.normal(ks[2], (n_pool, KV, pt, d))
+    lanes = ref.page_lanes(KV, d)
+    k_pool = ref.heads_to_lanes(
+        jax.random.normal(ks[1], (n_pool, pt, KV, d)), lanes)
+    v_pool = ref.heads_to_lanes(
+        jax.random.normal(ks[2], (n_pool, pt, KV, d)), lanes)
+    geo = dict(kv_heads=KV, head_widths=(d, d), impl="interpret")
     # every slot owns ALL n_p of its pages (pre-rollback coverage) ...
     tab = jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
     # ... but lengths rolled back to mid-page values
     lens = jnp.array([6, 13], jnp.int32)
-    o1 = ops.paged_decode_attention(q, k_pool, v_pool, tab, lens,
-                                    impl="interpret")
+    o1 = ops.paged_decode_attention(q, k_pool, v_pool, tab, lens, **geo)
     # poison everything past each slot's rolled-back length
-    flat_pos = jnp.arange(n_pool * pt).reshape(n_pool, pt)
     poison = jnp.zeros((n_pool, pt), bool)
     for b in range(B):
         for ip in range(n_p):
@@ -182,11 +184,10 @@ def test_paged_decode_kernel_post_rollback():
             valid = np.clip(int(lens[b]) - ip * pt, 0, pt)
             poison = poison.at[page, valid:].set(True)
     poison = poison.at[n_pool - 1].set(True)             # sink row too
-    kp = jnp.where(poison[:, None, :, None], 1e4, k_pool)
-    vp = jnp.where(poison[:, None, :, None], -1e4, v_pool)
-    o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret")
+    kp = jnp.where(poison[:, :, None], 1e4, k_pool)
+    vp = jnp.where(poison[:, :, None], -1e4, v_pool)
+    o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, **geo)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
-    del flat_pos
 
 
 # ---------------------------------------------------------------------------
