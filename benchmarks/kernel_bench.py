@@ -106,15 +106,10 @@ def run(verbose: bool = True):
         np.asarray(ref.attention_ref(qi, ki, vi, causal=True)),
         atol=2e-4))
     lens = jnp.array([Si // 2], jnp.int32)
-    # decode reads head-major caches (B, KV, T, r)
+    # the decode oracle reads head-major per-slot K/V (B, KV, T, r)
     kh, vh = ki.transpose(0, 2, 1, 3), vi.transpose(0, 2, 1, 3)
-    dec = ops.decode_attention(qi[:, 0], kh, vh, lens, impl="interpret")
-    dec_ok = bool(np.allclose(
-        np.asarray(dec),
-        np.asarray(ref.decode_attention_ref(qi[:, 0], kh, vh, lens)),
-        atol=2e-4))
-    # paged decode: same query against the same cache re-laid-out as a
-    # shuffled page pool + page table must agree with the dense oracle
+    # paged decode: the same K/V laid out as a shuffled page pool +
+    # page table must agree with the per-slot oracle
     pt = 8
     n_p = Si // pt
     perm = np.random.default_rng(0).permutation(n_p)
@@ -157,7 +152,6 @@ def run(verbose: bool = True):
         "cache_bytes_linear": abs(rows[5][2] / rows[4][2] - 0.75) < 0.05,
         # Pallas kernels in interpret mode reproduce the jnp oracles
         "interpret_flash_matches_ref": flash_ok,
-        "interpret_decode_matches_ref": dec_ok,
         "interpret_paged_decode_matches_ref": paged_ok,
         "interpret_page_copy_matches_ref": copy_ok,
     }

@@ -1,24 +1,22 @@
-"""Serving throughput/latency under chunked-prefill continuous batching,
-dense AND paged KV caches, self-speculative decoding, and copy-on-write
+"""Serving throughput/latency under chunked-prefill continuous batching
+over the paged KV cache, self-speculative decoding, and copy-on-write
 prefix caching.
 
 Nine scenarios connect the paper's rank pruning and fine-tuning story
 to the serving path:
 
 1. **Mixed trace** — a Poisson arrival trace of mixed-length prompts is
-   played against the dense and the paged engine at several CLOVER
-   prune ratios, measuring tokens/sec, p50/p95 inter-token latency and
-   time-to-first-token for both.  The paged engine must reproduce the
-   dense engine's greedy streams token-for-token.
+   played against the paged engine at several CLOVER prune ratios,
+   measuring tokens/sec, p50/p95 inter-token latency and
+   time-to-first-token.  Streams must match their isolated greedy
+   references.
 
 2. **Memory pressure** — a burst of long prompts at a fixed KV HBM
-   budget.  The dense engine can hold ``budget / max_len`` slots no
-   matter how short sequences actually are; the paged engine holds
-   ``budget / bytes_per_page`` pages and admits by ACTUAL length, so it
-   must sustain strictly more concurrent sequences at the same budget.
-   And because pruning shrinks bytes-per-token, the same byte budget
-   holds more pages at prune ratio 0.5 than at 0.0 — rank pruning
-   converts directly into concurrency (the tentpole claim).
+   budget.  The engine holds ``budget / bytes_per_page`` pages and
+   admits by ACTUAL length; because pruning shrinks bytes-per-token,
+   the same byte budget holds more pages at prune ratio 0.5 than at
+   0.0 — rank pruning converts directly into concurrency (the
+   tentpole claim).
 
 3. **Self-speculative decoding** — the same mixed trace replayed at
    ``spec_k`` in {0, 2, 4}: every pure-decode step, a rank-sliced DRAFT
@@ -97,7 +95,7 @@ to the serving path:
    fine-tuning half served) — a mixed-tenant trace (three waves of a
    shared system prompt + unique tails, tenants interleaved across the
    identity adapter and two fine-tuned SV-adapter trees in one
-   ``core.peft.AdapterRegistry``) replayed across {dense, paged,
+   ``core.peft.AdapterRegistry``) replayed across {paged,
    paged+prefix} x spec_k {0, 2} x tp {1, 2}.  Gated: every request's
    stream token-identical to a single-adapter replay of its own
    adapter (identity requests replay against the BASE params, so
@@ -129,17 +127,15 @@ to the serving path:
    ``SERVE_BENCH_SCENARIO=budget`` runs ONLY this scenario.
 
 What must hold on CPU (timings vary, orderings don't):
-  * both engines compile exactly TWO step shapes each over the whole
-    mixed-length trace (the two-shape contract survives paging), plus
-    at most one draft + one verify shape when speculation is on (and
-    one page-copy shape once a COW fires);
-  * greedy streams match their isolated full-prefill references, paged
-    matches dense exactly (preemptions included), every speculative
-    stream is token-identical to its non-speculative counterpart in
-    BOTH layouts, and every prefix-cached warm stream is token-
+  * the engine compiles exactly TWO step shapes over the whole
+    mixed-length trace, plus at most one draft + one verify shape when
+    speculation is on (and one page-copy shape once a COW fires);
+  * greedy streams match their isolated full-prefill references, every
+    speculative stream is token-identical to its non-speculative
+    counterpart, and every prefix-cached warm stream is token-
     identical to the cold paged engine's;
-  * the paged engine's max concurrency strictly exceeds the dense
-    engine's at equal HBM budget, and grows again at prune 0.5;
+  * the engine's max concurrency at a fixed HBM budget grows at prune
+    0.5;
   * prefix-hit TTFT < 0.5x the cold engine's, and burst concurrency at
     the fixed pool budget strictly exceeds the no-sharing engine's.
 
@@ -201,7 +197,7 @@ MAX_LEN = 64
 SPEC_KS = (0, 2, 4)            # draft tokens per speculative round
 DRAFT_RATIO = 0.5              # draft slices half of every CURRENT rank
 # memory-pressure scenario: KV HBM budget expressed in UNPRUNED tokens
-# (= a dense 2-slot x max_len allocation at prune 0.0)
+# (2 x max_len at prune 0.0)
 PRESSURE_BUDGET_TOKENS = 2 * MAX_LEN
 PRESSURE_REQUESTS = 10
 # prefix-cache scenario: a 40-token system prompt (5 full pages) shared
@@ -486,7 +482,7 @@ def _chaos_run(params, cfg, specs, arrivals, cancels,
     are re-verified after EVERY step; a violation is recorded as a
     failed check instead of crashing the whole benchmark module."""
     ecfg = EngineConfig(slots=3, max_len=MAX_LEN, prefill_chunk=CHUNK,
-                        paged=True, page_tokens=PAGE_TOKENS,
+                        page_tokens=PAGE_TOKENS,
                         n_pages=CHAOS_POOL_PAGES, step_retries=1,
                         quarantine_steps=2, watchdog_steps=32)
     eng = Engine(params, cfg, ecfg, faults=faults)
@@ -643,7 +639,7 @@ def _scenario_adapters(params0, cfg0, rows, checks, metrics):
     """Scenario 8 (DESIGN.md §13): multi-tenant SV-adapter serving —
     the paper's fine-tuning half behind the same engine.  One registry
     (identity + two fine-tuned tenants), one mixed-tenant trace,
-    replayed across {dense, paged, paged+prefix} x spec_k {0, 2} x
+    replayed across {paged, paged+prefix} x spec_k {0, 2} x
     tp {1, 2}; every stream gated against its own single-adapter
     replay, compiled shapes against the adapter-free engine, and the
     prefix trie against cross-tenant aliasing."""
@@ -693,14 +689,12 @@ def _scenario_adapters(params0, cfg0, rows, checks, metrics):
         refs.update({r.uid: r.generated for r in rs})
 
     base_cfgs = {
-        "dense": EngineConfig(slots=len(aids), max_len=MAX_LEN,
-                              prefill_chunk=CHUNK),
         "paged": EngineConfig(slots=len(aids), max_len=MAX_LEN,
-                              prefill_chunk=CHUNK, paged=True,
+                              prefill_chunk=CHUNK,
                               page_tokens=PAGE_TOKENS,
                               n_pages=ADAPTER_POOL_PAGES),
         "paged_prefix": EngineConfig(slots=len(aids), max_len=MAX_LEN,
-                                     prefill_chunk=CHUNK, paged=True,
+                                     prefill_chunk=CHUNK,
                                      page_tokens=PAGE_TOKENS,
                                      n_pages=ADAPTER_POOL_PAGES,
                                      prefix_cache=True),
@@ -757,7 +751,7 @@ def _scenario_adapters(params0, cfg0, rows, checks, metrics):
                         r.cached_tokens > 0 for r in reqs
                         if arrivals[r.uid] > 0)
                 else:
-                    # dense/paged: identical scheduling with and
+                    # paged: identical scheduling with and
                     # without the registry -> the jit caches must end
                     # the trace the same size (the gather is traced
                     # data, not shape)
@@ -870,7 +864,7 @@ def _scenario_budget(params0, cfg0, rows, checks, metrics):
     rng = np.random.default_rng(3)
     trace = _poisson_trace(rng, N_REQUESTS, cfg0.vocab_size)
     uni_cfg = EngineConfig(slots=4, max_len=MAX_LEN, prefill_chunk=CHUNK,
-                           paged=True, page_tokens=PAGE_TOKENS,
+                           page_tokens=PAGE_TOKENS,
                            kernel_impl="interpret")
     bud_cfg = dataclasses.replace(uni_cfg, rank_budget=star)
     eng_u, reqs_u, m_u = _serve_trace(params_u, cfg_u, trace, uni_cfg)
@@ -1006,112 +1000,78 @@ def run(verbose: bool = True):
         params, cfg = clover_prune(dp, dcfg, qk_ratio=ratio, vo_ratio=ratio)
         tag = f"prune{ratio:.2f}"
 
-        # -- mixed trace: dense vs paged, identical streams ------------
-        dense_cfg = EngineConfig(slots=4, max_len=MAX_LEN,
-                                 prefill_chunk=CHUNK)
+        # -- mixed trace ---------------------------------------------
         paged_cfg = EngineConfig(slots=4, max_len=MAX_LEN,
-                                 prefill_chunk=CHUNK, paged=True,
+                                 prefill_chunk=CHUNK,
                                  page_tokens=PAGE_TOKENS)
-        eng_d, reqs_d, m_d = _serve_trace(params, cfg, trace, dense_cfg)
         eng_p, reqs_p, m_p = _serve_trace(params, cfg, trace, paged_cfg)
-        metrics[tag] = {"dense": m_d, "paged": m_p,
-                        "qk_rank": cfg.clover.qk_rank}
-        for mode, m in (("dense", m_d), ("paged", m_p)):
-            for k, v in m.items():
-                rows.append((f"{tag}_{mode}", k, v))
+        metrics[tag] = {"paged": m_p, "qk_rank": cfg.clover.qk_rank}
+        for k, v in m_p.items():
+            rows.append((f"{tag}_paged", k, v))
         rows.append((tag, "qk_rank", cfg.clover.qk_rank))
 
         # None = jit cache not introspectable (private API drift) —
         # soft-pass rather than failing CI with no real regression
-        checks[f"{tag}_two_compiled_shapes"] = (
-            eng_d.compiled_shapes() in (2, None))
         checks[f"{tag}_paged_two_compiled_shapes"] = (
             eng_p.compiled_shapes() in (2, None))
-        # the paged engine reproduces the dense engine token-for-token
-        checks[f"{tag}_paged_matches_dense"] = all(
-            p.generated == d.generated for p, d in zip(reqs_p, reqs_d))
         # chunked prefill is exact: spot-check 3 streams (covering both
         # multi-chunk and sub-chunk prompts) against isolated references
         ok = all(r.generated == greedy_reference(
                      params, cfg, r.prompt, r.max_new_tokens)
-                 for r in reqs_d[:3])
+                 for r in reqs_p[:3])
         checks[f"{tag}_greedy_matches_reference"] = ok
         if ratio > 0:
             checks[f"{tag}_kv_rank_reduced"] = (
                 cfg.clover.qk_rank < cfg0.head_dim_)
 
         # -- self-speculative decoding sweep (DESIGN.md §8) ------------
-        # k=0 is the non-speculative dense/paged run above; every k > 0
-        # must reproduce those streams token-for-token while emitting
+        # k=0 is the non-speculative run above; every k > 0 must
+        # reproduce those streams token-for-token while emitting
         # accepted-tokens-per-step > 1 where drafts are good
-        spec = {"k0": {"dense_tokens_per_step": m_d["tokens_per_step"],
-                       "paged_tokens_per_step": m_p["tokens_per_step"]}}
+        spec = {"k0": {"paged_tokens_per_step": m_p["tokens_per_step"]}}
         for kk in [k for k in SPEC_KS if k > 0]:
-            eng_sd, reqs_sd, m_sd = _serve_trace(
-                params, cfg, trace,
-                dataclasses.replace(dense_cfg, spec_k=kk,
-                                    draft_rank_ratio=DRAFT_RATIO))
             eng_sp, reqs_sp, m_sp = _serve_trace(
                 params, cfg, trace,
                 dataclasses.replace(paged_cfg, spec_k=kk,
                                     draft_rank_ratio=DRAFT_RATIO))
             spec[f"k{kk}"] = {
-                "dense_tokens_per_step": m_sd["tokens_per_step"],
                 "paged_tokens_per_step": m_sp["tokens_per_step"],
-                "dense_tokens_per_s_wall": m_sd["tokens_per_s_wall"],
                 "paged_tokens_per_s_wall": m_sp["tokens_per_s_wall"],
-                "accepted_per_round": round(eng_sd.accepted_per_round, 3),
+                "accepted_per_round": round(eng_sp.accepted_per_round, 3),
                 "accept_hist": {str(a): c for a, c in
-                                sorted(eng_sd.accept_hist.items())},
+                                sorted(eng_sp.accept_hist.items())},
             }
             for kname, val in spec[f"k{kk}"].items():
                 if kname != "accept_hist":
                     rows.append((f"{tag}_spec_k{kk}", kname, val))
             # the speculative path changes WHEN tokens are computed,
-            # never WHICH tokens come out — both layouts
-            checks[f"{tag}_spec_k{kk}_dense_matches_nonspec"] = all(
-                s.generated == d.generated
-                for s, d in zip(reqs_sd, reqs_d))
+            # never WHICH tokens come out
             checks[f"{tag}_spec_k{kk}_paged_matches_nonspec"] = all(
                 s.generated == p.generated
                 for s, p in zip(reqs_sp, reqs_p))
             # 2 base shapes + 1 draft + 1 verify at most (pure-decode
             # steps may be entirely replaced by speculative rounds)
             checks[f"{tag}_spec_k{kk}_shapes_fixed"] = (
-                eng_sd.compiled_shapes() in (3, 4, None)
-                and eng_sp.compiled_shapes() in (3, 4, None))
+                eng_sp.compiled_shapes() in (3, 4, None))
         metrics[f"spec_{tag}"] = spec
         spec_accept[ratio] = spec[f"k{max(SPEC_KS)}"]["accepted_per_round"]
 
         # -- memory pressure at a fixed HBM budget ---------------------
         # pruning shrinks bytes/token, so the SAME byte budget holds
-        # more tokens (hence pages / dense slots) at higher prune ratio
+        # more tokens (hence pages) at higher prune ratio
         budget_tokens = int(PRESSURE_BUDGET_TOKENS
                             * _kv_tokens_per_unpruned_token(cfg0, cfg))
-        dense_slots = max(1, budget_tokens // MAX_LEN)
         n_pages = budget_tokens // PAGE_TOKENS
-        press_dense = EngineConfig(slots=dense_slots, max_len=MAX_LEN,
-                                   prefill_chunk=CHUNK)
         press_paged = EngineConfig(slots=PRESSURE_REQUESTS, max_len=MAX_LEN,
-                                   prefill_chunk=CHUNK, paged=True,
+                                   prefill_chunk=CHUNK,
                                    page_tokens=PAGE_TOKENS, n_pages=n_pages)
-        eng_pd, reqs_pd, m_pd = _serve_trace(params, cfg, pressure,
-                                             press_dense)
-        eng_pp, reqs_pp, m_pp = _serve_trace(params, cfg, pressure,
-                                             press_paged)
+        _, _, m_pp = _serve_trace(params, cfg, pressure, press_paged)
         metrics[f"pressure_{tag}"] = {
-            "budget_tokens": budget_tokens, "dense_slots": dense_slots,
-            "n_pages": n_pages, "dense": m_pd, "paged": m_pp}
-        for mode, m in (("dense", m_pd), ("paged", m_pp)):
-            for k, v in m.items():
-                rows.append((f"pressure_{tag}_{mode}", k, v))
+            "budget_tokens": budget_tokens, "n_pages": n_pages,
+            "paged": m_pp}
+        for k, v in m_pp.items():
+            rows.append((f"pressure_{tag}_paged", k, v))
         pressure_concurrency[ratio] = m_pp["max_concurrent"]
-        # acceptance (a): at equal HBM budget, paging admits STRICTLY
-        # more concurrent sequences than slots x max_len dense
-        checks[f"pressure_{tag}_paged_more_concurrent"] = (
-            m_pp["max_concurrent"] > m_pd["max_concurrent"])
-        checks[f"pressure_{tag}_paged_matches_dense"] = all(
-            p.generated == d.generated for p, d in zip(reqs_pp, reqs_pd))
 
         # -- shared-system-prompt warm replay (DESIGN.md §9) -----------
         # same page budget, same trace: prefix caching must (a) keep
@@ -1126,7 +1086,7 @@ def run(verbose: bool = True):
         for kk in PREFIX_SPEC_KS:
             cold_cfg = EngineConfig(
                 slots=PREFIX_BURST, max_len=MAX_LEN, prefill_chunk=CHUNK,
-                paged=True, page_tokens=PAGE_TOKENS,
+                page_tokens=PAGE_TOKENS,
                 n_pages=PREFIX_POOL_PAGES, spec_k=kk,
                 draft_rank_ratio=DRAFT_RATIO)
             warm_cfg = dataclasses.replace(cold_cfg, prefix_cache=True)
@@ -1154,7 +1114,7 @@ def run(verbose: bool = True):
         # and without the host tier under the trie
         host_cold_cfg = EngineConfig(
             slots=PREFIX_BURST, max_len=MAX_LEN, prefill_chunk=CHUNK,
-            paged=True, page_tokens=PAGE_TOKENS,
+            page_tokens=PAGE_TOKENS,
             n_pages=PREFIX_POOL_PAGES, prefix_cache=True)
         host_warm_cfg = dataclasses.replace(host_cold_cfg,
                                             host_pages=HOST_PAGES)

@@ -287,8 +287,8 @@ def one_chip(seed: int, log: CompileLog, *, arch: str = "gpt2-xl",
     print(f"[traffic] {len(requests)} requests, prompt tokens "
           f"{[len(r.prompt) for r in requests]}, new tokens "
           f"{[r.max_new_tokens for r in requests]}")
-    ecfg = EngineConfig(slots=slots, max_len=max_len, paged=True,
-                        page_tokens=16, n_pages=n_pages, prefix_cache=True,
+    ecfg = EngineConfig(slots=slots, max_len=max_len, page_tokens=16,
+                        n_pages=n_pages, prefix_cache=True,
                         kernel_impl=impl)
     runs = {}
     for name in (impl, "xla"):
@@ -369,7 +369,7 @@ def four_chips(seed: int, log: CompileLog, *, arch: str = "phi3-medium-14b",
                              new_range, page_tokens=16)
     # fewer slots than requests: the last one (a repeat of the first)
     # is admitted after the first has published its pages
-    ecfg = EngineConfig(slots=4, max_len=512, paged=True, page_tokens=16,
+    ecfg = EngineConfig(slots=4, max_len=512, page_tokens=16,
                         prefix_cache=True, kernel_impl=impl, tp=tp)
     before = log.snapshot()
     eng, out = serve(params, cfg, ecfg, requests)

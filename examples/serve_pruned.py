@@ -6,9 +6,9 @@ halves), then serves a mixed batch of requests with different prompt
 lengths and arrival times.  Prompts are consumed in fixed-size chunks
 interleaved with decoding, so the whole mixed-length batch compiles
 exactly two step shapes; each stream is verified against its isolated
-greedy reference.  The same trace is then replayed on the PAGED engine
-(global page pool + page tables, admission gated on free pages,
-preemption on exhaustion) and must produce identical streams — and,
+greedy reference.  The same trace is then replayed against an
+UNDERSIZED page pool (admission gated on free pages, preemption on
+exhaustion) and must produce identical streams — and,
 with ``--spec-k`` > 0, replayed once more with SELF-SPECULATIVE
 decoding (a rank-sliced draft of the same weights proposes tokens, one
 multi-token verify step commits a greedy prefix; DESIGN.md §8), again
@@ -159,16 +159,16 @@ def main():
     print(f"request 0: engine={r.generated}")
     print(f"           ref   ={ref}  match={r.generated == ref}")
 
-    # replay on the paged engine: undersized pool -> page-gated
-    # admission + preemption, identical streams
+    # replay on an undersized pool -> page-gated admission +
+    # preemption, identical streams
     ep = Engine(pparams, pcfg, EngineConfig(slots=4, max_len=96,
-                                            prefill_chunk=8, paged=True,
+                                            prefill_chunk=8,
                                             page_tokens=8, n_pages=8))
     reqs_p = [Request(uid=r.uid, prompt=r.prompt,
                       max_new_tokens=r.max_new_tokens) for r in reqs]
     ep.run(reqs_p)
     match = all(a.generated == b.generated for a, b in zip(reqs, reqs_p))
-    print(f"paged replay: match={match} "
+    print(f"undersized-pool replay: match={match} "
           f"({ep.compiled_shapes()} compiled step shapes, "
           f"{ep.sched.preemptions} preemptions, "
           f"peak page util {ep.peak_page_util:.0%})")
@@ -198,7 +198,7 @@ def main():
               f"({pb['kept'] / pb['allocated']:.0%} of max-width pool)")
         eb = Engine(bparams, bcfg,
                     EngineConfig(slots=4, max_len=96, prefill_chunk=8,
-                                 paged=True, page_tokens=8,
+                                 page_tokens=8,
                                  kernel_impl=args.kernel_impl,
                                  rank_budget=plan))
         reqs_b = [Request(uid=r.uid, prompt=r.prompt,
@@ -247,7 +247,7 @@ def main():
             et = Engine(pparams, pcfg,
                         dataclasses.replace(
                             EngineConfig(slots=4, max_len=96,
-                                         prefill_chunk=8, paged=True,
+                                         prefill_chunk=8,
                                          page_tokens=8, n_pages=8),
                             tp=args.tp,
                             kernel_impl=args.kernel_impl))
@@ -277,7 +277,7 @@ def main():
     # shared prefill chunks, and must match the cold pass exactly
     epc = Engine(pparams, pcfg,
                  EngineConfig(slots=4, max_len=96, prefill_chunk=8,
-                              paged=True, page_tokens=8,
+                              page_tokens=8,
                               prefix_cache=True))
     sys_prompt = rng.integers(0, cfg.vocab_size, 32).astype(np.int32)
     shared = [np.concatenate(
@@ -305,7 +305,7 @@ def main():
     if args.host_pages > 0:
         eh = Engine(pparams, pcfg,
                     EngineConfig(slots=4, max_len=96, prefill_chunk=8,
-                                 paged=True, page_tokens=8,
+                                 page_tokens=8,
                                  prefix_cache=True,
                                  host_pages=args.host_pages))
         cold_h = [Request(uid=i, prompt=p, max_new_tokens=8)
@@ -341,7 +341,7 @@ def main():
                 for entry in reg.get(0)))
         ea = Engine(dp2, dcfg2,
                     EngineConfig(slots=4, max_len=96, prefill_chunk=8,
-                                 paged=True, page_tokens=8,
+                                 page_tokens=8,
                                  prefix_cache=True),
                     adapters=reg)
         sys_a = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
@@ -376,7 +376,7 @@ def main():
               if args.chaos_seed is not None else None)
     eo = Engine(pparams, pcfg,
                 EngineConfig(slots=2, max_len=96, prefill_chunk=8,
-                             paged=True, page_tokens=8, n_pages=8,
+                             page_tokens=8, n_pages=8,
                              step_retries=1, quarantine_steps=2,
                              watchdog_steps=32),
                 faults=faults)

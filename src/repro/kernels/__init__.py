@@ -5,7 +5,7 @@ One module per kernel, each with a pure-jnp oracle in ``ref.py`` and a
 public dispatch surface in ``ops.py`` (``resolve(impl, mesh=None)`` —
 §10's per-shard execution).  Kernels exist ONLY for compute hot-spots
 the paper's inference story actually optimizes: asymmetric flash
-attention and (paged) flash decoding over rank-pruned caches, the
+attention and paged flash decoding over rank-pruned caches, the
 recurrent mixers' scans, and the page-copy/page-restore row movers
 behind prefix caching and the host spill tier.
 """

@@ -11,17 +11,15 @@ resolved once per (impl alias, platform, mesh):
   lowering — it never silently becomes the interpreter),
   ``"interpret"`` (Pallas kernels in interpret mode — the CPU
   validation path, an explicit choice).
-* Layout: dense KV caches are HEAD-MAJOR ``(B, KV, T, r)`` and the
-  dense decode kernels take queries as ``(B, KV, G, d)``; these entry
-  points accept the model's ``(B, H, d)`` queries and reshape them
-  (free: H is KV groups of G consecutive heads).  Page pools are
-  TOKEN-MAJOR and LANE-DENSE ``(N, page_tokens, lanes)``, every KV head
-  of a token side by side in one row (``ref.page_lanes``), so the paged
-  entry points take the pool's ``kv_heads`` and ``head_widths``.
+* Layout: page pools are TOKEN-MAJOR and LANE-DENSE ``(N, page_tokens,
+  lanes)``, every KV head of a token side by side in one row
+  (``ref.page_lanes``), so the paged entry points take the model's
+  ``(B, H, d)`` queries with the pool's ``kv_heads`` and
+  ``head_widths``.
   Full-sequence attention keeps token-major activations at this API and
   transposes them for the kernel.
-* ``mesh``: when set, the serving hot-path kernels (flash-decode,
-  paged-decode, page-copy, full-sequence attention) run PER SHARD
+* ``mesh``: when set, the serving hot-path kernels (paged-decode,
+  page-copy, full-sequence attention) run PER SHARD
   under ``shard_map`` with serve-rules operand specs (slot batch over
   "data", KV heads over "model" — ``parallel.sharding.kernel_axes``).
   Per-(slot, kv-head) results are independent, so the sharded
@@ -50,8 +48,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ref as _ref
 from repro.kernels.clover_attention import flash_attention as _flash
-from repro.kernels.decode_attention import (
-    flash_decode as _decode, flash_decode_ranked as _decode_ranked)
 from repro.kernels.paged_decode_attention import (
     paged_flash_decode as _paged_decode)
 from repro.kernels.wkv6 import wkv6 as _wkv6
@@ -75,17 +71,6 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int,
 # every padded/blocked axis — seq, pages, rank — is unsharded)
 # ---------------------------------------------------------------------------
 
-def _group(q, kv_heads: int):
-    """(B, H, d) queries -> the kernels' (B, KV, G, d)."""
-    B, H, d = q.shape
-    return q.reshape(B, kv_heads, H // kv_heads, d)
-
-
-def _ungroup(out):
-    B, KV, G, d = out.shape
-    return out.reshape(B, KV * G, d)
-
-
 def _clover_body(q, k, v, *, causal, scale, block_q, block_k, interpret):
     S, T = q.shape[1], k.shape[1]
     bq = min(block_q, max(8, S))
@@ -100,39 +85,6 @@ def _clover_body(q, k, v, *, causal, scale, block_q, block_k, interpret):
     out = _flash(qp, kp, vp, causal=causal, scale=scale, block_q=bq,
                  block_k=bk, interpret=interpret)
     return out.transpose(0, 2, 1, 3)[:, :S]
-
-
-def _decode_body(q, k, v, lengths, *, scale, block_t, interpret):
-    T = k.shape[2]
-    bt = min(block_t, max(8, T))
-    kp = _pad_to(k, 2, bt)
-    vp = _pad_to(v, 2, bt)
-    return _ungroup(_decode(_group(q, k.shape[1]), kp, vp, lengths,
-                            scale=scale, block_t=bt, interpret=interpret))
-
-
-def _rank_block(rank_block: int, dq: int, dv: int) -> int:
-    """The rank block the dense ranked kernel walks: ``rank_block`` (a lane
-    multiple) for wide heads, else the whole width — the only block
-    widths the TPU lowering accepts."""
-    return min(rank_block, max(8, max(dq, dv)))
-
-
-def _decode_ranked_body(q, k, v, lengths, qk_ranks, vo_ranks, *,
-                        scale, block_t, rank_block, interpret):
-    # Rank-dim zero-padding to block multiples is exact under the
-    # mask_head_ranks convention (zeroed dims contribute exactly 0).
-    T, dq, dv = k.shape[2], q.shape[-1], v.shape[-1]
-    bt = min(block_t, max(8, T))
-    rb = _rank_block(rank_block, dq, dv)
-    if scale is None:
-        scale = float(1.0 / (dq ** 0.5))
-    out = _decode_ranked(
-        _group(_pad_to(q, -1, rb), k.shape[1]),
-        _pad_to(_pad_to(k, 2, bt), -1, rb),
-        _pad_to(_pad_to(v, 2, bt), -1, rb), lengths, qk_ranks, vo_ranks,
-        scale=scale, block_t=bt, rank_block=rb, interpret=interpret)
-    return _ungroup(out)[..., :dv]
 
 
 # ---------------------------------------------------------------------------
@@ -206,53 +158,6 @@ class KernelDispatch:
                                    P(b, None, m, None)),
                          out_specs=P(b, None, m, None))
         return fn(q, k, v)
-
-    def decode_attention(self, q, k, v, lengths, *,
-                         scale: Optional[float] = None,
-                         block_t: int = 256,
-                         qk_ranks: Optional[jnp.ndarray] = None,
-                         vo_ranks: Optional[jnp.ndarray] = None,
-                         rank_block: int = 128) -> jnp.ndarray:
-        """Flash-decoding vs a (possibly CLOVER-rank) KV cache.
-
-        q (B,H,dq), k (B,KV,T,dq), v (B,KV,T,dv), lengths (B,)
-        -> (B,H,dv).  With ``qk_ranks``/``vo_ranks`` ((KV,) int32,
-        both or neither) the per-head rank-clamped kernel runs instead
-        (non-uniform ``RankBudget`` plans, DESIGN.md §14); under a
-        mesh the rank vectors shard along KV heads with the caches.
-        """
-        ranked = qk_ranks is not None or vo_ranks is not None
-        if not self.kernel_path:
-            return _ref.decode_attention_ref(q, k, v, lengths, scale=scale,
-                                             qk_ranks=qk_ranks,
-                                             vo_ranks=vo_ranks)
-        b, m = self._axes(batch=q.shape[0], kv_heads=k.shape[1])
-        if ranked:
-            dq, dv = q.shape[-1], v.shape[-1]
-            qk_ranks = (jnp.full((k.shape[1],), dq, jnp.int32)
-                        if qk_ranks is None else qk_ranks.astype(jnp.int32))
-            vo_ranks = (jnp.full((k.shape[1],), dv, jnp.int32)
-                        if vo_ranks is None else vo_ranks.astype(jnp.int32))
-            body = functools.partial(_decode_ranked_body, scale=scale,
-                                     block_t=block_t, rank_block=rank_block,
-                                     interpret=self.interpret)
-            if b is None and m is None:
-                return body(q, k, v, lengths, qk_ranks, vo_ranks)
-            fn = self._shard(body,
-                             in_specs=(P(b, m, None), P(b, m, None, None),
-                                       P(b, m, None, None), P(b), P(m),
-                                       P(m)),
-                             out_specs=P(b, m, None))
-            return fn(q, k, v, lengths, qk_ranks, vo_ranks)
-        body = functools.partial(_decode_body, scale=scale, block_t=block_t,
-                                 interpret=self.interpret)
-        if b is None and m is None:
-            return body(q, k, v, lengths)
-        fn = self._shard(body,
-                         in_specs=(P(b, m, None), P(b, m, None, None),
-                                   P(b, m, None, None), P(b)),
-                         out_specs=P(b, m, None))
-        return fn(q, k, v, lengths)
 
     def paged_decode_attention(self, q, k_pool, v_pool, page_table,
                                lengths, *, kv_heads: int,
@@ -459,25 +364,6 @@ def clover_attention(q, k, v, *, causal: bool = True,
     return resolve(impl).clover_attention(q, k, v, causal=causal,
                                           scale=scale, block_q=block_q,
                                           block_k=block_k)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("scale", "block_t", "rank_block", "impl"))
-def decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
-                     block_t: int = 256, qk_ranks=None, vo_ranks=None,
-                     rank_block: int = 128,
-                     impl: str = "ref") -> jnp.ndarray:
-    """Flash-decoding vs a (possibly CLOVER-rank) KV cache.
-
-    q (B,H,dq), k (B,KV,T,dq), v (B,KV,T,dv), lengths (B,) -> (B,H,dv).
-    qk_ranks / vo_ranks: optional (KV,) int32 per-head kept ranks
-    (non-uniform ``RankBudget`` plans, DESIGN.md §14).
-    """
-    return resolve(impl).decode_attention(q, k, v, lengths, scale=scale,
-                                          block_t=block_t,
-                                          qk_ranks=qk_ranks,
-                                          vo_ranks=vo_ranks,
-                                          rank_block=rank_block)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_heads", "head_widths",

@@ -1,12 +1,11 @@
 """Paged flash-decoding: one new token's query against a PAGED
 CLOVER-rank KV cache (vLLM-style page pool + per-slot page tables).
 
-The dense `flash_decode` streams a per-slot cache of shape
-``(B, KV, capacity, r)`` — every slot reserves (and, before the
-index-map clamp, streamed) full capacity regardless of actual length.
-Here the cache is one global pool ``(n_pages + 1, page_tokens, L)``
-shared by all slots; each slot owns an ordered list of page ids (its
-page table row) and positions map through the indirection
+Instead of a per-slot cache ``(B, KV, capacity, r)`` that reserves full
+capacity for every slot regardless of actual length, the cache is one
+global pool ``(n_pages + 1, page_tokens, L)`` shared by all slots; each
+slot owns an ordered list of page ids (its page table row) and
+positions map through the indirection
 ``pool[table[b, p // page_tokens], p % page_tokens]``.  The pool is
 TOKEN-MAJOR and LANE-DENSE: a page row holds every KV head of one token
 side by side, ``r`` lanes a head, the row rounded up to a multiple of

@@ -2,11 +2,13 @@
 behind DESIGN.md §4's kernels and the §9/§12 page movers).
 
 These are the semantics the kernels must reproduce bit-approximately;
-tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.  Dense KV
-caches are head-major ``(B, KV, T, r)``.  Page pools are token-major and
-lane-dense ``(N, page_tokens, lanes)``: a page row holds every KV head
-of one token side by side (``page_lanes`` / ``heads_to_lanes`` /
-``lanes_to_heads`` below are the one definition of that order).
+tests sweep shapes/dtypes and assert_allclose kernel-vs-ref.  The
+decode oracles read head-major per-slot ``(B, KV, T, r)`` K/V; the
+paged oracle gathers a page pool into that form first.  Page pools are
+token-major and lane-dense ``(N, page_tokens, lanes)``: a page row
+holds every KV head of one token side by side (``page_lanes`` /
+``heads_to_lanes`` / ``lanes_to_heads`` below are the one definition of
+that order).
 """
 from __future__ import annotations
 
@@ -78,11 +80,7 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 def _rank_mask(x: jnp.ndarray, ranks: Optional[jnp.ndarray]) -> jnp.ndarray:
     """Zero dims >= ranks[kv] of a (B, KV, T, d) cache — the exact-
     truncation oracle semantics of a non-uniform per-head rank plan
-    (DESIGN.md §14).  The paged kernel masks the same lanes exactly;
-    the dense rank-clamped kernel skips whole ``rank_block``-wide
-    blocks instead, which agrees whenever every rank is a block
-    multiple OR the data already obeys the ``mask_head_ranks`` zero-pad
-    convention (zeroed dims contribute exactly 0 either way)."""
+    (DESIGN.md §14).  The paged kernel masks the same lanes exactly."""
     if ranks is None:
         return x
     d = x.shape[-1]
@@ -165,8 +163,8 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
                                vo_ranks: Optional[jnp.ndarray] = None,
                                ) -> jnp.ndarray:
     """Paged flash-decoding oracle: gather each slot's pages into a
-    dense per-slot cache through the page-table indirection, then defer
-    to the dense oracle (lengths mask everything past each slot's valid
+    per-slot cache through the page-table indirection, then defer to
+    ``decode_attention_ref`` (lengths mask everything past each slot's valid
     tokens, so sentinel/garbage pages never influence the output).
 
     q: (B, H, dq);  k_pool: (N, page_tokens, Lk);  v_pool: (N,

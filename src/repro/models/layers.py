@@ -223,16 +223,18 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
     """GQA attention.
 
     Full-sequence (train/prefill): ``kv_cache is None`` -> causal mask.
-    Decode: ``kv_cache`` holds {"k": (B, KV, Smax, dq), "v": (B, KV, Smax,
-    dv)} and ``cache_index`` is the write position (scalar int32); x has
-    S==1.  Caches are HEAD-MAJOR so the decode kernels stream
-    (tokens, rank) blocks whole (DESIGN.md §4).
+    Lockstep cache (``transformer.prefill`` / ``decode_step`` with one
+    position for the whole batch): ``kv_cache`` holds {"k": (B, KV,
+    Smax, dq), "v": (B, KV, Smax, dv)}, head-major, and ``cache_index``
+    is the scalar int32 write position; reads take the einsum or the
+    chunked-flash path.
 
-    Paged decode: ``page_table`` (B, n_p) int32 is given and ``kv_cache``
-    holds the global pools {"k": (N, page_tokens, Lk), "v": (N,
-    page_tokens, Lv)} shared by all slots, token-major and lane-dense
-    (one token's KV heads side by side, ``kernels.ref.page_lanes``);
-    ``cache_index`` must be the (B,) per-slot vector.  Position p of
+    Paged (the serving engine's cache): ``page_table`` (B, n_p) int32
+    is given and ``kv_cache`` holds the global pools {"k": (N,
+    page_tokens, Lk), "v": (N, page_tokens, Lv)} shared by all slots,
+    token-major and lane-dense (one token's KV heads side by side,
+    ``kernels.ref.page_lanes``); ``cache_index`` must be the (B,)
+    per-slot vector.  Position p of
     slot b lives at ``pool[page_table[b, p // page_tokens], p %
     page_tokens]``.  The table must cover positions [0, cache_index +
     S) per slot — entries
@@ -335,8 +337,8 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
     # params; the transformer's lax.scan delivers this layer's (KV,)
     # rows here.  The weights are already zero-padded past each head's
     # kept rank (mask_head_ranks), so the einsum paths need nothing —
-    # the vectors only feed the decode kernels' per-head rank clamp,
-    # which turns the semantic zeros into skipped DMA + compute.
+    # the vectors only feed the paged decode kernel's per-head rank
+    # clamp.
     rank_qk = params.get("rank_qk")
     rank_vo = params.get("rank_vo")
     rank_kw = {}
@@ -355,8 +357,8 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
         # through sentinel entries to the pool's garbage row, so padded
         # windows and idle slots never corrupt other slots' pages;
         # garbage inside a slot's own last page sits beyond its causal
-        # horizon until the slot itself overwrites it — the same
-        # masked-or-overwritten invariant as the dense cache.
+        # horizon until the slot itself overwrites it (masked or
+        # overwritten, never read).
         from repro.kernels.ref import (gather_pages, heads_to_lanes,
                                        lanes_to_heads, page_lanes)
         from repro.parallel.sharding import kv_shards
@@ -423,37 +425,22 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             ctx = _masked(k, v, mask, kv_spec="btkq")
         return _vo_out(ctx), new_cache
     elif kv_cache is not None:
-        # cache_index: scalar (whole batch at one position — prefill and
-        # lockstep decode) or (B,) vector (per-slot positions — the
-        # serving engine's continuous batching; S may be > 1 for chunked
-        # prefill, writing an S-token window at each slot's own offset).
-        per_slot = jnp.ndim(cache_index) == 1
+        # lockstep cache: the whole batch writes its S-token window at
+        # the one scalar position ``cache_index``
+        assert jnp.ndim(cache_index) == 0, \
+            "a per-slot (B,) cache index needs a page table"
         # head-major window (B, KV, S, r), as the cache stores it
         kw = (_pad_rank(k, dq_c).astype(kv_cache["k"].dtype)
               .transpose(0, 2, 1, 3))
         vw = (_pad_rank(v, dv_c).astype(kv_cache["v"].dtype)
               .transpose(0, 2, 1, 3))
-        if per_slot:
-            upd = jax.vmap(
-                lambda c, kn, i: jax.lax.dynamic_update_slice_in_dim(
-                    c, kn, i, axis=1))
-            ck = upd(kv_cache["k"], kw, cache_index)
-            cv = upd(kv_cache["v"], vw, cache_index)
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["k"], kw, cache_index, axis=2)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["v"], vw, cache_index, axis=2)
+        ck = jax.lax.dynamic_update_slice_in_dim(
+            kv_cache["k"], kw, cache_index, axis=2)
+        cv = jax.lax.dynamic_update_slice_in_dim(
+            kv_cache["v"], vw, cache_index, axis=2)
         new_cache = {"k": ck, "v": cv}
-        if use_pallas and S == 1:  # flash-decoding against the cache
-            lengths = jnp.broadcast_to(cache_index + 1, (B,)).astype(jnp.int32)
-            ctx = dispatch.decode_attention(
-                q[:, 0], ck[..., :dq].astype(x.dtype),
-                cv[..., :dv].astype(x.dtype), lengths,
-                scale=scale, **rank_kw)[:, None]               # (B,1,H,dv)
-            return _vo_out(ctx), new_cache
         k, v = ck[..., :dq].astype(x.dtype), cv[..., :dv].astype(x.dtype)
-        if not per_slot and S > ATTN_CHUNK:
+        if S > ATTN_CHUNK:
             # long cached prefill: chunked flash path
             ctx = _causal_attention_chunked(
                 q, k, v, scale, softcap=cfg.attn_logit_softcap,
@@ -463,12 +450,11 @@ def attention(params: Params, cfg, x: jnp.ndarray, *,
             return _vo_out(ctx), new_cache
         T = k.shape[2]
         kv_pos = jnp.arange(T, dtype=jnp.int32)
-        ci = jnp.broadcast_to(jnp.atleast_1d(cache_index), (B,))
-        # query j of slot b sits at global position ci[b] + j; causal
-        # against every cached position (covers scalar AND per-slot
-        # offsets, S == 1 and chunked windows uniformly).
-        qpos = ci[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        mask = kv_pos[None, None, :] <= qpos[:, :, None]      # (B, S, T)
+        # query j sits at global position cache_index + j; causal
+        # against every cached position
+        qpos = cache_index + jnp.arange(S, dtype=jnp.int32)
+        mask = jnp.broadcast_to(kv_pos[None, :] <= qpos[:, None],
+                                (B, S, T))
     else:
         if use_pallas:  # full-sequence causal flash kernel
             ctx = dispatch.clover_attention(q, k, v, causal=True,

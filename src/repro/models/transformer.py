@@ -278,8 +278,8 @@ def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
                             page_tokens: int, shards: int = 1) -> Params:
     """Decode state whose KV caches are PAGED: one global pool
     ``(n_pages + 1, page_tokens, lanes)`` per attention layer and per K
-    and V (stacked over ``n_blocks``) instead of a dense per-slot
-    ``(batch, KV, max_len, r)``.  A page row is TOKEN-MAJOR and
+    and V (stacked over ``n_blocks``), the serving engine's one KV
+    layout.  A page row is TOKEN-MAJOR and
     LANE-DENSE: one token's KV heads side by side, ``r`` lanes each, the
     row rounded up to a multiple of 128 lanes, in ``shards`` blocks of
     heads, one per tensor-parallel shard (``kernels.ref.page_lanes``;
@@ -293,7 +293,7 @@ def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
     (serve.engine's ``PageAllocator``) and passed into each step
     alongside the state.
     """
-    dense = init_decode_state(cfg, batch, 1)   # non-KV leaves + layout
+    base = init_decode_state(cfg, batch, 1)    # non-KV leaves + layout
     kv_dtype = _dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
 
     def repage(path, leaf):
@@ -304,7 +304,7 @@ def init_decode_state_paged(cfg: ArchConfig, batch: int, n_pages: int,
         return jnp.zeros((cfg.n_blocks, n_pages + 1, page_tokens, lanes),
                          kv_dtype)
 
-    blocks = jax.tree_util.tree_map_with_path(repage, dense["blocks"])
+    blocks = jax.tree_util.tree_map_with_path(repage, base["blocks"])
     return {"blocks": blocks, "index": jnp.zeros((batch,), jnp.int32)}
 
 
@@ -365,7 +365,7 @@ def prefill(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
 
 def prefill_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                   state: Params, lengths: jnp.ndarray,
-                  pages: Optional[jnp.ndarray] = None,
+                  pages: jnp.ndarray,
                   write_floor: Optional[jnp.ndarray] = None,
                   adapters=None,
                   ) -> Tuple[jnp.ndarray, Params]:
@@ -373,9 +373,10 @@ def prefill_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
 
     tokens:  (B, C) int32 — each slot's next window of prompt tokens,
              right-padded; positions past ``lengths[b]`` are padding.
-    state:   decode state with a per-slot ``index`` vector (B,) — the
-             number of tokens already written per slot; the window lands
-             at positions [index, index + C) of each slot's caches.
+    state:   paged decode state (``init_decode_state_paged``) with a
+             per-slot ``index`` vector (B,) — the number of tokens
+             already written per slot; the window lands at positions
+             [index, index + C) of each slot's pages.
     lengths: (B,) int32 in [0, C] — valid tokens per slot.  A slot with
              length 0 is idle this step; length 1 is exactly a decode
              step (the slot's last sampled token rides in column 0).
@@ -387,8 +388,7 @@ def prefill_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     Recurrent (mamba/rwkv) states advance over the FULL window including
     padding; callers with such states must only pass fully-valid windows
     (see serve.engine's scheduler) and merge inactive slots' states back.
-    ``pages``: optional (B, n_p) page table for paged KV caches — the
-    window then writes through the page indirection (see
+    ``pages``: the (B, n_p) page table the window writes through (see
     ``init_decode_state_paged``).  ``write_floor``: optional (B,) first
     WRITABLE position per slot — scatter-writes below it (a
     prefix-cached read-only region, serve.engine) are rerouted to the
@@ -411,7 +411,7 @@ def prefill_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
 
 def verify_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                  state: Params, lengths: jnp.ndarray,
-                 pages: Optional[jnp.ndarray] = None,
+                 pages: jnp.ndarray,
                  write_floor: Optional[jnp.ndarray] = None,
                  adapters=None,
                  ) -> Tuple[jnp.ndarray, Params]:
@@ -427,11 +427,11 @@ def verify_chunk(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     idle slot riding along).  K/V for all W positions are written at
     full rank at [index, index + W) — overwriting whatever the draft
     pass left there — and ``index`` advances by ``lengths``; the caller
-    rolls ``index`` back to the accepted prefix (dense and paged: a pure
-    length decrement — stale K/V past the new index sits beyond every
-    causal horizon until overwritten, the cache invariant every padded
-    chunk write already relies on).  ``write_floor`` and ``adapters`` as
-    in ``prefill_chunk``."""
+    rolls ``index`` back to the accepted prefix (a pure length
+    decrement — stale K/V past the new index sits beyond every causal
+    horizon until overwritten, the cache invariant every padded chunk
+    write already relies on).  ``pages``, ``write_floor`` and
+    ``adapters`` as in ``prefill_chunk``."""
     B, C = tokens.shape
     idx = state["index"]                                   # (B,)
     positions = idx[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
@@ -453,9 +453,10 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
                 ) -> Tuple[jnp.ndarray, Params]:
     """token: (B,) int32.  Returns (logits (B, V), new_state).
 
-    state["index"] may be a scalar (lockstep decode) or a (B,) vector
-    (per-slot positions, continuous batching).  ``pages``: optional
-    (B, n_p) page table for paged KV caches.  ``write_floor`` and
+    state["index"] is a scalar (lockstep decode over
+    ``init_decode_state``) or a (B,) vector (per-slot positions,
+    continuous batching over ``init_decode_state_paged``, which then
+    needs the (B, n_p) page table ``pages``).  ``write_floor`` and
     ``adapters`` as in ``prefill_chunk``.  ``draft_rank``: run the
     attention layers at the sliced (r_q, r_v) widths — the
     self-speculative DRAFT pass over the shared full-rank cache
@@ -463,6 +464,8 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     B = token.shape[0]
     idx = state["index"]
     if jnp.ndim(idx) == 1:
+        assert pages is not None, \
+            "a per-slot (B,) index needs a page table (paged state)"
         positions = idx[:, None].astype(jnp.int32)
     else:
         positions = jnp.broadcast_to(idx[None, None], (B, 1)).astype(jnp.int32)

@@ -255,20 +255,24 @@ def serve_rules() -> ShardingRules:
     })
 
 
-def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
+def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool = True,
                       rules: Optional[ShardingRules] = None) -> Params:
     """PartitionSpec tree for the serving engine's decode state.
 
-    KV leaves shard along their heads: the dense head-major ``(nb, B,
-    KV, T, r)`` along KV (and its slots over "data"), the paged pool
-    ``(nb, n_pages+1, PT, lanes)`` along its lanes, one block of heads
-    a shard (``kernels.ref.page_lanes`` with ``kv_shards`` blocks).  The
-    paged pool's page-row axis is replicated — page ids are global, the
-    host-side allocator/trie address the same rows on every shard.
-    Recurrent (mamba/rwkv) leaves shard only their slot axis over
-    "data" (their inner dims replicate across "model" — O(1) per
-    token, not worth a collective); the index vector replicates.
+    KV leaves are page pools ``(nb, n_pages+1, PT, lanes)`` and shard
+    along their lanes, one block of heads a shard
+    (``kernels.ref.page_lanes`` with ``kv_shards`` blocks).  The
+    page-row axis is replicated — page ids are global, the host-side
+    allocator/trie address the same rows on every shard.  Recurrent
+    (mamba/rwkv) leaves shard only their slot axis over "data" (their
+    inner dims replicate across "model" — O(1) per token, not worth a
+    collective); the index vector replicates.  ``paged`` admits only
+    True: the page pool is the serving engine's one KV layout.
     """
+    if not paged:
+        raise ValueError("serve_state_specs(paged=False): the dense "
+                         "per-slot KV layout was removed; the serving "
+                         "state is always a page pool")
     rules = rules or serve_rules()
 
     def visit(path, leaf):
@@ -276,12 +280,10 @@ def serve_state_specs(state: Params, mesh: Mesh, *, paged: bool,
         if getattr(path[-1], "key", "") == "index":
             return P()
         if "kv" in names:
-            axes = ((None, None, None, KV_HEADS) if paged
-                    else (None, BATCH, KV_HEADS, None, None))
+            axes = (None, None, None, KV_HEADS)
         else:
             axes = (None, BATCH) + (None,) * (leaf.ndim - 2)
-        axes = tuple(axes)[:leaf.ndim]
-        axes = axes + (None,) * (leaf.ndim - len(axes))
+        axes = axes[:leaf.ndim] + (None,) * (leaf.ndim - len(axes))
         # normalize to jax's canonical form (size-1 mesh axes and
         # trailing Nones dropped) so the init placement is the SAME jit
         # cache key as the constrained step outputs — a cosmetic spec

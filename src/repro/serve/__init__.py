@@ -1,4 +1,4 @@
-"""Serving: batched engine over (optionally paged) CLOVER-rank KV
+"""Serving: batched engine over paged CLOVER-rank KV
 caches with copy-on-write prefix caching, a hierarchical host-RAM
 spill tier, rank-balanced tensor parallelism, an overload-safe
 robustness layer, and multi-tenant SV-adapter serving (DESIGN.md §13:

@@ -20,14 +20,16 @@ class EngineConfig:
     eos_id: int = -1                    # -1: never stop on token
     prefill_chunk: int = 64             # prompt tokens consumed per chunk step
     # -- paged KV cache (DESIGN.md §6) --------------------------------
-    paged: bool = False                 # page the KV cache
+    # K/V always live in a page pool; True is the only legal value (the
+    # field stays for configurations that still spell it out)
+    paged: bool = True
     page_tokens: int = 8                # tokens per KV page
     # pool size in pages; 0 -> slots * ceil(capacity / page_tokens),
     # i.e. no memory pressure (every slot can reach full capacity).
     # Size it below that to overcommit: admission then gates on free
     # pages and exhaustion preempts the youngest sequence.
     n_pages: int = 0
-    # -- automatic prefix caching (DESIGN.md §9, requires paged) ------
+    # -- automatic prefix caching (DESIGN.md §9) -----------------------
     # share KV pages across requests with a common page-aligned token
     # prefix (system prompts, few-shot templates, replayed chats): a
     # host-side trie indexes retired/prefilled full-page runs, admission
@@ -72,9 +74,9 @@ class EngineConfig:
     # "" inherits ArchConfig.kernel_impl; any other alias overrides it
     # for this engine: "ref" | "xla" | "pallas" | "interpret".  The
     # executor resolves the alias ONCE per (platform, mesh) into a
-    # frozen KernelDispatch — under tp > 1 the flash-decode /
-    # paged-decode / page-copy kernels then run per shard via
-    # shard_map.  Unknown aliases fail here, loudly, not at trace time.
+    # frozen KernelDispatch — under tp > 1 the paged-decode /
+    # page-copy kernels then run per shard via shard_map.  Unknown
+    # aliases fail here, loudly, not at trace time.
     kernel_impl: str = ""
     # -- robustness (DESIGN.md §11) -----------------------------------
     # A failed compiled step (raised, or returned non-finite logits) is
@@ -107,6 +109,10 @@ class EngineConfig:
     rank_budget: Optional[RankBudget] = None
 
     def __post_init__(self):
+        if not self.paged:
+            raise ValueError(
+                "EngineConfig.paged=False: the dense per-slot KV layout "
+                "was removed; the engine serves from a page pool only")
         if self.kernel_impl not in ("",) + self._IMPLS:
             raise ValueError(
                 f"EngineConfig.kernel_impl={self.kernel_impl!r}: expected "
@@ -152,13 +158,11 @@ class EngineConfig:
     def capacity(self) -> int:
         """Per-slot KV capacity: max_len rounded up to a chunk multiple
         PLUS spare room, so every window write [index, index+W) with
-        index <= max_len stays in bounds — dense dynamic_update_slice
-        never clamps (a clamped write would shift backwards over valid
-        history) and paged position->page lookups never fall off the
-        table.  W is the chunk size or, with speculation on, the
-        (k+1)-wide verify window whose rejected tail transiently
-        overhangs the committed length.  The spare tail is beyond every
-        causal horizon, hence never readable."""
+        index <= max_len stays in bounds — position->page lookups
+        never fall off the page table.  W is the chunk size or, with
+        speculation on, the (k+1)-wide verify window whose rejected
+        tail transiently overhangs the committed length.  The spare
+        tail is beyond every causal horizon, hence never readable."""
         C = self.chunk
         spare = max(C, self.spec_window if self.spec_k > 0 else 1)
         return ((self.max_len + C - 1) // C * C
@@ -166,7 +170,7 @@ class EngineConfig:
 
     @property
     def table_pages(self) -> int:
-        """Static per-slot page-table width (paged mode)."""
+        """Static per-slot page-table width."""
         pt = self.page_tokens
         return (self.capacity + pt - 1) // pt
 

@@ -18,10 +18,10 @@ fires).  The division of labor:
     touches a mesh, a sharding or a jit cache — swap the executor and
     nothing here changes.
 
-KV layout is DENSE (``EngineConfig.paged=False``: per-slot caches) or
-PAGED (one global pool per attention layer + host page tables); paged
-mode optionally shares pages across sequences by page-aligned token
-prefix (``prefix_cache``, DESIGN.md §9), optionally backed by a
+K/V lives in PAGES: one global pool per attention layer, addressed
+through host page tables (DESIGN.md §6).  The engine optionally shares
+pages across sequences by page-aligned token prefix (``prefix_cache``,
+DESIGN.md §9), optionally backed by a
 host-RAM spill tier (``host_pages``, DESIGN.md §12: trie eviction
 copies page bytes device->host before freeing, admission restores them
 instead of re-prefilling), and every pure-decode step can
@@ -130,16 +130,12 @@ class Engine:
                 "speculative decoding requires an attention-only "
                 "architecture: recurrent (mamba/rwkv) state cannot roll "
                 "back rejected draft tokens")
-        if ecfg.prefix_cache:
-            if not ecfg.paged:
-                raise ValueError("prefix_cache requires paged=True: only "
-                                 "pages can be shared across sequences")
-            if recurrent:
-                raise ValueError(
-                    "prefix caching requires an attention-only "
-                    "architecture: recurrent (mamba/rwkv) state is not "
-                    "page-addressable, so a cached page run cannot "
-                    "reconstruct it")
+        if ecfg.prefix_cache and recurrent:
+            raise ValueError(
+                "prefix caching requires an attention-only "
+                "architecture: recurrent (mamba/rwkv) state is not "
+                "page-addressable, so a cached page run cannot "
+                "reconstruct it")
         self.adapters = adapters
         if executor is None:
             bank = adapters.bank() if adapters is not None else None
@@ -171,12 +167,8 @@ class Engine:
                 "reuse a donated state buffer")
         self.faults = faults
         self.state = executor.init_state()
-        if ecfg.paged:
-            self.alloc: Optional[PageAllocator] = PageAllocator(
-                ecfg.pool_pages, ecfg.page_tokens, ecfg.slots,
-                ecfg.table_pages)
-        else:
-            self.alloc = None
+        self.alloc = PageAllocator(ecfg.pool_pages, ecfg.page_tokens,
+                                   ecfg.slots, ecfg.table_pages)
         self.prefix: Optional[PrefixCache] = None
         self.host: Optional[HostTier] = None
         if ecfg.prefix_cache:
@@ -283,10 +275,9 @@ class Engine:
             out["host_dropped"] = self.host.dropped
             out["host_hit_rate"] = self.host.hit_rate
             out["host_pages_used"] = len(self.host)
-        if self.alloc is not None:
-            out["page_util"] = self.alloc.utilization()
-            out["peak_page_util"] = self.peak_page_util
-            out["free_pages"] = self.alloc.free_pages
+        out["page_util"] = self.alloc.utilization()
+        out["peak_page_util"] = self.peak_page_util
+        out["free_pages"] = self.alloc.free_pages
         if self.ecfg.spec_k > 0:
             out["accepted_per_round"] = self.accepted_per_round
         if self.adapters is not None:
@@ -491,7 +482,7 @@ class Engine:
         self.watchdog_sheds += 1
         self.metrics.bump("watchdog_sheds")
 
-    # -- paged page-coverage / COW / preemption ------------------------
+    # -- page coverage / COW / preemption ------------------------------
     def _cover_writes(self, s: int, take_s: int, pairs: List) -> bool:
         """Page-cover slot ``s``'s next write window [written, written +
         take) AND copy-on-write any SHARED page inside it (a prefix-hit
@@ -616,10 +607,9 @@ class Engine:
         longest draft prefix matching the full model's argmaxes plus
         the bonus token — between 1 and k+1 tokens, never diverging
         from the non-speculative greedy stream — and the per-slot index
-        rolls back over the rejected tail (dense and paged alike this
-        is a pure length decrement: rejected K/V sits beyond every
-        causal horizon until overwritten, the invariant padded chunk
-        writes already rely on)."""
+        rolls back over the rejected tail (a pure length decrement:
+        rejected K/V sits beyond every causal horizon until overwritten,
+        the invariant padded chunk writes already rely on)."""
         sched, ecfg = self.sched, self.ecfg
         k, W = ecfg.spec_k, ecfg.spec_window
         slots = ecfg.slots
@@ -695,7 +685,6 @@ class Engine:
         sched = self.sched
         with span("serve.plan"):
             spec = self._spec_due()
-            pages = wfloor = None
             # newly admitted slots restart their tenure at their resume
             # point — 0, or the first uncached position on a prefix hit
             # (the device index follows via the executor's fresh-reset
@@ -713,17 +702,15 @@ class Engine:
             active_rows = np.array([r is not None for r in sched.slot_req])
             self.written[~active_rows] = 0
             resume = np.where(active_rows, sched.resume, 0).astype(np.int32)
-            if self.alloc is not None:
-                with span("serve.pages"):
-                    self._ensure_pages(self.ecfg.spec_window if spec else 1)
-                pages = self.alloc.table_array()
-                # defense in depth: scatter-writes below each slot's
-                # resume point are rerouted to the garbage row on
-                # device, so even a host-side COW bug cannot corrupt a
-                # shared cached prefix
-                wfloor = resume
-                self.peak_page_util = max(self.peak_page_util,
-                                          self.alloc.utilization())
+            with span("serve.pages"):
+                self._ensure_pages(self.ecfg.spec_window if spec else 1)
+            pages = self.alloc.table_array()
+            # defense in depth: scatter-writes below each slot's resume
+            # point are rerouted to the garbage row on device, so even a
+            # host-side COW bug cannot corrupt a shared cached prefix
+            wfloor = resume
+            self.peak_page_util = max(self.peak_page_util,
+                                      self.alloc.utilization())
             # recompute after _ensure_pages: preemption may have idled
             # slots
             active = np.array([r is not None for r in sched.slot_req])

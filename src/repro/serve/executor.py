@@ -105,10 +105,9 @@ def _reset_fresh(state: Params, fresh: jnp.ndarray,
     """Zero recurrent state of freshly admitted slots and set their
     index to ``resume`` (0 normally; the first uncached position on a
     prefix-cache hit — the cached prefix's K/V is already present in
-    the slot's read-only shared pages).  KV caches keep their stale
-    contents — masked by the per-slot index (dense: the slot's own
-    region; paged: freshly allocated pages hold a previous owner's
-    data, masked until overwritten by the new one)."""
+    the slot's read-only shared pages).  KV pools keep their stale
+    contents: freshly allocated pages hold a previous owner's data,
+    masked by the per-slot index until overwritten by the new one."""
 
     def z(path, leaf):
         if _is_kv(path):
@@ -125,8 +124,8 @@ def _merge_inactive(old_blocks, new_blocks, active: jnp.ndarray):
     wholesale: inactive slots' garbage writes land at [index, index+C),
     which is either masked (beyond each slot's causal horizon),
     overwritten by that slot's own future writes before it becomes
-    readable, or (paged) routed via sentinel table entries into the
-    pool's garbage row."""
+    readable, or routed via sentinel table entries into the pool's
+    garbage row."""
 
     def sel(path, old, new):
         if _is_kv(path):
@@ -134,10 +133,6 @@ def _merge_inactive(old_blocks, new_blocks, active: jnp.ndarray):
         return jnp.where(_mask_like(active, old), new, old)
 
     return jax.tree_util.tree_map_with_path(sel, old_blocks, new_blocks)
-
-
-def _dev(x):
-    return None if x is None else jnp.asarray(x)
 
 
 def _select_adapters(bank, ids):
@@ -186,9 +181,9 @@ class Executor(Protocol):
     All array arguments are host (numpy) values except ``state``, which
     is whatever ``init_state`` returned (device-resident, possibly
     sharded) and is threaded engine -> executor -> engine unchanged in
-    structure.  ``pages`` / ``wfloor`` are None in dense mode.  Every
-    step method returns ``(logits, new_state)`` with logits gatherable
-    via ``np.asarray``.
+    structure.  ``pages`` is the (slots, table_pages) page table and
+    ``wfloor`` the per-slot write floor.  Every step method returns
+    ``(logits, new_state)`` with logits gatherable via ``np.asarray``.
     """
     tp: int
     draft_rank: Optional[Tuple[int, int]]
@@ -300,7 +295,7 @@ class LocalExecutor:
             return self._pin_blocks(
                 jax.tree_util.tree_map_with_path(cp, blocks))
 
-        self._copy = jit(copy_fn, state_argnum=0) if ecfg.paged else None
+        self._copy = jit(copy_fn, state_argnum=0)
 
         # host-tier restore scatter (DESIGN.md §12): one fixed-width
         # batch shape, reusing page_copy's row-to-row slab contract —
@@ -318,7 +313,7 @@ class LocalExecutor:
                 jax.tree_util.tree_map_with_path(rs, blocks))
 
         self._restore = (jit(restore_fn, state_argnum=0)
-                         if ecfg.paged and ecfg.host_pages > 0 else None)
+                         if ecfg.host_pages > 0 else None)
         self._draft = self._verify = None
         self.draft_rank: Optional[Tuple[int, int]] = None
         if ecfg.spec_k > 0 and not self.recurrent:
@@ -400,17 +395,11 @@ class LocalExecutor:
         return self._draft is not None
 
     def init_state(self) -> Params:
+        from repro.parallel.sharding import kv_shards
         cfg, ecfg = self.cfg, self.ecfg
-        if ecfg.paged:
-            from repro.parallel.sharding import kv_shards
-            state = T.init_decode_state_paged(
-                cfg, ecfg.slots, ecfg.pool_pages, ecfg.page_tokens,
-                shards=kv_shards(self.dispatch.mesh, cfg.n_kv_heads))
-        else:
-            state = T.init_decode_state(cfg, ecfg.slots, ecfg.capacity)
-            # per-slot positions: (slots,) index vector so slots at
-            # different depths coexist in one batch
-            state["index"] = jnp.zeros((ecfg.slots,), jnp.int32)
+        state = T.init_decode_state_paged(
+            cfg, ecfg.slots, ecfg.pool_pages, ecfg.page_tokens,
+            shards=kv_shards(self.dispatch.mesh, cfg.n_kv_heads))
         return self._place_state(state)
 
     def prefill_chunk(self, state, tokens, lengths, fresh, resume,
@@ -418,30 +407,30 @@ class LocalExecutor:
         with self._ctx():
             return self._chunk(self.params, jnp.asarray(tokens),
                                jnp.asarray(lengths), jnp.asarray(fresh),
-                               jnp.asarray(resume), _dev(pages),
-                               _dev(wfloor), self.abank, self._aids(aids),
-                               state)
+                               jnp.asarray(resume), jnp.asarray(pages),
+                               jnp.asarray(wfloor), self.abank,
+                               self._aids(aids), state)
 
     def decode_step(self, state, tok, fresh, resume, pages, wfloor,
                     aids=None):
         with self._ctx():
             return self._decode(self.params, jnp.asarray(tok),
                                 jnp.asarray(fresh), jnp.asarray(resume),
-                                _dev(pages), _dev(wfloor), self.abank,
-                                self._aids(aids), state)
+                                jnp.asarray(pages), jnp.asarray(wfloor),
+                                self.abank, self._aids(aids), state)
 
     def draft_step(self, state, tok, pages, wfloor, aids=None):
         with self._ctx():
-            return self._draft(self.params, jnp.asarray(tok), _dev(pages),
-                               _dev(wfloor), self.abank, self._aids(aids),
-                               state)
+            return self._draft(self.params, jnp.asarray(tok),
+                               jnp.asarray(pages), jnp.asarray(wfloor),
+                               self.abank, self._aids(aids), state)
 
     def verify_chunk(self, state, tokens, lengths, pages, wfloor,
                      aids=None):
         with self._ctx():
             return self._verify(self.params, jnp.asarray(tokens),
-                                jnp.asarray(lengths), _dev(pages),
-                                _dev(wfloor), self.abank,
+                                jnp.asarray(lengths), jnp.asarray(pages),
+                                jnp.asarray(wfloor), self.abank,
                                 self._aids(aids), state)
 
     def page_copy(self, state, src, dst) -> Params:
@@ -490,7 +479,7 @@ class LocalExecutor:
     def compiled_shapes(self) -> Optional[int]:
         """Total jit cache entries across all step functions — the
         executor's contract is that this never exceeds 2 without
-        speculation (dense AND paged: the page table is shape-static),
+        speculation (the page table is shape-static),
         4 with it (one draft shape + one verify shape on top), plus at
         most 1 for the fixed-width page-copy batch once a prefix-cache
         copy-on-write fault has fired, plus at most 1 for the
@@ -513,8 +502,8 @@ class LocalExecutor:
         """What each compiled entry ACTUALLY runs — ground truth for
         ``examples/serve_pruned`` reporting (the old executor could
         claim "pallas" while silently tracing XLA under tp > 1).  The
-        hot one-token steps (decode/draft) take the flash-decode
-        kernels on the kernel path; chunked prefill/verify windows
+        hot one-token steps (decode/draft) take the paged flash-decode
+        kernel on the kernel path; chunked prefill/verify windows
         (S > 1) always take the masked einsum path."""
         d = self.dispatch
         hot = (d.describe()
@@ -524,8 +513,7 @@ class LocalExecutor:
         if self._draft is not None:
             rep["draft_step"] = hot
             rep["verify_chunk"] = "xla"
-        if self._copy is not None:
-            rep["page_copy"] = d.describe() if d.kernel_path else "ref"
+        rep["page_copy"] = d.describe() if d.kernel_path else "ref"
         if self._restore is not None:
             rep["page_restore"] = d.describe() if d.kernel_path else "ref"
         return rep
@@ -561,8 +549,8 @@ class ShardedExecutor(LocalExecutor):
     were added.
 
     Pallas step kernels run PER SHARD: ``_compile_cfg`` resolves
-    ``kernel_impl`` against the executor's mesh, so the flash-decode /
-    paged-decode / page-copy calls inside the step functions trace
+    ``kernel_impl`` against the executor's mesh, so the paged-decode /
+    page-copy calls inside the step functions trace
     under ``shard_map`` with serve-rules operand specs
     (``kernels.ops.KernelDispatch``).  Page ids stay host-global — the
     pools' page-row axis is replicated, so the scalar-prefetched page
@@ -637,8 +625,7 @@ class ShardedExecutor(LocalExecutor):
 
     def _place_state(self, state: Params) -> Params:
         from repro.parallel import sharding as sh
-        self._state_specs = sh.serve_state_specs(state, self.mesh,
-                                                 paged=self.ecfg.paged)
+        self._state_specs = sh.serve_state_specs(state, self.mesh)
         return _put_tree(state, self._state_specs, self.mesh)
 
     def _pin_state(self, state: Params) -> Params:

@@ -147,13 +147,12 @@ class Scheduler:
     """Admission / chunking / preemption / retirement policy with
     per-slot phases.
 
-    With a ``PageAllocator`` (paged mode) admission is gated on free
-    pages for the effective prompt, retirement frees pages, and
-    ``preempt`` requeues a sequence at the queue head with its
-    generated tokens folded into the effective prompt (greedy
-    continuation is exact).
+    Admission is gated on the ``PageAllocator``'s free pages for the
+    effective prompt, retirement frees pages, and ``preempt`` requeues
+    a sequence at the queue head with its generated tokens folded into
+    the effective prompt (greedy continuation is exact).
 
-    With a ``PrefixCache`` (paged + ``EngineConfig.prefix_cache``)
+    With a ``PrefixCache`` (``EngineConfig.prefix_cache``)
     admission additionally matches the longest cached page-aligned
     prefix of the effective prompt, maps those pages READ-ONLY into the
     slot's table and resumes chunked prefill at the first uncached
@@ -175,7 +174,7 @@ class Scheduler:
     """
 
     def __init__(self, ecfg: EngineConfig, recurrent: bool,
-                 allocator: Optional[PageAllocator] = None,
+                 allocator: PageAllocator,
                  prefix: Optional[PrefixCache] = None,
                  metrics=None):
         self.ecfg = ecfg
@@ -237,16 +236,14 @@ class Scheduler:
                 f"Request.prompt (uid={req.uid}): prompt length {L} + "
                 f"max_new_tokens {req.max_new_tokens} exceeds "
                 f"EngineConfig.max_len={self.ecfg.max_len}")
-        if self.alloc is not None:
-            need = self.alloc.pages_for(
-                L + req.max_new_tokens + self.ecfg.spec_k)
-            if need > self.alloc.n_pages:
-                raise ValueError(
-                    f"Request.prompt (uid={req.uid}): needs {need} KV "
-                    f"pages (prompt {L} + max_new_tokens "
-                    f"{req.max_new_tokens} + spec overhang "
-                    f"{self.ecfg.spec_k}) but the pool only has "
-                    f"{self.alloc.n_pages}")
+        need = self.alloc.pages_for(L + req.max_new_tokens + self.ecfg.spec_k)
+        if need > self.alloc.n_pages:
+            raise ValueError(
+                f"Request.prompt (uid={req.uid}): needs {need} KV "
+                f"pages (prompt {L} + max_new_tokens "
+                f"{req.max_new_tokens} + spec overhang "
+                f"{self.ecfg.spec_k}) but the pool only has "
+                f"{self.alloc.n_pages}")
         req.t_submit = time.monotonic()
         req.submit_step = self.now_step
         req.status = QUEUED
@@ -275,55 +272,54 @@ class Scheduler:
             # submit() validated the request; these are invariants
             assert L > 0 and L + remaining <= self.ecfg.max_len
             resume = 0
-            if self.alloc is not None:
-                with span("serve.prefix_match"):
-                    # speculative verify windows transiently overhang
-                    # the committed length by up to spec_k tokens
-                    slack = self.ecfg.spec_k
-                    assert (self.alloc.pages_for(L + remaining + slack)
-                            <= self.alloc.n_pages)
-                    hit_pages = 0
-                    if self.prefix is not None:
-                        pages = self.prefix.match(eff,
-                                                  extra=req._trie_extra)
-                        if pages and self.alloc.map_shared(s, pages):
-                            # at least one token must remain to prefill
-                            # (its logits seed generation); a FULL hit
-                            # resumes at L-1 and the rewrite of that
-                            # position COWs the shared last page
-                            pt = self.alloc.page_tokens
-                            hit_pages = len(pages)
-                            resume = min(hit_pages * pt, L - 1)
-                    ok = self.alloc.ensure(s, L)
-                    if not ok and self.prefix is not None:
-                        # cached-but-idle prefixes are reclaimable
-                        # bytes: evict LRU trie pages nobody maps and
-                        # retry (matched pages are slot-mapped now, so
-                        # eviction can never touch THIS hit)
-                        short = (self.alloc.pages_for(L)
-                                 - len(self.alloc.tables[s])
-                                 - self.alloc.free_pages)
-                        if short > 0 and self.prefix.evict(short) > 0:
-                            ok = self.alloc.ensure(s, L)
-                if not ok:
-                    # head-of-line: the best candidate waits for pages
-                    # (undo the shared mapping so the trie can evict)
-                    self.alloc.release(s)
-                    break
-                if self.restore is not None:
-                    # hierarchical KV (DESIGN.md §12): pages beyond the
-                    # trie hit may survive in the HOST tier — ensure()
-                    # just allocated the slot's own pages for them, so
-                    # the engine can copy spilled bytes back instead of
-                    # re-prefilling.  On a host_copy fault the callback
-                    # returns what it managed (possibly 0); the resume
-                    # point only ever advances over RESTORED pages.
-                    with span("serve.host_restore"):
-                        n_rest = self.restore(s, eff, hit_pages,
-                                              req._trie_extra)
-                    if n_rest > 0:
-                        resume = min((hit_pages + n_rest)
-                                     * self.alloc.page_tokens, L - 1)
+            with span("serve.prefix_match"):
+                # speculative verify windows transiently overhang
+                # the committed length by up to spec_k tokens
+                slack = self.ecfg.spec_k
+                assert (self.alloc.pages_for(L + remaining + slack)
+                        <= self.alloc.n_pages)
+                hit_pages = 0
+                if self.prefix is not None:
+                    pages = self.prefix.match(eff,
+                                              extra=req._trie_extra)
+                    if pages and self.alloc.map_shared(s, pages):
+                        # at least one token must remain to prefill
+                        # (its logits seed generation); a FULL hit
+                        # resumes at L-1 and the rewrite of that
+                        # position COWs the shared last page
+                        pt = self.alloc.page_tokens
+                        hit_pages = len(pages)
+                        resume = min(hit_pages * pt, L - 1)
+                ok = self.alloc.ensure(s, L)
+                if not ok and self.prefix is not None:
+                    # cached-but-idle prefixes are reclaimable
+                    # bytes: evict LRU trie pages nobody maps and
+                    # retry (matched pages are slot-mapped now, so
+                    # eviction can never touch THIS hit)
+                    short = (self.alloc.pages_for(L)
+                             - len(self.alloc.tables[s])
+                             - self.alloc.free_pages)
+                    if short > 0 and self.prefix.evict(short) > 0:
+                        ok = self.alloc.ensure(s, L)
+            if not ok:
+                # head-of-line: the best candidate waits for pages
+                # (undo the shared mapping so the trie can evict)
+                self.alloc.release(s)
+                break
+            if self.restore is not None:
+                # hierarchical KV (DESIGN.md §12): pages beyond the
+                # trie hit may survive in the HOST tier — ensure()
+                # just allocated the slot's own pages for them, so
+                # the engine can copy spilled bytes back instead of
+                # re-prefilling.  On a host_copy fault the callback
+                # returns what it managed (possibly 0); the resume
+                # point only ever advances over RESTORED pages.
+                with span("serve.host_restore"):
+                    n_rest = self.restore(s, eff, hit_pages,
+                                          req._trie_extra)
+                if n_rest > 0:
+                    resume = min((hit_pages + n_rest)
+                                 * self.alloc.page_tokens, L - 1)
             self.queue.remove(req)
             req.cached_tokens = resume
             req.status = RUNNING
@@ -382,8 +378,7 @@ class Scheduler:
         if the request had never run."""
         req = self.slot_req[s]
         assert req is not None
-        if self.alloc is not None:
-            self.alloc.release(s)
+        self.alloc.release(s)
         self.slot_req[s] = None
         self.slot_prompt[s] = None
         self.phase[s] = None
@@ -450,8 +445,7 @@ class Scheduler:
         so re-admission re-prefills from the host-held token stream."""
         req = self.slot_req[s]
         assert req is not None
-        if self.alloc is not None:
-            self.alloc.release(s)
+        self.alloc.release(s)
         self.slot_req[s] = None
         self.slot_prompt[s] = None
         self.phase[s] = None
@@ -598,9 +592,8 @@ class Scheduler:
         re-prefilling — pages are decref'd, not freed."""
         req = self.slot_req[s]
         assert req is not None
-        if self.alloc is not None:
-            self._publish(s, n_valid)
-            self.alloc.release(s)
+        self._publish(s, n_valid)
+        self.alloc.release(s)
         self.slot_req[s] = None
         self.slot_prompt[s] = None
         self.phase[s] = None
@@ -620,10 +613,9 @@ class Scheduler:
             if (len(req.generated) >= req.max_new_tokens
                     or (self.ecfg.eos_id >= 0 and req.generated
                         and req.generated[-1] == self.ecfg.eos_id)):
-                if self.alloc is not None:
-                    if written is not None:
-                        self._publish(s, int(written[s]))
-                    self.alloc.release(s)
+                if written is not None:
+                    self._publish(s, int(written[s]))
+                self.alloc.release(s)
                 self.slot_req[s] = None
                 self.slot_prompt[s] = None
                 self.phase[s] = None

@@ -1,8 +1,8 @@
 """shard_map'd kernel parity: per-shard Pallas execution must be
 BITWISE identical to the single-device kernels.
 
-The serving hot-path kernels (flash-decode, paged flash-decode,
-page-copy, full-sequence attention) iterate a grid whose (batch,
+The serving hot-path kernels (paged flash-decode, page-copy,
+full-sequence attention) iterate a grid whose (batch,
 kv-head) cells are independent, so splitting slots over "data" and KV
 heads over "model" (``kernels.ops.resolve(impl, mesh)``) must not
 change a single bit — these tests assert ``np.array_equal`` on raw
@@ -117,25 +117,6 @@ def test_recurrent_tp_kernel_path_raises():
 # per-kernel bitwise parity, single device vs shard_map
 # ---------------------------------------------------------------------------
 
-def test_decode_attention_shard_parity():
-    _need(2)
-    mesh = make_host_mesh(model=2)
-    rng = np.random.default_rng(0)
-    B, H, KV, dq, dv, T = 4, 8, 4, 16, 12, 40
-    q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    k = _ragged_kv(rng, (B, KV, T, dq), head_axis=1, rank_axis=3)
-    v = _ragged_kv(rng, (B, KV, T, dv), head_axis=1, rank_axis=3)
-    lens = jnp.asarray([1, 17, 40, 5], jnp.int32)
-    single = kops.resolve("interpret")
-    sharded = kops.resolve("interpret", mesh=mesh)
-    a = jax.jit(lambda *x: single.decode_attention(*x, scale=0.25))(
-        q, k, v, lens)
-    b = jax.jit(lambda *x: sharded.decode_attention(*x, scale=0.25))(
-        q, k, v, lens)
-    assert a.dtype == b.dtype and np.array_equal(np.asarray(a),
-                                                 np.asarray(b))
-
-
 def _paged_pool(rng, N, PT, KV, r, shards):
     """A ragged head-major pool re-laid-out in page-row order, ``shards``
     lane blocks of heads (``ref.page_lanes``)."""
@@ -167,6 +148,36 @@ def test_paged_decode_attention_shard_parity():
     b = jax.jit(lambda *x: sharded.paged_decode_attention(*x, **geo))(
         q, kp, vp, table, lens)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_decode_attention_shard_parity():
+    """The per-head rank clamp under shard_map: each shard clamps its
+    own heads with its slice of the rank vectors, bitwise the single
+    device."""
+    _need(2)
+    mesh = make_host_mesh(model=2)
+    rng = np.random.default_rng(0)
+    # two heads a shard fill whole lane tiles (2 x 64 = 128), so the
+    # single-device and tp=2 page rows are the same bytes
+    B, H, KV, dq, dv = 4, 8, 4, 64, 64
+    N, PT, nP = 11, 8, 5
+    q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
+    kp = _paged_pool(rng, N, PT, KV, dq, shards=2)
+    vp = _paged_pool(rng, N, PT, KV, dv, shards=2)
+    assert kp.shape[-1] == ref.page_lanes(KV, dq, 1)
+    table = jnp.asarray(rng.integers(0, N, (B, nP)), jnp.int32)
+    lens = jnp.asarray([1, 17, 40, 5], jnp.int32)
+    geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=0.25,
+               qk_ranks=jnp.asarray([8, 64, 24, 40], jnp.int32),
+               vo_ranks=jnp.asarray([64, 16, 32, 8], jnp.int32))
+    single = kops.resolve("interpret")
+    sharded = kops.resolve("interpret", mesh=mesh)
+    a = jax.jit(lambda *x: single.paged_decode_attention(*x, **geo))(
+        q, kp, vp, table, lens)
+    b = jax.jit(lambda *x: sharded.paged_decode_attention(*x, **geo))(
+        q, kp, vp, table, lens)
+    assert a.dtype == b.dtype and np.array_equal(np.asarray(a),
+                                                 np.asarray(b))
 
 
 def test_paged_decode_attention_shard_lane_blocks():
@@ -233,16 +244,20 @@ def test_nondivisible_heads_degrade_to_replication():
     _need(2)
     mesh = make_host_mesh(model=2)
     rng = np.random.default_rng(4)
-    B, H, KV, dq, dv, T = 4, 6, 3, 8, 8, 16        # 3 kv heads, tp=2
+    B, H, KV, dq, dv = 4, 6, 3, 8, 8               # 3 kv heads, tp=2
+    N, PT, nP = 9, 4, 4
     q = jnp.asarray(rng.standard_normal((B, H, dq)).astype(np.float32))
-    k = _ragged_kv(rng, (B, KV, T, dq), head_axis=1, rank_axis=3)
-    v = _ragged_kv(rng, (B, KV, T, dv), head_axis=1, rank_axis=3)
+    # one lane block: the heads are not split, each shard holds them all
+    kp = _paged_pool(rng, N, PT, KV, dq, shards=1)
+    vp = _paged_pool(rng, N, PT, KV, dv, shards=1)
+    table = jnp.asarray(rng.integers(0, N, (B, nP)), jnp.int32)
     lens = jnp.asarray([4, 16, 8, 1], jnp.int32)
     from repro.parallel.sharding import kernel_axes
     b_ax, m_ax = kernel_axes(mesh, batch=B, kv_heads=KV)
     assert m_ax is None and b_ax == "data"
+    geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=0.5)
     a = jax.jit(lambda *x: kops.resolve("interpret")
-                .decode_attention(*x, scale=0.5))(q, k, v, lens)
+                .paged_decode_attention(*x, **geo))(q, kp, vp, table, lens)
     b = jax.jit(lambda *x: kops.resolve("interpret", mesh=mesh)
-                .decode_attention(*x, scale=0.5))(q, k, v, lens)
+                .paged_decode_attention(*x, **geo))(q, kp, vp, table, lens)
     assert np.array_equal(np.asarray(a), np.asarray(b))

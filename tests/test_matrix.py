@@ -1,10 +1,11 @@
-"""Cross-layout exactness matrix: ONE parameterized sweep pinning every
-serving configuration to the same oracle.
+"""Cross-configuration exactness matrix: ONE parameterized sweep
+pinning every serving configuration to the same oracle.
 
-{dense, paged, paged+prefix-cache} x {spec_k 0, 2} x {prune 0.0, 0.5}
-must all emit BYTE-IDENTICAL greedy streams to the isolated whole-
-prompt reference — layouts and speculative decoding change WHEN tokens
-are computed and WHERE their K/V lives, never WHICH tokens come out.
+{paged, paged on the Pallas kernels (interpret mode), paged+prefix-
+cache} x {spec_k 0, 2} x {prune 0.0, 0.5} must all emit BYTE-IDENTICAL
+greedy streams to the isolated whole-prompt reference — kernels, prefix
+sharing and speculative decoding change HOW and WHEN tokens are
+computed, never WHICH tokens come out.
 This supersedes the ad-hoc per-feature exactness tests that used to be
 scattered across test_serve/test_paged/test_spec (kept there as thin
 wrappers over ``run_layout_case``).
@@ -32,7 +33,7 @@ from repro.core import clover_decompose, clover_prune
 from repro.models import init_lm_params
 from repro.serve import Engine, EngineConfig, Request, greedy_reference
 
-LAYOUTS = ("dense", "paged", "prefix")
+LAYOUTS = ("kernel", "paged", "prefix")
 SPEC_KS = (0, 2)
 PRUNES = (0.0, 0.5)
 TPS = (1, 2)
@@ -76,9 +77,10 @@ def run_layout_case(layout: str, spec_k: int, prune: float, tp: int = 1):
     prompts = [np.asarray(p, np.int32) for p in prompts_t]
     ecfg = EngineConfig(slots=2, max_len=32, prefill_chunk=4,
                         spec_k=spec_k, draft_rank_ratio=0.5,
-                        paged=(layout != "dense"),
                         page_tokens=4,
-                        prefix_cache=(layout == "prefix"), tp=tp)
+                        prefix_cache=(layout == "prefix"), tp=tp,
+                        kernel_impl=("interpret" if layout == "kernel"
+                                     else ""))
     eng = Engine(params, cfg, ecfg)
     passes = 2 if layout == "prefix" else 1
     for pass_i in range(passes):
@@ -117,7 +119,7 @@ def test_tp2_interpret_kernel_cell():
     """tp=2 paged+prefix+spec with ``kernel_impl="interpret"``: the
     sharded executor now COMPILES the Pallas kernel paths per shard
     (the silent XLA demotion is gone), and the stream must still match
-    the dense tp=1 whole-prompt oracle token-for-token — across a cold
+    the tp=1 whole-prompt oracle token-for-token — across a cold
     pass and a warm prefix-cache replay (which drives the shard_map'd
     page-copy kernel through COW faults)."""
     if jax.device_count() < 2 or jax.device_count() % 2:
@@ -145,7 +147,7 @@ def test_tp2_interpret_kernel_cell():
     assert shapes is None or 2 <= shapes <= 5   # 2 base +2 spec +1 COW
 
 
-@pytest.mark.parametrize("layout", ("dense", "prefix"))
+@pytest.mark.parametrize("layout", ("paged", "prefix"))
 def test_tp_streams_identical_to_local(layout):
     """tp=2 cells must be TOKEN-IDENTICAL to the tp=1 engine (not just
     to the oracle): same requests, same engine config, executor
@@ -156,8 +158,7 @@ def test_tp_streams_identical_to_local(layout):
     prompts_t, _ = _trace(0.5)
     prompts = [np.asarray(p, np.int32) for p in prompts_t]
     base = EngineConfig(slots=2, max_len=32, prefill_chunk=4,
-                        paged=(layout != "dense"), page_tokens=4,
-                        prefix_cache=(layout == "prefix"))
+                        page_tokens=4, prefix_cache=(layout == "prefix"))
     streams = []
     for ecfg in (base, dataclasses.replace(base, tp=2)):
         eng = Engine(params, cfg, ecfg)

@@ -1,4 +1,4 @@
-"""Paged KV cache: engine equivalence vs dense, kernel parity vs the
+"""Paged KV cache: engine exactness vs the oracle, kernel parity vs the
 paged oracle, allocator/preemption behavior."""
 import dataclasses
 
@@ -24,14 +24,13 @@ def _streams(params, cfg, ecfg, prompts, max_new=4):
 
 
 # ---------------------------------------------------------------------------
-# engine: paged vs dense equivalence
+# engine: streams vs the oracle
 # ---------------------------------------------------------------------------
 
 def test_paged_engine_matches_dense_mixed_trace():
-    """Thin wrapper over the cross-layout exactness matrix
-    (tests/test_matrix.py superseded the ad-hoc paged-vs-dense stream
-    comparison): the paged cell must match the oracle byte-for-byte,
-    which pins it to the dense cell transitively."""
+    """Thin wrapper over the exactness matrix (tests/test_matrix.py): the
+    paged cell must match the whole-prompt (dense) oracle
+    byte-for-byte."""
     from test_matrix import run_layout_case
     eng = run_layout_case("paged", spec_k=0, prune=0.0)
     assert eng.compiled_shapes() in (2, None)
@@ -94,7 +93,7 @@ def test_paged_engine_on_pruned_model():
 
 def test_paged_capacity_guard():
     """A request that cannot ever fit the pool is rejected eagerly, like
-    the dense capacity guard."""
+    the max_len capacity guard."""
     cfg = get_config("musicgen-large").reduced()
     params = init_lm_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(params, cfg, EngineConfig(slots=1, max_len=32, paged=True,
@@ -215,6 +214,11 @@ def _rand_paged_case(key, B, H, KV, dq, dv, pt, n_p, n_pool, max_len,
     # speculative rollback
     pytest.param(3, 4, 2, 16, 16, 16, 12, (20, 129, 100), 3,
                  id="rolled-back-pages"),
+    # the (B, H, KV, dq, dv) shapes of the removed per-slot kernel's
+    # sweep, at 100, 256 and 40 tokens of pages
+    pytest.param(2, 4, 2, 32, 24, 4, 25, None, 0, id="gqa-100-tokens"),
+    pytest.param(3, 8, 1, 16, 16, 16, 16, None, 0, id="mqa-256-tokens"),
+    pytest.param(1, 16, 16, 64, 64, 8, 5, None, 0, id="mha-d64-40-tokens"),
 ])
 def test_paged_decode_kernel_sweep(B, H, KV, dq, dv, pt, n_p, lens, extra):
     q, kp, vp, tab, lens = _rand_paged_case(
@@ -251,6 +255,67 @@ def test_paged_decode_kernel_ignores_garbage_pages():
         o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, **geo)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                    atol=1e-5)
+
+
+def test_paged_decode_kernel_respects_lengths():
+    """Tokens past each row's length inside its own last page (a padded
+    chunk's writes) change nothing, and the output is the oracle's."""
+    B, H, KV, d, pt, n_p = 2, 4, 2, 16, 8, 8
+    n_pool = B * n_p + 1
+    q, kp, vp, tab, lens = _rand_paged_case(
+        jax.random.PRNGKey(2), B, H, KV, d, d, pt, n_p, n_pool=n_pool,
+        max_len=n_p * pt, lengths=(10, 30))
+    geo = dict(kv_heads=KV, head_widths=(d, d))
+    o1 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret",
+                                    **geo)
+    for b in range(B):
+        L = int(lens[b])
+        last = int(tab[b, (L - 1) // pt])
+        kp = kp.at[last, L % pt or pt:].set(999.0)
+        vp = vp.at[last, L % pt or pt:].set(-999.0)
+    o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, impl="interpret",
+                                    **geo)
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+    o_ref = ref.paged_decode_attention_ref(q, kp, vp, tab, lens, **geo)
+    np.testing.assert_allclose(np.asarray(o2), np.asarray(o_ref),
+                               atol=5e-5, rtol=5e-5)
+
+
+def test_paged_decode_kernel_chunk_advanced_rows():
+    """Chunked prefill moves a row's length on by a whole chunk and
+    leaves a padded window's garbage past it, in pages the row already
+    owns.  Such rows — parked at chunk edges and mid-chunk — read the
+    same as a fresh table that maps only the pages in use, over a pool
+    with that garbage zeroed."""
+    B, H, KV, d, pt, n_p, chunk = 3, 4, 2, 16, 8, 12, 32
+    n_pool = B * n_p + 1
+    lengths = (chunk, 2 * chunk, chunk + 5)
+    # each row owns one chunk of pages past its length
+    q, kp, vp, tab, lens = _rand_paged_case(
+        jax.random.PRNGKey(7), B, H, KV, d, d, pt, n_p, n_pool=n_pool,
+        max_len=n_p * pt, lengths=lengths, extra=chunk // pt)
+    poison_k, poison_v, clean_k, clean_v = kp, vp, kp, vp
+    fresh = np.full((B, n_p), n_pool - 1, np.int32)
+    for b, L in enumerate(lengths):
+        fresh[b, :-(-L // pt)] = tab[b, :-(-L // pt)]
+        for pos in range(L, min(L + chunk, n_p * pt)):
+            page, off = int(tab[b, pos // pt]), pos % pt
+            poison_k = poison_k.at[page, off].set(1e4)
+            poison_v = poison_v.at[page, off].set(-1e4)
+            clean_k = clean_k.at[page, off].set(0.0)
+            clean_v = clean_v.at[page, off].set(0.0)
+    geo = dict(kv_heads=KV, head_widths=(d, d))
+    o_poison = ops.paged_decode_attention(q, poison_k, poison_v, tab, lens,
+                                          impl="interpret", **geo)
+    o_fresh = ops.paged_decode_attention(q, clean_k, clean_v,
+                                         jnp.asarray(fresh), lens,
+                                         impl="interpret", **geo)
+    o_ref = ref.paged_decode_attention_ref(q, clean_k, clean_v,
+                                           jnp.asarray(fresh), lens, **geo)
+    np.testing.assert_allclose(np.asarray(o_poison), np.asarray(o_fresh),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(o_poison), np.asarray(o_ref),
+                               atol=5e-5, rtol=5e-5)
 
 
 def test_paged_ref_matches_dense_ref():

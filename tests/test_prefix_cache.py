@@ -281,10 +281,10 @@ def test_spec_decoding_composes_with_prefix_cache():
 
 
 def test_prefix_cache_config_guards():
-    params, cfg = _model()
+    # pages are the only KV layout: a config without them is refused
+    # before any engine exists
     with pytest.raises(ValueError, match="paged"):
-        Engine(params, cfg, EngineConfig(slots=1, max_len=16,
-                                         prefix_cache=True))
+        EngineConfig(slots=1, max_len=16, paged=False, prefix_cache=True)
     rcfg = get_config("rwkv6-1.6b").reduced()
     rparams = init_lm_params(rcfg, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="attention-only"):

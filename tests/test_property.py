@@ -216,7 +216,7 @@ def test_speculative_engine_exact(seed, n_prompts, k, draft_ratio,
                                   tight_pool):
     """For ANY prompt mix, draft rank and k, the speculative paged
     engine's greedy streams are token-identical to the non-speculative
-    dense engine — including across forced preemption+requeue when the
+    engine — including across forced preemption+requeue when the
     page pool is undersized (tight_pool)."""
     params, cfg = _spec_model()
     rng = np.random.default_rng(seed)

@@ -18,7 +18,6 @@ from repro.core import (RankBudget, apply_rank_budget, budget_kept_energy,
                         clover_decompose, plan_rank_budget)
 from repro.core.prune import snap_rank, threshold_ratios
 from repro.kernels import ref
-from repro.kernels.decode_attention import flash_decode_ranked
 from repro.kernels.paged_decode_attention import paged_flash_decode
 from repro.serve.memory import PageAllocator, PrefixCache
 
@@ -180,67 +179,37 @@ def test_threshold_ratios_contract():
     assert out["vo_head_keeps"] == (snapped, ())
 
 
-def _zero_pad(q, k, v, rq, rv, G):
-    qz, kz, vz = q.copy(), k.copy(), v.copy()
-    for h in range(len(rq)):
-        qz[..., h * G:(h + 1) * G, rq[h]:] = 0.0
-        kz[:, h, :, rq[h]:] = 0.0           # head-major (B, KV, T, r)
-        vz[:, h, :, rv[h]:] = 0.0
-    return qz, kz, vz
-
-
-def test_ranked_decode_kernel_parity():
+@pytest.mark.parametrize("B,KV,G,dq,dv,T,pt,lens,rq,rv", [
+    pytest.param(3, 4, 2, 32, 24, 64, 8, (5, 37, 64), (8, 16, 32, 24),
+                 (24, 8, 16, 24), id="gqa-g2-8-token-pages"),
+    # 4 queries a KV head, 16-token pages in blocks, ragged lengths
+    pytest.param(2, 2, 4, 40, 24, 96, 16, (1, 90), (8, 40), (24, 16),
+                 id="gqa-g4-16-token-pages"),
+])
+def test_ranked_paged_decode_kernel_parity(B, KV, G, dq, dv, T, pt, lens,
+                                           rq, rv):
     """Per-head rank clamp: on zero-padded data the clamped kernel is
-    BITWISE the full-rank kernel (skipped blocks contribute exactly
-    zero) and matches the truncating reference oracle."""
-    rng = np.random.default_rng(0)
-    B, KV, G, dq, dv, T, bt, rb = 3, 4, 2, 32, 24, 64, 16, 8
-    q = rng.normal(size=(B, KV * G, dq)).astype(np.float32)
-    k = rng.normal(size=(B, KV, T, dq)).astype(np.float32)
-    v = rng.normal(size=(B, KV, T, dv)).astype(np.float32)
-    lengths = np.array([5, 37, 64], np.int32)
-    rq = np.array([8, 16, 32, 24], np.int32)
-    rv = np.array([24, 8, 16, 24], np.int32)
-    qz, kz, vz = _zero_pad(q, k, v, rq, rv, G)
-    qg = qz.reshape(B, KV, G, dq)             # the kernel's query layout
-    scale = 1.0 / np.sqrt(dq)
-    out = flash_decode_ranked(qg, kz, vz, lengths, rq, rv, scale=scale,
-                              block_t=bt, rank_block=rb, interpret=True)
-    out_full = flash_decode_ranked(
-        qg, kz, vz, lengths, np.full(KV, dq, np.int32),
-        np.full(KV, dv, np.int32), scale=scale, block_t=bt,
-        rank_block=rb, interpret=True)
-    assert (np.asarray(out) == np.asarray(out_full)).all()
-    out = np.asarray(out).reshape(B, KV * G, dv)
-    oracle = ref.decode_attention_ref(qz, kz, vz, lengths, scale=scale)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
-                               atol=5e-5, rtol=5e-5)
-    # the oracle's explicit truncation path agrees on UNpadded data
-    oracle_trunc = ref.decode_attention_ref(q, k, v, lengths, scale=scale,
-                                            qk_ranks=rq, vo_ranks=rv)
-    np.testing.assert_allclose(np.asarray(oracle_trunc),
-                               np.asarray(oracle), atol=5e-5, rtol=5e-5)
-
-
-def test_ranked_paged_decode_kernel_parity():
+    BITWISE the full-rank kernel (clamped lanes contribute exactly zero)
+    and matches the oracle, whose explicit truncation path agrees on
+    UNpadded data."""
     rng = np.random.default_rng(1)
-    B, KV, G, dq, dv, T, pt = 3, 4, 2, 32, 24, 64, 8
     q = rng.normal(size=(B, KV * G, dq)).astype(np.float32)
-    lengths = np.array([5, 37, 64], np.int32)
-    rq = np.array([8, 16, 32, 24], np.int32)
-    rv = np.array([24, 8, 16, 24], np.int32)
+    lengths = np.array(lens, np.int32)
+    rq, rv = np.array(rq, np.int32), np.array(rv, np.int32)
     n_p = T // pt
     N = B * n_p + 1
-    pool_k = rng.normal(size=(N, pt, KV, dq)).astype(np.float32)
-    pool_v = rng.normal(size=(N, pt, KV, dv)).astype(np.float32)
-    qz = q.copy()
+    k = rng.normal(size=(N, pt, KV, dq)).astype(np.float32)
+    v = rng.normal(size=(N, pt, KV, dv)).astype(np.float32)
+    qz, kz, vz = q.copy(), k.copy(), v.copy()
     for h in range(KV):
         qz[:, h * G:(h + 1) * G, rq[h]:] = 0.0
-        pool_k[:, :, h, rq[h]:] = 0.0
-        pool_v[:, :, h, rv[h]:] = 0.0
-    # the pools in page-row order: a token's heads side by side
-    pool_k = ref.heads_to_lanes(jnp.asarray(pool_k), ref.page_lanes(KV, dq))
-    pool_v = ref.heads_to_lanes(jnp.asarray(pool_v), ref.page_lanes(KV, dv))
+        kz[:, :, h, rq[h]:] = 0.0
+        vz[:, :, h, rv[h]:] = 0.0
+
+    def rows(x, width):
+        # the pools in page-row order: a token's heads side by side
+        return ref.heads_to_lanes(jnp.asarray(x), ref.page_lanes(KV, width))
+    pool_k, pool_v = rows(kz, dq), rows(vz, dv)
     table = rng.permutation(N - 1)[:B * n_p].reshape(B, n_p).astype(np.int32)
     scale = 1.0 / np.sqrt(dq)
     geo = dict(kv_heads=KV, head_widths=(dq, dv), scale=scale,
@@ -258,6 +227,11 @@ def test_ranked_paged_decode_kernel_parity():
                                             scale=scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
                                atol=5e-5, rtol=5e-5)
+    oracle_trunc = ref.paged_decode_attention_ref(
+        q, rows(k, dq), rows(v, dv), table, lengths, kv_heads=KV,
+        head_widths=(dq, dv), scale=scale, qk_ranks=rq, vo_ranks=rv)
+    np.testing.assert_allclose(np.asarray(oracle_trunc),
+                               np.asarray(oracle), atol=5e-5, rtol=5e-5)
 
 
 @pytest.mark.skipif(jax.device_count() < 2 or jax.device_count() % 2,

@@ -5,6 +5,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import clover_decompose, clover_prune
+from repro.kernels.ref import page_lanes
 from repro.models import init_lm_params
 from repro.serve import Engine, EngineConfig, Request, greedy_reference
 
@@ -59,12 +60,26 @@ def test_engine_on_clover_pruned_model():
     dp, dcfg, _ = clover_decompose(params, cfg, peft=False)
     pp, pcfg = clover_prune(dp, dcfg, qk_ratio=0.5, vo_ratio=0.5)
     eng = Engine(pp, pcfg, EngineConfig(slots=2, max_len=32))
-    # KV cache really is at the pruned rank
+    # KV cache really is at the pruned rank: a page row holds every KV
+    # head at rank 16, side by side
     k = eng.state["blocks"][0]["kv"]["k"]
-    assert k.shape[-1] == pcfg.clover.qk_rank < cfg.head_dim_
+    assert pcfg.clover.qk_rank == 16 < cfg.head_dim_
+    assert k.shape[-1] == page_lanes(pcfg.n_kv_heads, pcfg.clover.qk_rank)
     prompt = np.arange(4, dtype=np.int32) + 5
     out = eng.run([Request(uid=0, prompt=prompt, max_new_tokens=4)])
     assert out[0].generated == _greedy_reference(pp, pcfg, prompt, 4)
+
+
+def test_engine_config_refuses_dense_layout():
+    """The page pool is the engine's one KV layout: ``paged=False`` is
+    refused with the reason, and the default config serves from pages
+    sized so every slot reaches full capacity."""
+    with pytest.raises(ValueError, match="dense per-slot KV layout"):
+        EngineConfig(paged=False)
+    ecfg = EngineConfig(slots=3, max_len=32)
+    assert ecfg.paged
+    assert ecfg.pool_pages == 3 * ecfg.table_pages
+    assert ecfg.table_pages * ecfg.page_tokens >= ecfg.capacity
 
 
 def test_engine_capacity_guard():

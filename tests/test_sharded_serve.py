@@ -210,7 +210,7 @@ def test_permute_heads_preserves_function():
 # ShardedExecutor at tp=1: the full sharded path on a single device
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", ("dense", "prefix"))
+@pytest.mark.parametrize("layout", ("paged", "prefix"))
 def test_sharded_executor_tp1_matches_local(layout):
     """tp=1 runs the ENTIRE sharded code path (mesh, plan, placement,
     output pinning, sharded draft/verify and the rollback's index
@@ -218,8 +218,8 @@ def test_sharded_executor_tp1_matches_local(layout):
     params, cfg = _model(0.5)
     prompts = _prompts(cfg)
     ecfg = EngineConfig(slots=2, max_len=32, prefill_chunk=4,
-                        paged=(layout != "dense"), page_tokens=4,
-                        prefix_cache=(layout == "prefix"), spec_k=2)
+                        page_tokens=4, prefix_cache=(layout == "prefix"),
+                        spec_k=2)
     _, want = _streams(params, cfg, ecfg, prompts,
                        executor=LocalExecutor(params, cfg, ecfg))
     exe = ShardedExecutor(params, cfg, ecfg, tp=1)
@@ -333,7 +333,8 @@ def test_tp2_nondivisible_heads_replicate():
     assert got == want
     assert eng.exe.kv_pad == 1 and eng.exe.cfg.n_kv_heads == 2
     pool = eng.state["blocks"][0]["kv"]["k"]
-    assert pool.sharding.spec[2] == "model"        # KV-head axis split
+    # the page rows' lane axis splits, one block of KV heads a shard
+    assert pool.sharding.spec[3] == "model"
 
 
 @pytest.mark.slow

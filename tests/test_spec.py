@@ -1,5 +1,5 @@
 """Self-speculative decoding: draft/verify correctness, KV rollback in
-dense and paged layouts, and the verify oracle (DESIGN.md §8)."""
+the page pool, and the verify oracle (DESIGN.md §8)."""
 import dataclasses
 
 import jax
@@ -36,18 +36,26 @@ def _run(params, cfg, ecfg, prompts, max_new=6):
 # model entry points
 # ---------------------------------------------------------------------------
 
+def _paged_state(cfg, batch, tokens, pt=4):
+    """Paged decode state whose slots own consecutive pages (an
+    identity page table), room for ``tokens`` tokens each."""
+    n_p = -(-tokens // pt)
+    state = T.init_decode_state_paged(cfg, batch, batch * n_p, pt)
+    pages = jnp.arange(batch * n_p, dtype=jnp.int32).reshape(batch, n_p)
+    return state, pages
+
+
 def test_verify_chunk_matches_forward():
     """verify_chunk returns the full model's logits at EVERY window
     position — identical to the whole-sequence forward pass."""
     params, cfg = _setup(prune=0.5)
     toks = jnp.arange(12, dtype=jnp.int32)[None] + 3
     full, _ = T.forward(params, cfg, toks)
-    state = T.init_decode_state(cfg, 1, 32)
-    state["index"] = jnp.zeros((1,), jnp.int32)
+    state, pages = _paged_state(cfg, 1, 32)
     _, state = T.prefill_chunk(params, cfg, toks[:, :7], state,
-                               jnp.array([7], jnp.int32))
+                               jnp.array([7], jnp.int32), pages=pages)
     lv, state = T.verify_chunk(params, cfg, toks[:, 7:], state,
-                               jnp.array([5], jnp.int32))
+                               jnp.array([5], jnp.int32), pages=pages)
     np.testing.assert_allclose(np.asarray(lv), np.asarray(full[:, 7:]),
                                atol=2e-4, rtol=2e-4)
     assert int(state["index"][0]) == 12
@@ -57,14 +65,13 @@ def test_draft_full_rank_is_exact_model():
     """draft_rank == (qk_dim, vo_dim) must be bit-identical to the plain
     decode step (the degenerate draft IS the model)."""
     params, cfg = _setup(prune=0.5)
-    state = T.init_decode_state(cfg, 2, 16)
-    state["index"] = jnp.zeros((2,), jnp.int32)
+    state, pages = _paged_state(cfg, 2, 16)
     toks = jnp.array([[4, 9, 2, 7], [1, 3, 3, 8]], jnp.int32)
     _, state = T.prefill_chunk(params, cfg, toks, state,
-                               jnp.array([4, 4], jnp.int32))
+                               jnp.array([4, 4], jnp.int32), pages=pages)
     tok = jnp.array([5, 6], jnp.int32)
-    l_plain, _ = T.decode_step(params, cfg, tok, dict(state))
-    l_draft, _ = T.decode_step(params, cfg, tok, dict(state),
+    l_plain, _ = T.decode_step(params, cfg, tok, dict(state), pages=pages)
+    l_draft, _ = T.decode_step(params, cfg, tok, dict(state), pages=pages,
                                draft_rank=(cfg.qk_dim, cfg.vo_dim))
     np.testing.assert_array_equal(np.asarray(l_plain), np.asarray(l_draft))
 
@@ -139,31 +146,17 @@ def test_verify_oracle_ignores_rolled_back_tail():
 # `lengths` shrinks — the kernels must key on lengths alone)
 # ---------------------------------------------------------------------------
 
-def test_dense_decode_kernel_post_rollback():
-    B, H, KV, Tt, d = 2, 4, 2, 32, 16
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (B, H, d))
-    k = jax.random.normal(ks[1], (B, KV, Tt, d))
-    v = jax.random.normal(ks[2], (B, KV, Tt, d))
-    lens = jnp.array([5, 21], jnp.int32)       # rolled back below written
-    pos = jnp.arange(Tt)[None, None, :, None]
-    poison = pos >= lens[:, None, None, None]
-    kp = jnp.where(poison, 1e4, k)
-    vp = jnp.where(poison, -1e4, v)
-    o_ref = ref.decode_attention_ref(q, k, v, lens)
-    o_pal = ops.decode_attention(q, kp, vp, lens, impl="interpret",
-                                 block_t=8)
-    np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
-                               atol=5e-5, rtol=5e-5)
-
-
-def test_paged_decode_kernel_post_rollback():
+@pytest.mark.parametrize("B,H,KV,d,pt,n_p,lens,seed", [
+    (2, 4, 2, 16, 4, 6, (6, 13), 4),
+    # 8-token pages, rolled back inside the first and the last page
+    (2, 4, 2, 16, 8, 4, (5, 21), 3),
+])
+def test_paged_decode_kernel_post_rollback(B, H, KV, d, pt, n_p, lens, seed):
     """A slot may own MORE pages than ceil(length/page_tokens) after a
     rollback; in-use-page garbage past length and whole rolled-back
-    pages must both be inert."""
-    B, H, KV, d, pt, n_p = 2, 4, 2, 16, 4, 6
+    pages must both be inert, and the output is the oracle's."""
     n_pool = B * n_p + 1
-    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, H, d))
     lanes = ref.page_lanes(KV, d)
     k_pool = ref.heads_to_lanes(
@@ -174,7 +167,7 @@ def test_paged_decode_kernel_post_rollback():
     # every slot owns ALL n_p of its pages (pre-rollback coverage) ...
     tab = jnp.arange(B * n_p, dtype=jnp.int32).reshape(B, n_p)
     # ... but lengths rolled back to mid-page values
-    lens = jnp.array([6, 13], jnp.int32)
+    lens = jnp.array(lens, jnp.int32)
     o1 = ops.paged_decode_attention(q, k_pool, v_pool, tab, lens, **geo)
     # poison everything past each slot's rolled-back length
     poison = jnp.zeros((n_pool, pt), bool)
@@ -188,23 +181,25 @@ def test_paged_decode_kernel_post_rollback():
     vp = jnp.where(poison[:, :, None], -1e4, v_pool)
     o2 = ops.paged_decode_attention(q, kp, vp, tab, lens, **geo)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+    o_ref = ref.paged_decode_attention_ref(q, k_pool, v_pool, tab, lens,
+                                           kv_heads=KV, head_widths=(d, d))
+    np.testing.assert_allclose(np.asarray(o2), np.asarray(o_ref),
+                               atol=5e-5, rtol=5e-5)
 
 
 # ---------------------------------------------------------------------------
 # engine: speculative == non-speculative, token for token
 # ---------------------------------------------------------------------------
 
-def test_spec_engine_matches_nonspec_dense_and_paged():
-    """Thin wrapper over the cross-layout exactness matrix
-    (tests/test_matrix.py superseded the ad-hoc spec-vs-nonspec stream
-    comparison): dense and paged speculative cells both match the
-    oracle, hence the non-speculative streams, byte-for-byte."""
+def test_spec_engine_matches_nonspec():
+    """Thin wrapper over the exactness matrix (tests/test_matrix.py
+    superseded the ad-hoc spec-vs-nonspec stream comparison): the paged
+    speculative cell matches the oracle, hence the non-speculative
+    streams, byte-for-byte."""
     from test_matrix import run_layout_case
-    es = run_layout_case("dense", spec_k=2, prune=0.5)
     ep = run_layout_case("paged", spec_k=2, prune=0.5)
-    assert es.spec_rounds > 0 and es.accepted_per_round >= 1.0
+    assert ep.spec_rounds > 0 and ep.accepted_per_round >= 1.0
     # two non-spec shapes + at most one draft + one verify shape
-    assert es.compiled_shapes() in (3, 4, None)
     assert ep.compiled_shapes() in (3, 4, None)
 
 
@@ -261,9 +256,9 @@ def test_spec_engine_paged_preemption_stays_exact():
 
 
 def test_spec_engine_interpret_kernel_path():
-    """Under attn_impl="interpret" the draft decode steps run the Pallas
-    flash-decode kernel on the SLICED cache view; streams must match the
-    XLA spec engine."""
+    """Under attn_impl="interpret" the draft decode steps run the paged
+    Pallas flash-decode kernel on the SLICED pool view; streams must
+    match the XLA spec engine."""
     params, cfg = _setup(seed=2)
     prompt = np.arange(4, dtype=np.int32) + 7
     ecfg = EngineConfig(slots=1, max_len=16, prefill_chunk=4, spec_k=2,

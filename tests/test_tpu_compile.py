@@ -2,19 +2,20 @@
 
 Interpret mode accepts any block shape; the TPU lowering does not (the
 last two dims of every block must be multiples of (8, 128) or the whole
-axis — the head-major dense caches and the token-major, lane-dense page
-rows of DESIGN.md §4 and §6 exist to satisfy it).
+axis — the token-major, lane-dense page rows of DESIGN.md §4 and §6
+exist to satisfy it).
 These tests compile each kernel, through the same ``KernelDispatch``
 methods the serving step calls, for a DESCRIBED ``v5e:2x2`` device: the
 TPU compiler is installed here, no chip is needed, nothing runs.
 
 Widths: GPT-2 XL after a 50% CLOVER prune (25 MHA heads, head_dim 64,
 rank 32) and a GQA width (phi3-medium: 10 KV heads, 4 queries per KV
-head, head_dim 128), bf16, 16-token pages.  The paged decode kernel is
-also compiled at the benchmark engine's own shape, where its VMEM
-footprint is real, and the serving engine's decode and chunk programs
-are compiled whole to check that the page pool stays in the order it
-is stored: no step relays it out.
+head, head_dim 128), bf16, 16-token pages.  The paged decode kernel is also
+compiled for the speculative draft's read (a query at half the rank
+against the full-rank pool) and at the benchmark engine's own shape,
+where its VMEM footprint is real, and the serving engine's decode and
+chunk programs are compiled whole to check that the page pool stays in
+the order it is stored: no step relays it out.
 
 The topology is described inside a module fixture — never at import —
 so every pytest-xdist worker collects the same tests and only the one
@@ -39,8 +40,8 @@ WIDTHS = {
 }
 SLOTS, PAGES, PAGE_TOKENS, TABLE, CAPACITY = 8, 64, 16, 16, 512
 KERNELS = ("paged_flash_decode", "paged_flash_decode_ranked",
-           "flash_decode", "flash_decode_ranked", "flash_attention",
-           "page_copy", "page_restore")
+           "paged_flash_decode_draft", "flash_attention", "page_copy",
+           "page_restore")
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +71,6 @@ def _case(kernel, width, sharding):
     pool = s((PAGES + 1, PAGE_TOKENS, lanes))
     table, lens, ranks = s((SLOTS, TABLE), i32), s((SLOTS,), i32), \
         s((KV,), i32)
-    cache = s((SLOTS, KV, CAPACITY, r))
     stacked = s((4, PAGES + 1, PAGE_TOKENS, lanes))   # (n_blocks, ...)
     ids = s((SLOTS,), i32)
     geo = dict(kv_heads=KV, head_widths=(r, r))
@@ -81,12 +81,10 @@ def _case(kernel, width, sharding):
         return (lambda *a: pallas.paged_decode_attention(
             *a[:5], qk_ranks=a[5], vo_ranks=a[6], **geo),
             (q, pool, pool, table, lens, ranks, ranks))
-    if kernel == "flash_decode":
-        return pallas.decode_attention, (q, cache, cache, lens)
-    if kernel == "flash_decode_ranked":
-        return (lambda *a: pallas.decode_attention(
-            *a[:4], qk_ranks=a[4], vo_ranks=a[5]),
-            (q, cache, cache, lens, ranks, ranks))
+    if kernel == "paged_flash_decode_draft":
+        # the rank-sliced draft reads each head's leading r/2 lanes
+        return (lambda *a: pallas.paged_decode_attention(*a, **geo),
+                (s((SLOTS, H, r // 2)), pool, pool, table, lens))
     if kernel == "flash_attention":
         act = s((1, CAPACITY, H, d))
         kv = s((1, CAPACITY, KV, d))
